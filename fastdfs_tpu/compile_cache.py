@@ -1,0 +1,35 @@
+"""Where compiled XLA programs are kept between process starts.
+
+Every entry point that jits (the sidecar, bench.py, bench_configs.py,
+chip_smoke.py, the tests' conftest) calls :func:`configure` before its
+first compile, and nothing else in the tree names a cache directory.
+
+The directory is placed from outside when ``JAX_COMPILATION_CACHE_DIR``
+is set: JAX reads that variable itself, so nothing is set in code.
+Otherwise it is ONE fixed path inside the checkout.  The path is part of
+the cache key, so a directory built from a temp name, a pid or a time
+would never hit; a fixed one means a second sidecar start skips the six
+bucket shapes x two kernels that ``DedupEngine.warmup`` compiles.
+"""
+
+from __future__ import annotations
+
+import os
+
+_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+DEFAULT_DIR = os.path.join(_REPO, ".jax_cache")
+
+
+def configure() -> str:
+    """Point JAX's persistent compilation cache; returns the directory."""
+    placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if placed:
+        return placed
+    import jax
+
+    jax.config.update("jax_compilation_cache_dir", DEFAULT_DIR)
+    # Cache everything: the Pallas kernels compile in a second or two
+    # each, under JAX's default 1 s threshold as often as over it.
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    return DEFAULT_DIR

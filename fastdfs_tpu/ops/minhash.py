@@ -5,8 +5,8 @@ backed by a jax.numpy cosine/MinHash similarity search") needs a compact
 per-chunk signature whose agreement rate estimates Jaccard similarity of
 the underlying shingle sets.  The v1 spec permuted EVERY shingle hash
 through all ``P`` universal hashes — ``P`` multiply-add-min triples per
-byte, ~192 vector ops/byte, which capped the whole ingest pipeline at
-~2.9 GB/s on a v5e chip (see tools/PROFILE_r03.md).  The v2 spec is a
+byte, ~192 vector ops/byte, which made MinHash the pipeline's
+costliest stage.  The v2 spec is a
 TPU-first two-stage sketch with identical set semantics:
 
 1. **Shingle hashes** — polynomial hash of every ``k``-byte window

@@ -4,7 +4,7 @@ Implements stages 1-3 of ``ops/minhash.py``'s sketch — shingle hashing,
 value-keyed survivor sampling, segment-min compaction — as ONE fused
 kernel that reads each ingested byte exactly once.  The XLA formulation
 pays ~20 HBM-bound vector ops per byte just to materialize the shingle
-hashes (measured ~15-19 ms per 128 MB on a v5e; tools/PROFILE_r03.md);
+hashes;
 this kernel keeps everything in registers and emits only the tiny
 ``(8, 128)`` survivor plane per chunk.
 

@@ -2,11 +2,12 @@
 
 Why a kernel at all: the pure-XLA formulation in ``ops/sha1.py`` emits
 ~1000 elementwise HLO ops per 64-byte block whose intermediates spill to
-HBM — measured ~8-9 GB/s marginal on a v5e chip.  This kernel keeps the
+HBM.  This kernel keeps the
 five state words and the 80-entry message schedule in vector registers,
 so steady-state cost collapses to one streamed read of the message plus
-the VPU rounds (~115 GB/s for the compress stage alone; end-to-end
-throughput is then bounded by the XLA-side padding/layout passes).
+the VPU rounds (end-to-end throughput is then bounded by the XLA-side
+padding/layout passes).  Neither path's rate is measured on this
+machine yet (PERF.md).
 
 Layout: chunks are packed one-per-lane onto (SUB, 128) vreg tiles —
 SUB*128 chunks per grid step, so every round instruction advances

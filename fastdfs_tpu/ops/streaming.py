@@ -11,7 +11,7 @@ in-flight window: keep up to ``depth`` batches dispatched, fetch the
 oldest only when the window is full.  With ``depth >= 2`` the transfer
 of the next batch and the compute of the current one are concurrent by
 construction; deeper windows additionally amortize per-dispatch
-latency (significant on remote backends — see tools/PROFILE_r03.md).
+latency.
 
 ``DedupEngine.fingerprint`` applies the same bounded-window pattern to
 its bucket batches (device arrays already resident, so no ``device_put``

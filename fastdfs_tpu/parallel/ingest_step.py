@@ -45,15 +45,6 @@ from fastdfs_tpu.ops.sha1 import _sha1_padded
 HALO = WINDOW - 1
 
 
-def _shard_mapped(fn, **specs):
-    """``shard_map`` across the jax API move (>=0.6 top-level / check_vma,
-    older experimental module / check_rep)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(fn, **specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(fn, **specs, check_rep=False)
-
-
 def _gear_from_g(g: jax.Array) -> jax.Array:
     """Windowed gear hash over pre-gathered table values ``g`` (n,)."""
     h = g
@@ -133,11 +124,12 @@ def make_ingest_step(mesh: Mesh, num_perms: int = 64, avg_bits: int = 13,
         best = jax.lax.pmax(local_best, "dp")                    # (N,)
         return cand, digests, sigs, best
 
-    sharded = _shard_mapped(
+    sharded = jax.shard_map(
         step_local,
         mesh=mesh,
         in_specs=(P("dp", "sp", None), P("dp", None), P("dp"), P("dp", None)),
         out_specs=(P("dp", "sp", None), P(), P(), P()),
+        check_vma=False,
     )
     return jax.jit(sharded)
 
@@ -177,11 +169,12 @@ def make_fingerprint_step(mesh: Mesh, num_perms: int = 64, shingle: int = 5):
         sigs = minhash_batch(chunk_batch, chunk_lens, num_perms, shingle)
         return digests, sigs
 
-    sharded = _shard_mapped(
+    sharded = jax.shard_map(
         fp_local,
         mesh=mesh,
         in_specs=(P("dp", None), P("dp")),
         out_specs=(P("dp", None), P("dp", None)),
+        check_vma=False,
     )
     return jax.jit(sharded)
 

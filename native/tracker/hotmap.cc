@@ -1,6 +1,7 @@
 #include "tracker/hotmap.h"
 
 #include <algorithm>
+#include <cstddef>
 #include <cstdio>
 #include <cstring>
 

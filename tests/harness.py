@@ -7,9 +7,14 @@ reference only supported manually.
 
 from __future__ import annotations
 
+import contextlib
+import fcntl
 import os
+import shutil
 import socket
 import subprocess
+import sys
+import tempfile
 import time
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -19,11 +24,20 @@ BUILD = os.path.join(REPO, os.environ.get("FDFS_NATIVE_BUILD",
                                           os.path.join("native", "build")))
 STORAGED = os.path.join(BUILD, "fdfs_storaged")
 TRACKERD = os.path.join(BUILD, "fdfs_trackerd")
+# Every executable of native/CMakeLists.txt: a tree is built when all of
+# them are there (ninja links them last, so a half-done build never passes).
+BINARIES = tuple(os.path.join(BUILD, name) for name in (
+    "fdfs_storaged", "fdfs_trackerd", "fdfs_codec", "fdfs_load",
+    "common_test", "storage_test", "tracker_test"))
 
 
 def ensure_native_built(targets: tuple[str, ...] = ()) -> None:
-    missing = [t for t in (STORAGED, *targets) if not os.path.exists(t)]
-    if not missing:
+    """Build the native tree (cmake + ninja, every target) unless every
+    executable is already there.  The one build routine: the tests (once
+    per session, tests/conftest.py), bench_configs.py and chip_smoke.py
+    all come through here."""
+    wanted = (*BINARIES, *targets)
+    if all(os.path.exists(t) for t in wanted):
         return
     # An alternate tree implies an instrumented build
     # (tools/run_sanitizers.sh naming); configuring it without the
@@ -43,21 +57,24 @@ def ensure_native_built(targets: tuple[str, ...] = ()) -> None:
                 raise RuntimeError(
                     f"unknown sanitizer build dir {base!r}: "
                     f"build it explicitly")
-    import shutil
-    if shutil.which("cmake") and shutil.which("ninja"):
-        cmake = ["cmake", "-S", os.path.join(REPO, "native"), "-B", BUILD,
-                 "-G", "Ninja", f"-DSANITIZE={sanitize}",
-                 f"-DFDFS_LOCKRANK={'ON' if lockrank else 'OFF'}"]
-        subprocess.run(cmake, check=True, capture_output=True)
-        subprocess.run(["ninja", "-C", BUILD], check=True,
-                       capture_output=True)
-    else:
-        # cmake-less environments build through the mirrored g++ script.
-        env = dict(os.environ, BUILD_DIR=base, SANITIZE=sanitize,
-                   FDFS_LOCKRANK="1" if lockrank else "")
-        subprocess.run(
-            ["bash", os.path.join(REPO, "tools", "build_native_gxx.sh")],
-            check=True, capture_output=True, env=env)
+    # pytest-xdist workers all arrive here at once on a fresh checkout:
+    # the first to take the lock builds, the rest wait and then find the
+    # binaries.  Unserialized, N workers ran N interleaved ninja builds
+    # into the one directory.
+    with open(BUILD + ".lock", "w") as lock:
+        fcntl.flock(lock, fcntl.LOCK_EX)
+        if all(os.path.exists(t) for t in wanted):
+            return
+        for cmd in (["cmake", "-S", os.path.join(REPO, "native"), "-B", BUILD,
+                     "-G", "Ninja", f"-DSANITIZE={sanitize}",
+                     f"-DFDFS_LOCKRANK={'ON' if lockrank else 'OFF'}"],
+                    ["ninja", "-C", BUILD]):
+            proc = subprocess.run(cmd, capture_output=True, text=True)
+            if proc.returncode != 0:
+                raise RuntimeError(
+                    f"native build failed ({cmd[0]} exit "
+                    f"{proc.returncode}):\n"
+                    f"{(proc.stdout + proc.stderr)[-4000:]}")
 
 
 def free_port() -> int:
@@ -193,6 +210,91 @@ def start_tracker(tmp_path, port: int | None = None, **kw) -> Daemon:
     os.makedirs(base, exist_ok=True)
     conf = make_tracker_conf(base, port, **kw)
     return Daemon(TRACKERD, conf, port)
+
+
+class Sidecar:
+    """One ``python -m fastdfs_tpu.sidecar`` child, started as an operator
+    starts it: it takes the chip, and exits at once if there is none.
+    ``extra_args`` is where CPU rehearsals put ``--platform cpu``.  The
+    caller's process must not have initialised a JAX backend: the sidecar
+    is the one process that may hold the device."""
+
+    def __init__(self, base: str, extra_args: tuple[str, ...] = (),
+                 state_dir: str | None = None):
+        os.makedirs(base, exist_ok=True)
+        self.sock = os.path.join(base, "dedup.sock")
+        self._sock_dir = None
+        if len(self.sock.encode()) > 100:  # sun_path holds 108 bytes
+            self._sock_dir = tempfile.mkdtemp(prefix="fdfs_sc_")
+            self.sock = os.path.join(self._sock_dir, "dedup.sock")
+        self.log_path = os.path.join(base, "sidecar.log")
+        args = [sys.executable, "-m", "fastdfs_tpu.sidecar",
+                "--socket", self.sock, *extra_args]
+        if state_dir:
+            os.makedirs(state_dir, exist_ok=True)
+            args += ["--state-dir", state_dir]
+        with contextlib.suppress(FileNotFoundError):
+            os.unlink(self.sock)
+        t0 = time.monotonic()
+        with open(self.log_path, "ab") as log:
+            self.proc = subprocess.Popen(args, cwd=REPO, stdout=log,
+                                         stderr=subprocess.STDOUT)
+        # It listens only after warm-up has compiled every bucket shape
+        # (a cold one takes half a minute on a v5e, minutes on XLA's CPU
+        # backend; later starts find them in the compile cache).
+        while True:
+            if self.proc.poll() is not None:
+                raise RuntimeError(
+                    f"sidecar exited {self.proc.returncode} before it "
+                    f"listened: {self.log_tail()}")
+            if os.path.exists(self.sock):
+                with contextlib.suppress(OSError):  # bound, not listening yet
+                    self.stats()
+                    break
+            if time.monotonic() > t0 + 1800:
+                self.stop()
+                raise TimeoutError("sidecar did not listen within 1800 s: "
+                                   + self.log_tail())
+            time.sleep(0.2)
+        self.ready_s = time.monotonic() - t0
+
+    def log_text(self) -> str:
+        with open(self.log_path, errors="replace") as fh:
+            return fh.read()
+
+    def log_tail(self) -> str:
+        return " | ".join(self.log_text().strip().splitlines()[-6:])
+
+    def warmup_s(self) -> float:
+        """engine.warmup() seconds, as the sidecar itself logged them."""
+        for line in reversed(self.log_text().splitlines()):
+            if "dedup sidecar warmed in" in line:
+                return float(line.split("warmed in")[1].split("s")[0])
+        raise RuntimeError("sidecar logged no warm-up time")
+
+    def stats(self) -> dict:
+        """Its ``stats`` reply: counters + the device it really got."""
+        from fastdfs_tpu.sidecar import read_stats
+        return read_stats(self.sock)
+
+    def rss_mb(self) -> float:
+        with open(f"/proc/{self.proc.pid}/status") as fh:
+            for line in fh:
+                if line.startswith("VmRSS:"):
+                    return int(line.split()[1]) / 1024.0
+        raise RuntimeError("the sidecar's /proc status has no VmRSS")
+
+    def stop(self) -> None:
+        """SIGTERM: the sidecar snapshots its state on the way out."""
+        if self.proc.poll() is None:
+            self.proc.terminate()
+            try:
+                self.proc.wait(timeout=60)
+            except subprocess.TimeoutExpired:
+                self.proc.kill()
+                self.proc.wait()
+        if self._sock_dir:
+            shutil.rmtree(self._sock_dir, ignore_errors=True)
 
 
 def chunk_files(base_dir: str) -> list[str]:

@@ -20,8 +20,7 @@ TRACKER_TEST = os.path.join(BUILD, "tracker_test")
 def _ensure_built():
     # TRACKER_TEST doubles as the staleness sentinel: a build tree from
     # before the stats subsystem has codec+common_test but not it, and
-    # must be rebuilt.  harness.ensure_native_built picks cmake/ninja or
-    # the mirrored tools/build_native_gxx.sh, whichever the box has.
+    # must be rebuilt.
     from tests.harness import ensure_native_built
     ensure_native_built((CODEC, COMMON_TEST, TRACKER_TEST))
 

@@ -1,0 +1,118 @@
+"""The fingerprint path's device programs, compiled for a v5e that is
+described and not attached.
+
+Interpret mode (tests/test_pallas_kernels.py) proves the kernels compute
+the right bits; it cannot show what Mosaic refuses: a slice off the
+tiling, too much VMEM, a kernel that cannot be partitioned.  The TPU
+compiler is installed here and compiles for a described topology, so
+these tests ask it, at the widths the sidecar really runs: row_tile 256,
+the smallest and the largest pow2 length bucket, both Pallas kernels,
+the jitted result concat, the XLA SHA-1 the scrubber's DEDUP_VERIFY
+jits, and the four-device fan-out step.  Nothing executes, so they say
+nothing about results or speed (chip_smoke.py does, on the chip).
+
+ALL such tests live in this ONE file: the worker that runs it loads
+libtpu and keeps its lock until it exits, and a second file could land
+on another worker.  The topology is described inside a module fixture,
+never at import (every xdist worker imports every test file).
+"""
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
+                          SingleDeviceSharding)
+
+from fastdfs_tpu.dedup.engine import DedupConfig, _packed_concat
+from fastdfs_tpu.ops.pallas_minhash import minhash_batch_pallas
+from fastdfs_tpu.ops.pallas_sha1 import sha1_batch_pallas
+from fastdfs_tpu.ops.sha1 import _sha1_padded
+from fastdfs_tpu.parallel.ingest_step import make_fingerprint_step
+
+CFG = DedupConfig()
+ROWS = CFG.row_tile
+# engine.py:_fingerprint_batch picks this from the row count.
+SUB = max(1, min(16, ROWS // 128))
+SMALLEST, LARGEST = CFG.min_size, CFG.max_size
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any reason it cannot is a skip
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # A compile for a described device is written to the persistent cache
+    # but cannot be read back without the chip: the next run would warn
+    # and compile again.  Keep the cache out of it.
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _batch(rows, blen, sharding, lens_sharding=None):
+    return (jax.ShapeDtypeStruct((rows, blen), jnp.uint8, sharding=sharding),
+            jax.ShapeDtypeStruct((rows,), jnp.int32,
+                                 sharding=lens_sharding or sharding))
+
+
+@pytest.mark.parametrize("blen", [SMALLEST, LARGEST])
+def test_sha1_pallas_compiles_for_v5e(one_chip, blen):
+    data, lens = _batch(ROWS, blen, one_chip)
+    compiled = sha1_batch_pallas.lower(data, lens, max_len=blen,
+                                       sub=SUB).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("blen", [SMALLEST, LARGEST])
+def test_minhash_pallas_compiles_for_v5e(one_chip, blen):
+    data, lens = _batch(ROWS, blen, one_chip)
+    compiled = minhash_batch_pallas.lower(
+        data, lens, num_perms=CFG.num_perms, k=CFG.shingle).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_packed_concat_compiles_for_v5e(one_chip):
+    tiles = 3
+    digests = [jax.ShapeDtypeStruct((ROWS, 5), jnp.uint32, sharding=one_chip)
+               ] * tiles
+    sigs = [jax.ShapeDtypeStruct((ROWS, CFG.num_perms), jnp.uint32,
+                                 sharding=one_chip)] * tiles
+    compiled = _packed_concat(tiles).lower(*digests, *sigs).compile()
+    assert compiled.output_shardings is not None
+
+
+def test_verify_sha1_compiles_for_v5e(one_chip):
+    """What DedupSidecar._batch_sha1 jits for a scrub batch: the XLA
+    SHA-1 at (chunks in the batch, longest chunk) — here the daemon's
+    largest batch, 64 chunks (scrub.cc kBatchChunks) of max_size."""
+    data, lens = _batch(64, LARGEST, one_chip)
+    _sha1_padded.lower(data, lens, max_len=LARGEST).compile()
+
+
+def test_fanout_step_compiles_for_four_v5e_chips(topo):
+    """engine.py routes every batch here when fan_out > 1 (the default on
+    a four-chip host): rows sharded over a 1-D dp mesh, the XLA reference
+    kernels under shard_map."""
+    mesh = Mesh(np.array(topo.devices[:4]), ("dp",))
+    data, lens = _batch(ROWS, LARGEST, NamedSharding(mesh, P("dp", None)),
+                        NamedSharding(mesh, P("dp")))
+    step = make_fingerprint_step(mesh, CFG.num_perms, CFG.shingle)
+    compiled = step.lower(data, lens).compile()
+    # Rows stay split: each device holds a quarter of the batch.
+    per_dev = compiled.memory_analysis().argument_size_in_bytes
+    assert per_dev < ROWS * LARGEST // 2

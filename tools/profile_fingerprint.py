@@ -6,8 +6,8 @@ device (median of steady-state iters, full device_get fence), so the
 headline bench number is explainable instead of guessed at.  Prints one
 JSON object per stage, then a final summary object; ``--trace DIR``
 additionally captures a JAX profiler trace of the fused pipeline (one
-extra ``{"trace_dir": ...}`` line).  The round-3 breakdown that
-justified the bench.py rewrite is checked in at tools/PROFILE_r03.md.
+extra ``{"trace_dir": ...}`` line).  One process: it holds the chip for
+its whole run, so run it through the chip tool with no sidecar alive.
 """
 
 import json

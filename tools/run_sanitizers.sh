@@ -17,8 +17,9 @@
 #   both      legacy alias for asan + tsan
 #
 # The harness picks up the instrumented binaries via FDFS_NATIVE_BUILD.
-# Builds use cmake/ninja when available and fall back to
-# tools/build_native_gxx.sh (same sources and flags) otherwise.
+# Each instrumented tree is several hundred MB; the chip tool and the
+# test driver copy the checkout as it stands, so remove native/build-*
+# when the run is done.
 set -euo pipefail
 cd "$(dirname "$0")/.."
 
@@ -41,17 +42,9 @@ fi
 
 build_tree() {
   local dir="$1" sanitize="$2" lockrank="$3"
-  if command -v cmake >/dev/null && command -v ninja >/dev/null; then
-    local args=(-S native -B "$dir" -G Ninja
-                -DCMAKE_BUILD_TYPE=RelWithDebInfo
-                -DSANITIZE="$sanitize" -DFDFS_LOCKRANK="$lockrank")
-    cmake "${args[@]}" >/dev/null
-    ninja -C "$dir"
-  else
-    BUILD_DIR="$(basename "$dir")" SANITIZE="$sanitize" \
-      FDFS_LOCKRANK="$([ "$lockrank" = ON ] && echo 1 || echo "")" \
-      bash tools/build_native_gxx.sh >/dev/null
-  fi
+  cmake -S native -B "$dir" -G Ninja -DCMAKE_BUILD_TYPE=RelWithDebInfo \
+    -DSANITIZE="$sanitize" -DFDFS_LOCKRANK="$lockrank" >/dev/null
+  ninja -C "$dir"
 }
 
 run_one() {
