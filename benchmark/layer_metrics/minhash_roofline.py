@@ -1,0 +1,7 @@
+"""The MinHash kernel's share of its HBM roofline (see _roofline.py)."""
+
+from . import _roofline
+
+
+def read(cell: dict):
+    return _roofline.read(cell, "minhash_roofline")
