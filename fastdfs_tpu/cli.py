@@ -1167,6 +1167,48 @@ def cmd_group(c: FdfsClient, args: list[str]) -> int:
         return 0
 
 
+def cmd_sidecar_trace(args: list[str]) -> int:
+    """sidecar-trace <socket> --seconds N --out DIR: a JAX profiler trace
+    of a running dedup sidecar (device operations and the ``fdfs.*`` spans
+    on one clock), and the spans' wall time and count over those seconds
+    from its ``stats`` reply.  Takes the sidecar's socket, no tracker."""
+    import time
+
+    from fastdfs_tpu.common.protocol import StorageCmd
+    from fastdfs_tpu.sidecar import read_stats, rpc
+
+    sock = args[0]
+    seconds = float(_flag(args, "--seconds", "10"))
+    out = os.path.abspath(_flag(args, "--out", "sidecar_trace"))
+
+    def trace(what: str) -> None:
+        status, resp = rpc(sock, StorageCmd.DEDUP_COMMIT, what.encode(),
+                           timeout=300.0)
+        if status != 0:
+            raise OSError(f"sidecar {what.split()[1]}: status {status} "
+                          f"{resp.decode('utf-8', 'replace')}")
+
+    trace(f"trace start {out}")
+    try:
+        before = read_stats(sock)
+        time.sleep(seconds)
+        after = read_stats(sock)
+    finally:
+        trace("trace stop")
+    mb = (after["fingerprint_bytes"] - before["fingerprint_bytes"]) / 1e6
+    print(f"{seconds:g} s traced into {out}: {mb:.1f} MB fingerprinted in "
+          f"{after['requests'] - before['requests']} requests, host stall "
+          f"{(after['host_stall_us'] - before['host_stall_us']) / 1e3:.1f} ms,"
+          f" device memory peak {after['memory_peak_bytes'] / 1e6:.1f} MB")
+    print(f"{'span':<28}{'n':>8}{'ms':>12}{'ms/MB':>10}")
+    for name in sorted(after["span_us"]):
+        n = after["span_n"][name] - before["span_n"].get(name, 0)
+        ms = (after["span_us"][name] - before["span_us"].get(name, 0)) / 1e3
+        per_mb = f"{ms / mb:10.3f}" if mb else f"{'-':>10}"
+        print(f"{name:<28}{n:>8}{ms:>12.1f}{per_mb}")
+    return 0
+
+
 TOOLS = {
     "upload": cmd_upload,
     "download": cmd_download,
@@ -1196,12 +1238,16 @@ TOOLS = {
 
 def main(argv: list[str] | None = None) -> int:
     argv = sys.argv[1:] if argv is None else argv
-    if len(argv) < 2 or argv[0] not in TOOLS:
+    if len(argv) < 2 or argv[0] not in (*TOOLS, "sidecar-trace"):
         print(f"usage: python -m fastdfs_tpu.cli <{'|'.join(TOOLS)}> "
-              "<client.conf|tracker_host:port> [args...]", file=sys.stderr)
+              "<client.conf|tracker_host:port> [args...]\n"
+              "       python -m fastdfs_tpu.cli sidecar-trace "
+              "<sidecar socket> [--seconds N] [--out DIR]", file=sys.stderr)
         return 2
     tool, conf = argv[0], argv[1]
     try:
+        if tool == "sidecar-trace":     # talks to a sidecar, not a cluster
+            return cmd_sidecar_trace(argv[1:])
         return TOOLS[tool](_client(conf), argv[2:])
     except Exception as e:  # CLI surface: print, nonzero exit
         print(f"error: {e}", file=sys.stderr)

@@ -27,6 +27,7 @@ from dataclasses import dataclass, field
 import numpy as np
 
 from fastdfs_tpu.dedup.index import ExactDigestIndex, MinHashLSHIndex
+from fastdfs_tpu.dedup.spans import new_acc, span
 from fastdfs_tpu.ops import gear_cdc
 from fastdfs_tpu.ops.minhash import DEFAULT_PERMS, DEFAULT_SHINGLE, minhash_batch
 from fastdfs_tpu.ops.sha1 import digest_bytes
@@ -115,11 +116,13 @@ def _packed_concat(half: int):
     import jax
     import jax.numpy as jnp
 
-    def f(*args):
+    # The name is what the device trace's modules line shows
+    # (jit_fdfs_packed_concat), whatever the tile count.
+    def fdfs_packed_concat(*args):
         return jnp.concatenate(
             [jnp.concatenate([args[i], args[half + i]], axis=1)
              for i in range(half)])
-    return jax.jit(f)
+    return jax.jit(fdfs_packed_concat)
 
 
 class DedupEngine:
@@ -224,7 +227,8 @@ class DedupEngine:
 
     # -- pure compute ------------------------------------------------------
 
-    def fingerprint(self, data: bytes, cuts: list[int] | None = None
+    def fingerprint(self, data: bytes, cuts: list[int] | None = None,
+                    acc: dict | None = None
                     ) -> tuple[list[tuple[int, int]], np.ndarray, np.ndarray]:
         """Chunk + fingerprint a stream: returns (spans, digests, signatures).
 
@@ -235,7 +239,17 @@ class DedupEngine:
         caller already ran an identical CDC — the daemon's native AVX2
         chunker shares the gear table, so in sidecar mode the bytes only
         cross the accelerator link once, for hashing.
+
+        ``acc`` (``spans.new_acc()``) takes the call's stage times; the
+        stages are ``fdfs.engine.*`` spans of a running profiler trace,
+        one per tile at the most.
         """
+        if acc is None:
+            acc = new_acc()
+        with span("fdfs.engine.fingerprint", acc):
+            return self._fingerprint(data, cuts, acc)
+
+    def _fingerprint(self, data, cuts, acc: dict):
         cfg = self.config
         if cuts is None:
             cuts = gear_cdc.chunk_stream(data, cfg.min_size, cfg.avg_bits,
@@ -293,17 +307,20 @@ class DedupEngine:
                 slot = tile_no % _N_STAGING_SLOTS
                 prev = slot_last.get((blen, slot))
                 if prev is not None:
-                    jax.block_until_ready(prev)
-                batch_buf = gear_cdc.staging_buffer(
-                    tile * blen, slot=slot).reshape(tile, blen)
+                    with span("fdfs.engine.slot_wait", acc):
+                        jax.block_until_ready(prev)
                 group = idxs[start:start + tile]
-                batch_buf[:] = 0
-                lens = np.zeros(tile, dtype=np.int32)
-                for row, i in enumerate(group):
-                    off, ln = spans[i]
-                    batch_buf[row, :ln] = arr[off:off + ln]
-                    lens[row] = ln
-                d, s = self._fingerprint_batch(batch_buf, lens)
+                with span("fdfs.engine.pack", acc, True):
+                    batch_buf = gear_cdc.staging_buffer(
+                        tile * blen, slot=slot).reshape(tile, blen)
+                    batch_buf[:] = 0
+                    lens = np.zeros(tile, dtype=np.int32)
+                    for row, i in enumerate(group):
+                        off, ln = spans[i]
+                        batch_buf[row, :ln] = arr[off:off + ln]
+                        lens[row] = ln
+                with span("fdfs.engine.dispatch", acc):
+                    d, s = self._fingerprint_batch(batch_buf, lens)
                 slot_last[(blen, slot)] = (d, s)
                 groups.append(group)
                 outs_d.append(d)
@@ -313,15 +330,17 @@ class DedupEngine:
         # fetch pays a single round-trip latency, then split on host.
         # The concat itself runs as ONE jitted call — as eager ops it
         # would be ~2 dispatches per tile.
-        packed = np.asarray(jax.device_get(
-            _packed_concat(len(outs_d))(*outs_d, *outs_s)))
-        d_all = packed[:, :5]
-        s_all = packed[:, 5:]
-        for gi, group in enumerate(groups):
-            base = gi * tile
-            for row, i in enumerate(group):
-                digests[i] = d_all[base + row]
-                sigs[i] = s_all[base + row]
+        with span("fdfs.engine.fetch", acc):
+            packed = np.asarray(jax.device_get(
+                _packed_concat(len(outs_d))(*outs_d, *outs_s)))
+        with span("fdfs.engine.scatter", acc, True):
+            d_all = packed[:, :5]
+            s_all = packed[:, 5:]
+            for gi, group in enumerate(groups):
+                base = gi * tile
+                for row, i in enumerate(group):
+                    digests[i] = d_all[base + row]
+                    sigs[i] = s_all[base + row]
         return spans, digests, sigs
 
     def warmup(self) -> None:

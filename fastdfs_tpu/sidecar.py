@@ -28,12 +28,31 @@ Opcodes
   ``forget <file_id>`` | ``stats``.  ``abort`` is sent on flat-fallback
   or a failed upload; sessions older than ``_SESSION_TTL`` seconds are
   reaped in case a daemon dies without either message.  ``stats``
-  returns the service counters as JSON (fingerprint_bytes, chunks,
-  requests, lock_wait_us, engine_us, verify_host_fallbacks) plus the
-  device this process got (backend, device_kind, device_count,
-  use_pallas, fan_out, device_bytes per device id) — a reader learns
-  from it whether the chip did the work, which the daemon's fail-open
-  path would otherwise hide.
+  returns the service counters as JSON: ``fingerprint_bytes``,
+  ``chunks``, ``requests``, ``verify_host_fallbacks``; ``engine_us``,
+  the time inside ``DedupEngine.fingerprint``, which runs OUTSIDE
+  ``_lock`` so that concurrent uploads overlap on the device;
+  ``lock_wait_us``, the wait for ``_lock`` AFTER that call, to append
+  the reply and the session's bookkeeping (it cannot show queueing for
+  the engine: nothing queues there); ``span_us`` / ``span_n``, wall time
+  and count of every ``fdfs.*`` span by name (``dedup/spans.py``; the
+  table is in OPERATIONS.md, "Tracing"; ``engine_us`` and
+  ``lock_wait_us`` are two of its entries under their old names);
+  ``host_stall_us``, counted while a trace runs: wall minus thread CPU
+  time over the spans in which a request thread has only Python to run
+  (parse, pack, scatter, reply), i.e. the time it waited for the
+  interpreter or a core.  Plus the device this process got (backend,
+  device_kind, device_count, use_pallas, fan_out, device_bytes per
+  device id, and memory_peak_bytes, the most the fullest device has
+  held) — a reader learns from it whether the chip did the work, which
+  the daemon's fail-open path would otherwise hide.
+  ``trace start <dir>`` / ``trace stop`` start and stop a JAX profiler
+  trace of this process (the one that holds the chip) into ``<dir>``:
+  device operations and the ``fdfs.*`` spans on one clock.  Both are
+  answered before ``_lock`` is taken (starting a trace takes seconds);
+  a second ``trace start`` while one runs gets status 16 (EBUSY).  The
+  socket is trusted: whoever may connect may already ``forget`` a file,
+  and may have a profile written wherever this process may write.
 * ``DEDUP_NEARDUPS`` (123): body = file id text.  Response: ranked text
   lines ``<file_id> <score>`` from the MinHash/LSH index (the operator
   query surface behind the daemon's ``NEAR_DUPS`` command); status 61
@@ -74,8 +93,11 @@ import numpy as np
 
 from fastdfs_tpu.common.protocol import HEADER_SIZE, StorageCmd, unpack_header
 from fastdfs_tpu.dedup.engine import DedupConfig, DedupEngine
+from fastdfs_tpu.dedup.spans import mark, new_acc, span
 
 _I64 = struct.Struct(">q")
+_FINGERPRINT_CMDS = (StorageCmd.DEDUP_FINGERPRINT,
+                     StorageCmd.DEDUP_FINGERPRINT_CUTS)
 
 _SESSION_TTL = 600.0  # seconds before an uncommitted session is reaped
 
@@ -132,9 +154,11 @@ def _parse_session(token: str) -> int:
 class DedupSidecar:
     """Unix-socket dedup service around a :class:`DedupEngine`.
 
-    One engine (and one TPU context) serves every daemon connection;
-    engine calls are serialized under a lock — batching happens inside
-    the engine's bucketed jit calls, not across requests.
+    One engine (and one TPU context) serves every daemon connection, one
+    thread each.  ``engine.fingerprint`` runs outside ``_lock`` (it
+    touches no index state), so concurrent uploads overlap on the device;
+    ``_lock`` serializes only sessions, indexes and ``stats``.  Batching
+    happens inside the engine's bucketed jit calls, not across requests.
     """
 
     def __init__(self, socket_path: str, state_dir: str | None = None,
@@ -148,15 +172,18 @@ class DedupSidecar:
         self._lock = threading.Lock()
         self._stop = threading.Event()
         self._listener: socket.socket | None = None
-        # lock_wait_us / engine_us price the one-engine-serialization
-        # design: lock_wait is time requests spent queued on _lock,
-        # engine is time actually inside engine.fingerprint.  Read via
-        # the `stats` commit subcommand (bench stage attribution).
+        # engine_us is time inside engine.fingerprint, which runs outside
+        # _lock; lock_wait_us is the wait for _lock afterwards, to append
+        # the reply and the session's bookkeeping.  Both, span_us / span_n
+        # and host_stall_us are folded from each request's own accumulator
+        # (dedup/spans.py) under that hold of _lock.
         # verify_host_fallbacks counts DEDUP_VERIFY batches the device
-        # path failed and hashlib answered (see _verify).
+        # path failed and hashlib answered (see _verify).  Read via the
+        # `stats` commit subcommand.
         self.stats = {"fingerprint_bytes": 0, "chunks": 0, "requests": 0,
                       "lock_wait_us": 0, "engine_us": 0,
-                      "verify_host_fallbacks": 0}
+                      "verify_host_fallbacks": 0,
+                      "span_us": {}, "span_n": {}, "host_stall_us": 0}
         if state_dir:
             self._load_state()
 
@@ -257,73 +284,107 @@ class DedupSidecar:
                 "device_count": len(devs),
                 "use_pallas": self.engine.use_pallas,
                 "fan_out": self.engine.fan_out,
+                "memory_peak_bytes": max(
+                    int((dev.memory_stats() or {}).get("peak_bytes_in_use", 0))
+                    for dev in devs),
                 # dict(): one atomic copy; connection threads add to it
                 "device_bytes": {str(dev): n for dev, n in sorted(
                     dict(self.engine.device_bytes).items())}}
 
     # -- request handlers --------------------------------------------------
 
-    def _fingerprint(self, body: bytes, with_cuts: bool = False
-                     ) -> tuple[int, bytes]:
-        if len(body) < 16:
-            return 22, b""
-        session_id = _I64.unpack_from(body)[0]
-        base_offset = _I64.unpack_from(body, 8)[0]
-        cuts = None
-        if with_cuts:
-            # DEDUP_FINGERPRINT_CUTS: the daemon already ran the
-            # (identical) native CDC; body carries the cut offsets.
-            if len(body) < 24:
+    def _fingerprint(self, body: bytes, with_cuts: bool = False,
+                     acc: dict | None = None) -> tuple[int, bytes]:
+        if acc is None:
+            acc = new_acc()
+        with span("fdfs.sidecar.parse", acc, True):
+            if len(body) < 16:
                 return 22, b""
-            n_cuts = _I64.unpack_from(body, 16)[0]
-            if n_cuts < 0 or 24 + 8 * n_cuts > len(body):
-                return 22, b""
-            cuts = [_I64.unpack_from(body, 24 + 8 * i)[0]
-                    for i in range(n_cuts)]
-            data = body[24 + 8 * n_cuts:]
-            # Cuts must exactly cover the payload: an empty cut list
-            # with data would "succeed" with zero chunks and a recipe
-            # covering none of the bytes.
-            if data:
-                if (not cuts or cuts[-1] != len(data)
-                        or any(c <= p for p, c in zip([0] + cuts, cuts))):
+            session_id = _I64.unpack_from(body)[0]
+            base_offset = _I64.unpack_from(body, 8)[0]
+            cuts = None
+            if with_cuts:
+                # DEDUP_FINGERPRINT_CUTS: the daemon already ran the
+                # (identical) native CDC; body carries the cut offsets.
+                if len(body) < 24:
                     return 22, b""
-            elif cuts:
-                return 22, b""
-        else:
-            data = body[16:]
+                n_cuts = _I64.unpack_from(body, 16)[0]
+                if n_cuts < 0 or 24 + 8 * n_cuts > len(body):
+                    return 22, b""
+                cuts = [_I64.unpack_from(body, 24 + 8 * i)[0]
+                        for i in range(n_cuts)]
+                data = body[24 + 8 * n_cuts:]
+                # Cuts must exactly cover the payload: an empty cut list
+                # with data would "succeed" with zero chunks and a recipe
+                # covering none of the bytes.
+                if data:
+                    if (not cuts or cuts[-1] != len(data)
+                            or any(c <= p for p, c in zip([0] + cuts, cuts))):
+                        return 22, b""
+                elif cuts:
+                    return 22, b""
+            else:
+                data = body[16:]
         # Pure compute OUTSIDE the lock: engine.fingerprint touches no
         # index state (its docstring is the contract), and JAX dispatch
         # is thread-safe — so concurrent daemon uploads overlap their
         # device round-trips instead of queueing behind one global lock.
         # Only session/stats/index mutation is serialized.
-        t_start = time.monotonic()
-        spans, digests, sigs = self.engine.fingerprint(data, cuts=cuts)
-        t_wait = time.monotonic()
-        with self._lock:
-            t_held = time.monotonic()
-            self.stats["lock_wait_us"] += int((t_held - t_wait) * 1e6)
-            self.stats["engine_us"] += int((t_wait - t_start) * 1e6)
-            sess = self._sessions.setdefault(session_id, _Session())
-            sess.touched = time.monotonic()
-            raw = np.asarray(digests, dtype=">u4").tobytes()
-            out = [_I64.pack(len(spans))]
-            for i, (off, ln) in enumerate(spans):
-                out.append(_I64.pack(base_offset + off))
-                out.append(_I64.pack(ln))
-                # Digest attribution (which file first carried a chunk,
-                # for near-dup reporting) stays buffered in the session
-                # until commit binds the real file id — the index never
-                # sees provisional entries.
-                dig = raw[i * 20:(i + 1) * 20]
-                out.append(dig)
-                sess.digests.append((dig, base_offset + off))
-            if len(spans):
-                sig = np.asarray(sigs).min(axis=0)
-                sess.sig = sig if sess.sig is None else np.minimum(sess.sig, sig)
-            self.stats["fingerprint_bytes"] += len(data)
-            self.stats["chunks"] += len(spans)
+        spans, digests, sigs = self.engine.fingerprint(data, cuts=cuts,
+                                                       acc=acc)
+        with span("fdfs.sidecar.lock_wait", acc):
+            self._lock.acquire()
+        try:
+            with span("fdfs.sidecar.reply", acc, True):
+                sess = self._sessions.setdefault(session_id, _Session())
+                sess.touched = time.monotonic()
+                raw = np.asarray(digests, dtype=">u4").tobytes()
+                out = [_I64.pack(len(spans))]
+                for i, (off, ln) in enumerate(spans):
+                    out.append(_I64.pack(base_offset + off))
+                    out.append(_I64.pack(ln))
+                    # Digest attribution (which file first carried a
+                    # chunk, for near-dup reporting) stays buffered in the
+                    # session until commit binds the real file id — the
+                    # index never sees provisional entries.
+                    dig = raw[i * 20:(i + 1) * 20]
+                    out.append(dig)
+                    sess.digests.append((dig, base_offset + off))
+                if len(spans):
+                    sig = np.asarray(sigs).min(axis=0)
+                    sess.sig = (sig if sess.sig is None
+                                else np.minimum(sess.sig, sig))
+                self.stats["fingerprint_bytes"] += len(data)
+                self.stats["chunks"] += len(spans)
+            # The two old counters are those two spans: one clock for each.
+            ns = acc["span_ns"]
+            self.stats["engine_us"] += ns["fdfs.engine.fingerprint"] // 1000
+            self.stats["lock_wait_us"] += ns["fdfs.sidecar.lock_wait"] // 1000
+            self.stats["host_stall_us"] += (
+                acc["host_wall_ns"] - acc["host_cpu_ns"]) // 1000
+            self._fold(acc)
+        finally:
+            self._lock.release()
+        # The request's size, on the trace's clock.  host_wall_us and
+        # host_cpu_us are summed over parse, pack, scatter and reply:
+        # their difference is time this thread had no interpreter or core.
+        mark("fdfs.sidecar.request_done", bytes=len(data),
+             host_wall_us=acc["host_wall_ns"] // 1000,
+             host_cpu_us=acc["host_cpu_ns"] // 1000)
         return 0, b"".join(out)
+
+    def _fold(self, acc: dict) -> None:
+        """The spans ``acc`` has closed so far into ``stats``, and out of
+        ``acc``.  A fingerprint request calls it under the ``_lock`` it
+        holds for its reply; what closes after that (``request``, ``send``,
+        and every span of the other opcodes) is added by ``_serve_conn``
+        without the lock, as ``requests`` is counted."""
+        us, n = self.stats["span_us"], self.stats["span_n"]
+        for name, ns in acc["span_ns"].items():
+            us[name] = us.get(name, 0) + ns // 1000
+            n[name] = n.get(name, 0) + acc["span_n"][name]
+        acc["span_ns"].clear()
+        acc["span_n"].clear()
 
     def _query(self, body: bytes) -> tuple[int, bytes]:
         sha1_hex = body.decode("ascii", "replace").strip()
@@ -332,9 +393,12 @@ class DedupSidecar:
         return 0, fid.encode() if fid else b""
 
     def _commit(self, body: bytes) -> tuple[int, bytes]:
-        parts = body.decode("utf-8", "replace").split()
+        text = body.decode("utf-8", "replace")
+        parts = text.split()
         if not parts:
             return 22, b""
+        if parts[0] == "trace":     # the directory may hold blanks
+            return self._trace(text.strip().split(None, 2)[1:])
         with self._lock:
             if parts[0] == "commitfile" and len(parts) == 3:
                 self.files.setdefault(parts[1], parts[2])
@@ -370,6 +434,28 @@ class DedupSidecar:
                 # per-file digest-list side table in RAM.
                 self.engine.exact.remove_by_carrier(parts[1])
                 return 0, b""
+        return 22, b""
+
+    @staticmethod
+    def _trace(args: list[str]) -> tuple[int, bytes]:
+        """``trace start <dir>`` / ``trace stop``: a JAX profiler trace of
+        this process, with the options the benchmark's launcher uses
+        (TraceMe events, which the ``fdfs.*`` spans are; no Python
+        tracer).  Never under ``_lock``: starting takes seconds."""
+        import jax
+
+        try:
+            if args[:1] == ["start"] and len(args) == 2:
+                opts = jax.profiler.ProfileOptions()
+                opts.python_tracer_level = 0
+                opts.host_tracer_level = 1
+                jax.profiler.start_trace(args[1], profiler_options=opts)
+                return 0, b""
+            if args == ["stop"]:
+                jax.profiler.stop_trace()
+                return 0, b""
+        except RuntimeError as e:   # one runs already, or none does
+            return 16, str(e).encode()
         return 22, b""
 
     def _verify(self, body: bytes) -> tuple[int, bytes]:
@@ -470,37 +556,58 @@ class DedupSidecar:
 
     # -- server loop -------------------------------------------------------
 
+    def _handle(self, cmd: int, body: bytes, acc: dict) -> tuple[int, bytes]:
+        """One request, handler entry to reply built: the root span, which
+        carries the identifiers the daemon minted for the upload."""
+        ids = {}
+        if cmd in _FINGERPRINT_CMDS and len(body) >= 16:
+            ids = {"session": _I64.unpack_from(body)[0],
+                   "base_offset": _I64.unpack_from(body, 8)[0]}
+        with span("fdfs.sidecar.request", acc, cmd=cmd, bytes=len(body),
+                  **ids):
+            if cmd == StorageCmd.DEDUP_FINGERPRINT:
+                return self._fingerprint(body, acc=acc)
+            if cmd == StorageCmd.DEDUP_FINGERPRINT_CUTS:
+                return self._fingerprint(body, with_cuts=True, acc=acc)
+            if cmd == StorageCmd.DEDUP_QUERY:
+                return self._query(body)
+            if cmd == StorageCmd.DEDUP_COMMIT:
+                return self._commit(body)
+            if cmd == StorageCmd.DEDUP_NEARDUPS:
+                return self._neardups(body)
+            if cmd == StorageCmd.DEDUP_VERIFY:
+                # the scrubber's batch: background work on the same chip
+                with span("fdfs.sidecar.verify", acc):
+                    return self._verify(body)
+            if cmd == StorageCmd.ACTIVE_TEST:
+                return 0, b""
+            return 22, b""
+
     def _serve_conn(self, conn: socket.socket) -> None:
         try:
             while not self._stop.is_set():
                 hdr = self._recv_exact(conn, HEADER_SIZE)
                 if hdr is None:
                     return
+                # The wait for a header on an idle pooled connection is
+                # the daemon's time, not a span: recv starts here.
+                acc = new_acc()
                 h = unpack_header(hdr)
                 if h.pkg_len < 0 or h.pkg_len > (1 << 31):
                     return
-                body = self._recv_exact(conn, h.pkg_len) if h.pkg_len else b""
+                with span("fdfs.sidecar.recv", acc, cmd=h.cmd,
+                          bytes=h.pkg_len):
+                    body = (self._recv_exact(conn, h.pkg_len)
+                            if h.pkg_len else b"")
                 if body is None:
                     return
                 self.stats["requests"] += 1
-                if h.cmd == StorageCmd.DEDUP_FINGERPRINT:
-                    status, resp = self._fingerprint(body)
-                elif h.cmd == StorageCmd.DEDUP_FINGERPRINT_CUTS:
-                    status, resp = self._fingerprint(body, with_cuts=True)
-                elif h.cmd == StorageCmd.DEDUP_QUERY:
-                    status, resp = self._query(body)
-                elif h.cmd == StorageCmd.DEDUP_COMMIT:
-                    status, resp = self._commit(body)
-                elif h.cmd == StorageCmd.DEDUP_NEARDUPS:
-                    status, resp = self._neardups(body)
-                elif h.cmd == StorageCmd.DEDUP_VERIFY:
-                    status, resp = self._verify(body)
-                elif h.cmd == StorageCmd.ACTIVE_TEST:
-                    status, resp = 0, b""
-                else:
-                    status, resp = 22, b""
-                conn.sendall(_pack_header(len(resp),
-                                          StorageCmd.RESP, status) + resp)
+                status, resp = self._handle(h.cmd, body, acc)
+                with span("fdfs.sidecar.send", acc, cmd=h.cmd,
+                          bytes=len(resp)):
+                    conn.sendall(_pack_header(len(resp),
+                                              StorageCmd.RESP, status) + resp)
+                self._fold(acc)     # what closed after the handler's fold
         except OSError:
             pass
         finally:

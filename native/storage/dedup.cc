@@ -62,12 +62,43 @@ bool CpuDedup::Save() {
   return rename(tmp.c_str(), snapshot_path_.c_str()) == 0;
 }
 
+static thread_local int64_t tls_dedup_lock_wait_us = 0;
+static thread_local int64_t tls_dedup_cdc_us = 0;
+
+int64_t TakeDedupLockWaitUs() {
+  int64_t v = tls_dedup_lock_wait_us;
+  tls_dedup_lock_wait_us = 0;
+  return v;
+}
+
+int64_t TakeDedupCdcUs() {
+  int64_t v = tls_dedup_cdc_us;
+  tls_dedup_cdc_us = 0;
+  return v;
+}
+
+static int64_t DedupMonoUs() {
+  struct timespec ts;
+  clock_gettime(CLOCK_MONOTONIC, &ts);
+  return static_cast<int64_t>(ts.tv_sec) * 1000000 + ts.tv_nsec / 1000;
+}
+
+// The native chunker, timed: both plugins cut with it, and the access
+// log's cdc_us column says how much of fp_us it was.
+static std::vector<int64_t> TimedGearChunkStream(const char* data,
+                                                 size_t len) {
+  const int64_t t0 = DedupMonoUs();
+  std::vector<int64_t> cuts = GearChunkStream(
+      reinterpret_cast<const uint8_t*>(data), len, kCdcDefaultMinSize,
+      kCdcDefaultAvgBits, kCdcDefaultMaxSize);
+  tls_dedup_cdc_us += DedupMonoUs() - t0;
+  return cuts;
+}
+
 bool CpuDedup::FingerprintChunks(int64_t /*session*/, const char* data,
                                  size_t len, int64_t base_offset,
                                  std::vector<ChunkFp>* out) {
-  const uint8_t* p = reinterpret_cast<const uint8_t*>(data);
-  std::vector<int64_t> cuts = GearChunkStream(
-      p, len, kCdcDefaultMinSize, kCdcDefaultAvgBits, kCdcDefaultMaxSize);
+  std::vector<int64_t> cuts = TimedGearChunkStream(data, len);
   int64_t last = 0;
   for (int64_t cut : cuts) {
     ChunkFp fp;
@@ -101,20 +132,6 @@ SidecarDedup::SidecarDedup(std::string socket_path)
 
 SidecarDedup::~SidecarDedup() {
   for (int fd : pool_) close(fd);
-}
-
-static thread_local int64_t tls_dedup_lock_wait_us = 0;
-
-int64_t TakeDedupLockWaitUs() {
-  int64_t v = tls_dedup_lock_wait_us;
-  tls_dedup_lock_wait_us = 0;
-  return v;
-}
-
-static int64_t DedupMonoUs() {
-  struct timespec ts;
-  clock_gettime(CLOCK_MONOTONIC, &ts);
-  return static_cast<int64_t>(ts.tv_sec) * 1000000 + ts.tv_nsec / 1000;
 }
 
 int SidecarDedup::AcquireFd(bool* pooled) {
@@ -252,9 +269,7 @@ int64_t SidecarDedup::BeginChunked() {
 bool SidecarDedup::FingerprintChunks(int64_t session, const char* data,
                                      size_t len, int64_t base_offset,
                                      std::vector<ChunkFp>* out) {
-  std::vector<int64_t> cuts = GearChunkStream(
-      reinterpret_cast<const uint8_t*>(data), len, kCdcDefaultMinSize,
-      kCdcDefaultAvgBits, kCdcDefaultMaxSize);
+  std::vector<int64_t> cuts = TimedGearChunkStream(data, len);
   std::string body;
   body.reserve(24 + cuts.size() * 8 + len);
   uint8_t num[8];

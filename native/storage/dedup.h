@@ -185,5 +185,9 @@ std::unique_ptr<DedupPlugin> MakeDedupPlugin(const std::string& mode,
 // upload path reads-and-clears it around its fingerprint calls to
 // attribute the wait per request in the access log.
 int64_t TakeDedupLockWaitUs();
+// The same for the native chunker: time THIS thread spent in
+// GearChunkStream inside FingerprintChunks (either plugin) since the last
+// take; the access log's cdc_us column.
+int64_t TakeDedupCdcUs();
 
 }  // namespace fdfs

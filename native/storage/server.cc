@@ -1712,6 +1712,8 @@ void StorageServer::ResetForNextRequest(Conn* c) {
   c->fp_lock_us = 0;
   c->cswrite_us = 0;
   c->binlog_us = 0;
+  c->cdc_us = 0;
+  c->readback_us = 0;
   c->ingest_session = 0;
   c->ingest_chunks_total = 0;
   c->ingest_chunks_missing = 0;
@@ -1879,12 +1881,17 @@ void StorageServer::LogAccess(Conn* c, uint8_t status, int64_t bytes) {
     std::lock_guard<RankedMutex> lk(log_mu_);
     // "<epoch.sec> <client_ip> <cmd> <status> <bytes> <cost_us>
     //  <recv_us> <work_us> <fp_us> <fp_lock_us> <cswrite_us> <binlog_us>
-    //  <req_bytes>" — per-stage split (SURVEY.md §5): recv = body receive
+    //  <req_bytes> <cdc_us> <dio_wait_us> <readback_us>" — per-stage split
+    // (SURVEY.md §5): recv = body receive
     // window, work = dio-stage time, then the chunked-upload splits
     // inside the work window (fingerprint wall, its sidecar-lock-wait
     // share, chunk-store writes, binlog append); req_bytes = request body
     // size (wire accounting — e.g. chunk-aware replication's savings show
-    // up here).  Columns are 0 when a stage did not occur;
+    // up here); then, appended so that readers by position keep working:
+    // cdc = the native chunker's share of fp, dio_wait = the wait in the
+    // dio queue at the head of the work window, readback = the tmp file
+    // read back segment by segment before each fingerprint call (inside
+    // work, outside fp).  Columns are 0 when a stage did not occur;
     // tools/access_log_stages.py aggregates them into the bench stage
     // table.
     int64_t recv_us =
@@ -1892,7 +1899,8 @@ void StorageServer::LogAccess(Conn* c, uint8_t status, int64_t bytes) {
     int64_t work_us =
         c->work_start_us > 0 ? now_us - c->work_start_us : 0;
     fprintf(access_log_,
-            "%lld %s %d %d %lld %lld %lld %lld %lld %lld %lld %lld %lld\n",
+            "%lld %s %d %d %lld %lld %lld %lld %lld %lld %lld %lld %lld %lld "
+            "%lld %lld\n",
             static_cast<long long>(time(nullptr)), c->peer_ip.c_str(), c->cmd,
             status, static_cast<long long>(bytes),
             static_cast<long long>(now_us - c->req_start_us),
@@ -1902,7 +1910,10 @@ void StorageServer::LogAccess(Conn* c, uint8_t status, int64_t bytes) {
             static_cast<long long>(c->fp_lock_us),
             static_cast<long long>(c->cswrite_us),
             static_cast<long long>(c->binlog_us),
-            static_cast<long long>(c->pkg_len));
+            static_cast<long long>(c->pkg_len),
+            static_cast<long long>(c->cdc_us),
+            static_cast<long long>(c->dio_wait_us),
+            static_cast<long long>(c->readback_us));
   }
   // Spans AFTER the column line: the slow gate's immediate fflush then
   // pushes this request's own access-log record out with the JSON line
@@ -1918,6 +1929,8 @@ void StorageServer::LogAccess(Conn* c, uint8_t status, int64_t bytes) {
   c->fp_lock_us = 0;
   c->cswrite_us = 0;
   c->binlog_us = 0;
+  c->cdc_us = 0;
+  c->readback_us = 0;
   c->heat_key.clear();
   c->heat_op = 0;
 }
@@ -1950,23 +1963,28 @@ void StorageServer::RecordRequestSpans(Conn* c, uint8_t status,
   root.SetName(full);
   trace_->Record(root);
 
-  auto child = [&](const char* name, int64_t start, int64_t dur) {
-    if (dur <= 0) return;
+  // Returns the span's id (0 when the stage did not occur), so that a
+  // stage inside another can name it as `parent`.
+  auto child = [&](const char* name, int64_t start, int64_t dur,
+                   uint32_t parent = 0) -> uint32_t {
+    if (dur <= 0) return 0;
     TraceSpan s;
     s.trace_id = root.trace_id;
     s.span_id = trace_->NextSpanId();
-    s.parent_id = root.span_id;
+    s.parent_id = parent != 0 ? parent : root.span_id;
     s.start_us = start;
     s.dur_us = dur;
     s.flags = root.flags;
     s.SetName(name);
     trace_->Record(s);
+    return s.span_id;
   };
   // recv = body receive window; the dio work window then decomposes into
-  // queue wait -> fingerprint -> chunk-store writes -> binlog
-  // (sequential in the handler, so their spans are laid out
-  // back-to-back).  dio.queue_wait is WAITING, not working — the span
-  // that makes a saturated dio pool visible on an fdfs_trace timeline.
+  // queue wait -> tmp read-back -> fingerprint (the native chunker inside
+  // it) -> chunk-store writes -> binlog (sequential in the handler, per
+  // segment; their sums are laid out back-to-back).  dio.queue_wait is
+  // WAITING, not working — the span that makes a saturated dio pool
+  // visible on an fdfs_trace timeline.
   int64_t recv_us =
       c->recv_done_us > 0 ? c->recv_done_us - c->req_start_us : 0;
   child("storage.recv", wall_start, recv_us);
@@ -1974,8 +1992,10 @@ void StorageServer::RecordRequestSpans(Conn* c, uint8_t status,
                                         ? c->work_start_us - c->req_start_us
                                         : recv_us);
   child("dio.queue_wait", work_wall, c->dio_wait_us);
-  int64_t stage_wall = work_wall + c->dio_wait_us;
-  child("storage.fingerprint", stage_wall, c->fp_us);
+  child("storage.tmp_readback", work_wall + c->dio_wait_us, c->readback_us);
+  int64_t stage_wall = work_wall + c->dio_wait_us + c->readback_us;
+  uint32_t fp_span = child("storage.fingerprint", stage_wall, c->fp_us);
+  child("storage.cdc", stage_wall, c->cdc_us, fp_span);
   child("storage.cs_write", stage_wall + c->fp_us, c->cswrite_us);
   child("storage.binlog", stage_wall + c->fp_us + c->cswrite_us,
         c->binlog_us);
@@ -4416,6 +4436,8 @@ void StorageServer::FinishUpload(Conn* c) {
         c->fp_us = st.fp;
         c->fp_lock_us = st.fp_lock;
         c->cswrite_us = st.cs_write;
+        c->cdc_us = st.cdc;
+        c->readback_us = st.readback;
         NoteTracedMutation(c, parts->RemoteFilename());
         stats_.success_upload++;
         stats_.last_source_update = time(nullptr);
@@ -4592,6 +4614,7 @@ bool StorageServer::ChunkedStoreWith(DedupPlugin* plugin,
   while (ok && seg_base < size) {
     int64_t want = std::min<int64_t>(cfg_.dedup_segment_bytes,
                                      size - seg_base);
+    int64_t t_read = MonoUs();
     seg.resize(static_cast<size_t>(want));
     int64_t got = 0;
     while (got < want) {
@@ -4599,6 +4622,7 @@ bool StorageServer::ChunkedStoreWith(DedupPlugin* plugin,
       if (r <= 0) break;
       got += r;
     }
+    if (stage != nullptr) stage->readback += MonoUs() - t_read;
     if (got != want) {
       ok = false;
       break;
@@ -4608,11 +4632,13 @@ bool StorageServer::ChunkedStoreWith(DedupPlugin* plugin,
     std::vector<ChunkFp> fps;
     int64_t t0 = MonoUs();
     TakeDedupLockWaitUs();  // clear: attribute only this call's wait
+    TakeDedupCdcUs();
     bool fp_ok = plugin->FingerprintChunks(session, seg.data(), seg.size(),
                                            seg_base, &fps);
     if (stage != nullptr) {
       stage->fp += MonoUs() - t0;
       stage->fp_lock += TakeDedupLockWaitUs();
+      stage->cdc += TakeDedupCdcUs();
     }
     if (!fp_ok) {
       ok = false;  // fingerprinting unavailable: caller stores flat

@@ -234,6 +234,8 @@ class StorageServer {
                                 // sidecar RPC mutex (engine serialization)
     int64_t cswrite_us = 0;     // chunk-store writes
     int64_t binlog_us = 0;      // binlog append
+    int64_t cdc_us = 0;         // share of fp_us in the native chunker
+    int64_t readback_us = 0;    // tmp-file read-back, before fp_us
     std::string peer_ip;
     // Negotiated upload (UPLOAD_CHUNKS): the session this request
     // commits, plus the missing/total split RecordRequestSpans turns
@@ -489,11 +491,14 @@ class StorageServer {
   // Per-upload stage attribution (access-log columns; the bench stage
   // table): fingerprint wall time (sidecar RPC incl. lock wait in
   // sidecar mode, serial CDC+SHA1 in cpu mode), the lock-wait share of
-  // it, and chunk-store write time.
+  // it, and chunk-store write time; the native chunker's share of fp,
+  // and the tmp-file read-back that precedes each segment's fingerprint.
   struct ChunkStageUs {
     int64_t fp = 0;
     int64_t fp_lock = 0;
     int64_t cs_write = 0;
+    int64_t cdc = 0;
+    int64_t readback = 0;
   };
   bool StoreChunkedFromTmp(const std::string& tmp_path, int spi,
                            int64_t size, const std::string& rcp_path,

@@ -40,12 +40,16 @@ def test_access_log_lines(tmp_path_factory):
     lines = open(log_path).read().strip().splitlines()
     assert len(lines) >= 2  # upload + download
     # "<ts> <ip> <cmd> <status> <bytes> <cost_us> <recv_us> <work_us>
-    #  <fp_us> <fp_lock_us> <cswrite_us> <binlog_us> <req_bytes>" —
+    #  <fp_us> <fp_lock_us> <cswrite_us> <binlog_us> <req_bytes>
+    #  <cdc_us> <dio_wait_us> <readback_us>" —
     # per-stage split (SURVEY.md §5): recv = body window, work = dio,
-    # then the chunked-upload splits inside the work window.
+    # then the chunked-upload splits inside the work window; the last
+    # three were appended after req_bytes (native chunker inside fp,
+    # dio queue wait and tmp-file read-back inside work).
     for line in lines:
         (ts, ip, cmd, status, nbytes, cost, recv_us, work_us,
-         fp_us, fp_lock_us, cswrite_us, binlog_us, req_bytes) = line.split()
+         fp_us, fp_lock_us, cswrite_us, binlog_us, req_bytes,
+         cdc_us, dio_wait_us, readback_us) = line.split()
         assert int(ts) > 0 and ip == "127.0.0.1"
         assert int(status) == 0 and int(cost) >= 0
         assert int(recv_us) >= 0 and int(work_us) >= 0
@@ -53,6 +57,9 @@ def test_access_log_lines(tmp_path_factory):
         assert int(fp_lock_us) <= int(fp_us) <= int(work_us)
         assert int(cswrite_us) >= 0 and int(binlog_us) >= 0
         assert int(req_bytes) >= 0
+        assert 0 <= int(cdc_us) <= int(fp_us)
+        assert 0 <= int(dio_wait_us) <= int(work_us)
+        assert 0 <= int(readback_us) <= int(work_us)
     cmds = {int(l.split()[2]) for l in lines}
     assert 11 in cmds and 14 in cmds  # UPLOAD_FILE, DOWNLOAD_FILE
 

@@ -331,3 +331,40 @@ def test_slow_request_force_retained_and_logged(tmp_path):
         cli.close()
         storage.stop()
         tracker.stop()
+
+
+@needs_native
+def test_chunked_upload_shows_readback_and_cdc_inside_fingerprint(tmp_path):
+    """A chunked upload's stages on the fdfs_trace timeline and in the
+    access log: the tmp file is read back before the fingerprint stage,
+    and the native chunker runs inside it (``storage.cdc`` is a child of
+    ``storage.fingerprint``, ``cdc_us`` a share of ``fp_us``)."""
+    from fastdfs_tpu.client import StorageClient
+
+    base = os.path.join(str(tmp_path), "st")
+    storage = start_storage(
+        base, dedup_mode="cpu",
+        extra="slow_request_threshold_ms = 1\nuse_access_log = true")
+    try:
+        with StorageClient("127.0.0.1", storage.port) as sc:
+            assert sc.upload_buffer(os.urandom(8 << 20))
+            spans = T.decode_dump(sc.trace_dump())
+        by_name = {s.name: s for s in spans}
+        root = by_name["storage.upload_file"]
+        fp, cdc = by_name["storage.fingerprint"], by_name["storage.cdc"]
+        readback = by_name["storage.tmp_readback"]
+        assert cdc.parent_id == fp.span_id and fp.parent_id == root.span_id
+        assert readback.parent_id == root.span_id
+        assert cdc.start_us == fp.start_us and 0 < cdc.dur_us <= fp.dur_us
+        assert readback.start_us + readback.dur_us == fp.start_us
+        assert by_name["dio.queue_wait"].start_us <= readback.start_us
+    finally:
+        storage.stop()      # flushes the access log
+    with open(os.path.join(base, "logs", "access.log")) as fh:
+        rows = [ln.split() for ln in fh if not ln.startswith("{")]
+    (row,) = [f for f in rows if f[2] == "11"]
+    work_us, fp_us = int(row[7]), int(row[8])
+    cdc_us, dio_wait_us, readback_us = map(int, row[13:16])
+    assert (cdc_us, readback_us) == (cdc.dur_us, readback.dur_us)
+    assert 0 < cdc_us <= fp_us and 0 < readback_us
+    assert dio_wait_us + readback_us + fp_us <= work_us

@@ -6,9 +6,13 @@ The daemon (storage.conf:use_access_log) writes one line per request to
 
     <epoch> <ip> <cmd> <status> <bytes> <cost_us> <recv_us> <work_us>
     <fp_us> <fp_lock_us> <cswrite_us> <binlog_us> <req_bytes>
+    <cdc_us> <dio_wait_us> <readback_us>
 
-(native/storage/server.cc:LogAccess; older 8-column logs parse too, with
-zero stage splits).  This tool answers the question the raw ingest rate
+(native/storage/server.cc:LogAccess; older 8- and 13-column logs parse
+too, with zeros for the stages they lack).  ``cdc_us`` is the native
+chunker's share of ``fp_us``; ``dio_wait_us`` (the wait in the dio queue)
+and ``readback_us`` (the tmp file read back before each fingerprint call)
+lie inside ``work_us``.  This tool answers the question the raw ingest rate
 can't: WHERE does an upload's time go — network receive, fingerprinting
 (and how much of that is queueing on the sidecar's serialized engine),
 chunk-store writes, or the binlog — the attribution SURVEY.md §3.1 marks
@@ -46,6 +50,9 @@ CMD_NAMES = {
 
 STAGES = ["recv_us", "work_us", "fp_us", "fp_lock_us", "cswrite_us",
           "binlog_us"]
+# appended after req_bytes, so they follow it in a line
+LATE_STAGES = ["cdc_us", "dio_wait_us", "readback_us"]
+ALL_STAGES = STAGES + LATE_STAGES
 
 
 def _pct(sorted_vals: list[int], q: float) -> int:
@@ -84,22 +91,22 @@ def aggregate(path: str) -> dict:
                 continue
             try:
                 cmd, status = int(f[2]), int(f[3])
-                nums = [int(x) for x in f[4:13]]
+                nums = [int(x) for x in f[4:16]]
             except ValueError:
                 continue
-            nums += [0] * (9 - len(nums))  # older column counts
+            nums += [0] * (12 - len(nums))  # older column counts
             bytes_, cost = nums[0], nums[1]
-            stages = nums[2:8]
+            stages = nums[2:8] + nums[9:12]
             req_bytes = nums[8]
             d = per_cmd.setdefault(cmd, {
                 "count": 0, "errors": 0, "bytes": 0, "req_bytes": 0,
-                "cost_us": [], **{s: 0 for s in STAGES}})
+                "cost_us": [], **{s: 0 for s in ALL_STAGES}})
             d["count"] += 1
             d["errors"] += status != 0
             d["bytes"] += bytes_
             d["req_bytes"] += req_bytes
             d["cost_us"].append(cost)
-            for name, v in zip(STAGES, stages):
+            for name, v in zip(ALL_STAGES, stages):
                 d[name] += v
     out = {}
     for cmd, d in sorted(per_cmd.items()):
@@ -114,22 +121,28 @@ def aggregate(path: str) -> dict:
             "p50_us": _pct(costs, 0.50),
             "p95_us": _pct(costs, 0.95),
             "p99_us": _pct(costs, 0.99),
-            "stages_s": {s: round(d[s] / 1e6, 3) for s in STAGES},
+            "stages_s": {s: round(d[s] / 1e6, 3) for s in ALL_STAGES},
             # share of total request time per stage ("other" = dispatch,
             # response send, file-id mint, rename, ...)
             "stage_share": {},
         }
         if total_cost > 0:
-            # fp_lock is a subset of fp; work contains fp+cswrite+binlog.
+            # fp_lock and cdc are subsets of fp; work contains dio_wait +
+            # readback + fp + cswrite + binlog.
             # Report the orthogonal decomposition of cost_us.
             recv = d["recv_us"]
             fp = d["fp_us"]
             lock = d["fp_lock_us"]
             cs = d["cswrite_us"]
             bl = d["binlog_us"]
-            other_work = max(d["work_us"] - fp - cs - bl, 0)
+            cdc = d["cdc_us"]
+            wait = d["dio_wait_us"]
+            rb = d["readback_us"]
+            other_work = max(d["work_us"] - fp - cs - bl - wait - rb, 0)
             pre = max(total_cost - d["recv_us"] - d["work_us"], 0)
-            for name, v in [("recv", recv), ("fp_rpc", fp - lock),
+            for name, v in [("recv", recv), ("dio_wait", wait),
+                            ("tmp_readback", rb), ("fp_cdc", cdc),
+                            ("fp_rpc", fp - lock - cdc),
                             ("fp_lock_wait", lock), ("cs_write", cs),
                             ("binlog", bl), ("work_other", other_work),
                             ("dispatch_other", pre)]:
