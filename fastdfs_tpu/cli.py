@@ -1170,8 +1170,9 @@ def cmd_group(c: FdfsClient, args: list[str]) -> int:
 def cmd_sidecar_trace(args: list[str]) -> int:
     """sidecar-trace <socket> --seconds N --out DIR: a JAX profiler trace
     of a running dedup sidecar (device operations and the ``fdfs.*`` spans
-    on one clock), and the spans' wall time and count over those seconds
-    from its ``stats`` reply.  Takes the sidecar's socket, no tracker."""
+    on one clock), and the spans' wall time and count and the receive
+    counters over those seconds from its ``stats`` reply.  Takes the
+    sidecar's socket, no tracker."""
     import time
 
     from fastdfs_tpu.common.protocol import StorageCmd
@@ -1200,6 +1201,15 @@ def cmd_sidecar_trace(args: list[str]) -> int:
           f"{after['requests'] - before['requests']} requests, host stall "
           f"{(after['host_stall_us'] - before['host_stall_us']) / 1e3:.1f} ms,"
           f" device memory peak {after['memory_peak_bytes'] / 1e6:.1f} MB")
+    # calls a body is how often the one-call receive engaged (1 when the
+    # whole body was waited for inside the kernel)
+    bodies = (after["span_n"].get("fdfs.sidecar.parse", 0)
+              - before["span_n"].get("fdfs.sidecar.parse", 0))
+    calls = after["recv_calls"] - before["recv_calls"]
+    print(f"fingerprint bodies: {bodies}, "
+          f"{(after['recv_bytes'] - before['recv_bytes']) / 1e6:.1f} MB "
+          f"received in {calls} recv calls"
+          + (f" ({calls / bodies:.2f} a body)" if bodies else ""))
     print(f"{'span':<28}{'n':>8}{'ms':>12}{'ms/MB':>10}")
     for name in sorted(after["span_us"]):
         n = after["span_n"][name] - before["span_n"].get(name, 0)

@@ -41,7 +41,10 @@ Opcodes
   ``host_stall_us``, counted while a trace runs: wall minus thread CPU
   time over the spans in which a request thread has only Python to run
   (parse, pack, scatter, reply), i.e. the time it waited for the
-  interpreter or a core.  Plus the device this process got (backend,
+  interpreter or a core; ``recv_calls`` / ``recv_bytes``, the
+  ``recv_into`` calls the fingerprint bodies took and their bytes (one
+  call a body when the whole of it is waited for inside the kernel).
+  Plus the device this process got (backend,
   device_kind, device_count, use_pallas, fan_out, device_bytes per
   device id, and memory_peak_bytes, the most the fullest device has
   held) — a reader learns from it whether the chip did the work, which
@@ -96,6 +99,7 @@ from fastdfs_tpu.dedup.engine import DedupConfig, DedupEngine
 from fastdfs_tpu.dedup.spans import mark, new_acc, span
 
 _I64 = struct.Struct(">q")
+_I64X2 = struct.Struct(">qq")
 _FINGERPRINT_CMDS = (StorageCmd.DEDUP_FINGERPRINT,
                      StorageCmd.DEDUP_FINGERPRINT_CUTS)
 
@@ -144,6 +148,16 @@ def read_stats(socket_path: str) -> dict:
     return json.loads(resp)
 
 
+def _cuts_cover(ends: np.ndarray, n: int) -> bool:
+    """Whether the exclusive chunk ends ``ends`` cut a payload of ``n``
+    bytes into non-empty chunks that cover it: first > 0, strictly
+    increasing, last == n; no cuts only for no payload."""
+    if len(ends) == 0 or n == 0:
+        return len(ends) == 0 and n == 0
+    return bool(ends[0] > 0 and ends[-1] == n
+                and (ends[1:] > ends[:-1]).all())
+
+
 def _parse_session(token: str) -> int:
     try:
         return int(token)
@@ -183,7 +197,8 @@ class DedupSidecar:
         self.stats = {"fingerprint_bytes": 0, "chunks": 0, "requests": 0,
                       "lock_wait_us": 0, "engine_us": 0,
                       "verify_host_fallbacks": 0,
-                      "span_us": {}, "span_n": {}, "host_stall_us": 0}
+                      "span_us": {}, "span_n": {}, "host_stall_us": 0,
+                      "recv_calls": 0, "recv_bytes": 0}
         if state_dir:
             self._load_state()
 
@@ -293,15 +308,19 @@ class DedupSidecar:
 
     # -- request handlers --------------------------------------------------
 
-    def _fingerprint(self, body: bytes, with_cuts: bool = False,
+    def _fingerprint(self, body, with_cuts: bool = False,
                      acc: dict | None = None) -> tuple[int, bytes]:
+        """``body`` is any bytes-like object; ``_serve_conn`` hands over a
+        view of its receive buffer, and the payload stays a view of it all
+        the way into the engine's ``np.frombuffer``.  Nothing that outlives
+        this call may refer to it: the buffer is the next request's."""
         if acc is None:
             acc = new_acc()
         with span("fdfs.sidecar.parse", acc, True):
+            body = memoryview(body)
             if len(body) < 16:
                 return 22, b""
-            session_id = _I64.unpack_from(body)[0]
-            base_offset = _I64.unpack_from(body, 8)[0]
+            session_id, base_offset = _I64X2.unpack_from(body)
             cuts = None
             if with_cuts:
                 # DEDUP_FINGERPRINT_CUTS: the daemon already ran the
@@ -311,18 +330,15 @@ class DedupSidecar:
                 n_cuts = _I64.unpack_from(body, 16)[0]
                 if n_cuts < 0 or 24 + 8 * n_cuts > len(body):
                     return 22, b""
-                cuts = [_I64.unpack_from(body, 24 + 8 * i)[0]
-                        for i in range(n_cuts)]
+                ends = np.frombuffer(body, dtype=">i8", count=n_cuts,
+                                     offset=24)
                 data = body[24 + 8 * n_cuts:]
                 # Cuts must exactly cover the payload: an empty cut list
                 # with data would "succeed" with zero chunks and a recipe
                 # covering none of the bytes.
-                if data:
-                    if (not cuts or cuts[-1] != len(data)
-                            or any(c <= p for p, c in zip([0] + cuts, cuts))):
-                        return 22, b""
-                elif cuts:
+                if not _cuts_cover(ends, len(data)):
                     return 22, b""
+                cuts = ends.tolist()
             else:
                 data = body[16:]
         # Pure compute OUTSIDE the lock: engine.fingerprint touches no
@@ -385,6 +401,9 @@ class DedupSidecar:
             n[name] = n.get(name, 0) + acc["span_n"][name]
         acc["span_ns"].clear()
         acc["span_n"].clear()
+        # a fingerprint body's receive, as _serve_conn counted it
+        self.stats["recv_calls"] += acc.pop("recv_calls", 0)
+        self.stats["recv_bytes"] += acc.pop("recv_bytes", 0)
 
     def _query(self, body: bytes) -> tuple[int, bytes]:
         sha1_hex = body.decode("ascii", "replace").strip()
@@ -561,8 +580,8 @@ class DedupSidecar:
         carries the identifiers the daemon minted for the upload."""
         ids = {}
         if cmd in _FINGERPRINT_CMDS and len(body) >= 16:
-            ids = {"session": _I64.unpack_from(body)[0],
-                   "base_offset": _I64.unpack_from(body, 8)[0]}
+            ids = dict(zip(("session", "base_offset"),
+                           _I64X2.unpack_from(body)))
         with span("fdfs.sidecar.request", acc, cmd=cmd, bytes=len(body),
                   **ids):
             if cmd == StorageCmd.DEDUP_FINGERPRINT:
@@ -584,6 +603,13 @@ class DedupSidecar:
             return 22, b""
 
     def _serve_conn(self, conn: socket.socket) -> None:
+        # One receive buffer a connection, grown to the largest body it has
+        # carried and let go with the connection: the daemon keeps at most
+        # kMaxIdleFds (4) idle ones, so that many segments
+        # (dedup_segment_bytes) stay pinned.  A buffer per request cost
+        # 1.5 ms/MB more on the v5e's host, most of it unmapping 64 MB
+        # after every reply (PERF.md section 6, PR 27).
+        kept = bytearray()
         try:
             while not self._stop.is_set():
                 hdr = self._recv_exact(conn, HEADER_SIZE)
@@ -595,14 +621,29 @@ class DedupSidecar:
                 h = unpack_header(hdr)
                 if h.pkg_len < 0 or h.pkg_len > (1 << 31):
                     return
+                if len(kept) < h.pkg_len:
+                    kept = bytearray(h.pkg_len)
+                body = memoryview(kept)[:h.pkg_len]
                 with span("fdfs.sidecar.recv", acc, cmd=h.cmd,
                           bytes=h.pkg_len):
-                    body = (self._recv_exact(conn, h.pkg_len)
-                            if h.pkg_len else b"")
-                if body is None:
+                    calls = self._recv_into(conn, body)
+                if calls is None:
                     return
                 self.stats["requests"] += 1
-                status, resp = self._handle(h.cmd, body, acc)
+                # A fingerprint payload goes to the engine as a view of the
+                # buffer: copied once, by the kernel.  Every other opcode
+                # decodes and splits a body of tens of bytes (verify: a
+                # batch), as bytes.
+                fingerprint = h.cmd in _FINGERPRINT_CMDS
+                if fingerprint:
+                    acc["recv_calls"], acc["recv_bytes"] = calls, h.pkg_len
+                status, resp = self._handle(
+                    h.cmd, body if fingerprint else bytes(body), acc)
+                # The next request overwrites the buffer, so no handler
+                # may keep a view of it: with this one let go, nothing
+                # refers to it between requests (the tests resize it to
+                # show that).
+                body.release()
                 with span("fdfs.sidecar.send", acc, cmd=h.cmd,
                           bytes=len(resp)):
                     conn.sendall(_pack_header(len(resp),
@@ -614,14 +655,31 @@ class DedupSidecar:
             conn.close()
 
     @staticmethod
-    def _recv_exact(conn: socket.socket, n: int) -> bytes | None:
-        buf = bytearray()
-        while len(buf) < n:
-            got = conn.recv(n - len(buf))
-            if not got:
+    def _recv_into(conn: socket.socket, buf: bytearray | memoryview
+                   ) -> int | None:
+        """Fill ``buf`` from the socket: the number of ``recv_into`` calls
+        that took, or None if the peer closed first.  Each call asks for
+        the whole remainder with ``MSG_WAITALL``, so on a blocking socket
+        (an accepted connection is one, whatever the listener's timeout)
+        the thread waits inside the kernel, without the interpreter lock,
+        until the body is complete: one call a body, not one per 200 KB
+        the peer's ``send`` happened to hand over.  A short return (a
+        signal; a socket with a timeout, as ``rpc``'s) loops."""
+        view = memoryview(buf)
+        got = calls = 0
+        while got < len(view):
+            n = conn.recv_into(view[got:], len(view) - got,
+                               socket.MSG_WAITALL)
+            if n == 0:
                 return None
-            buf.extend(got)
-        return bytes(buf)
+            got += n
+            calls += 1
+        return calls
+
+    @classmethod
+    def _recv_exact(cls, conn: socket.socket, n: int) -> bytes | None:
+        buf = bytearray(n)
+        return None if cls._recv_into(conn, buf) is None else bytes(buf)
 
     def _housekeeping_loop(self, snapshot_interval: float) -> None:
         """Snapshot + stale-session reaping on a dedicated timer thread:

@@ -172,25 +172,29 @@ void SidecarDedup::ReleaseFd(int fd) {
 }
 
 bool SidecarDedup::Rpc(uint8_t cmd, const std::string& body, std::string* resp,
-                       uint8_t* status, int64_t max_resp) {
+                       uint8_t* status, int64_t max_resp, const char* tail,
+                       size_t tail_len) {
   // Each RPC borrows its own pooled connection, so concurrent dio
   // threads overlap their sidecar round-trips.  A failure on a POOLED
   // fd retries once on a fresh connection: after a sidecar restart the
   // pool holds up to kMaxIdleFds dead sockets, and without the retry
-  // each of those would fail one upload into the flat-store path.
+  // each of those would fail one upload into the flat-store path.  The
+  // request is header + body + tail, each sent from where it lies, and a
+  // retry sends all three again.
   const int timeout_ms = 60000;
   for (int attempt = 0; attempt < 2; ++attempt) {
     bool pooled = false;
     int fd = AcquireFd(&pooled);
     if (fd < 0) return false;
     uint8_t hdr[kHeaderSize];
-    PutInt64BE(static_cast<int64_t>(body.size()), hdr);
+    PutInt64BE(static_cast<int64_t>(body.size() + tail_len), hdr);
     hdr[8] = cmd;
     hdr[9] = 0;
     // Generous timeout for fingerprint segments (first TPU compile of a
     // new bucket shape can take tens of seconds); the rest is instant.
     if (!SendAll(fd, hdr, sizeof(hdr), timeout_ms) ||
         !SendAll(fd, body.data(), body.size(), timeout_ms) ||
+        !SendAll(fd, tail, tail_len, timeout_ms) ||
         !RecvAll(fd, hdr, sizeof(hdr), timeout_ms)) {
       close(fd);
       if (pooled) continue;  // stale pooled socket: retry fresh
@@ -270,8 +274,10 @@ bool SidecarDedup::FingerprintChunks(int64_t session, const char* data,
                                      size_t len, int64_t base_offset,
                                      std::vector<ChunkFp>* out) {
   std::vector<int64_t> cuts = TimedGearChunkStream(data, len);
+  // The segment is the request's tail: Rpc sends it from the caller's
+  // buffer, so it is not copied here for the sake of one send().
   std::string body;
-  body.reserve(24 + cuts.size() * 8 + len);
+  body.reserve(24 + cuts.size() * 8);
   uint8_t num[8];
   PutInt64BE(session, num);
   body.append(reinterpret_cast<char*>(num), 8);
@@ -283,11 +289,10 @@ bool SidecarDedup::FingerprintChunks(int64_t session, const char* data,
     PutInt64BE(cut, num);
     body.append(reinterpret_cast<char*>(num), 8);
   }
-  body.append(data, len);
   std::string resp;
   uint8_t status = 0;
   if (!Rpc(static_cast<uint8_t>(StorageCmd::kDedupFingerprintCuts), body,
-           &resp, &status, /*max_resp=*/256 << 20) ||
+           &resp, &status, /*max_resp=*/256 << 20, data, len) ||
       status != 0 || resp.size() < 8) {
     FDFS_LOG_WARN("dedup(sidecar): fingerprint unavailable, storing flat");
     return false;

@@ -168,8 +168,11 @@ class SidecarDedup : public DedupPlugin {
   // when the sidecar restarts).  -1 on connect failure.
   int AcquireFd(bool* pooled);
   void ReleaseFd(int fd);   // return a healthy fd to the pool
+  // The request's bytes are `body` then `tail` (a fingerprint segment,
+  // sent from the caller's buffer).
   bool Rpc(uint8_t cmd, const std::string& body, std::string* resp,
-           uint8_t* status, int64_t max_resp = 1 << 20);
+           uint8_t* status, int64_t max_resp = 1 << 20,
+           const char* tail = nullptr, size_t tail_len = 0);
   std::string socket_path_;
   RankedMutex mu_{LockRank::kDedupPool};  // guards pool_
   std::vector<int> pool_;

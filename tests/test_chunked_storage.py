@@ -529,11 +529,19 @@ def test_sidecar_restart_stale_pool_retries_and_still_chunks(tmp_path):
 
         # identical content: if the retry path works, this upload chunks
         # and every byte lands as a dedup hit
-        cli.upload_buffer(data, ext="bin")
+        fid = cli.upload_buffer(data, ext="bin")
         assert _wait(lambda: any(
             int(r.get("dedup_bytes_saved", 0)) >= len(data)
             for r in cli._tracker().list_storages("group1")), timeout=20), \
             "upload after sidecar restart stored flat (stale-fd retry broken)"
+        # The request goes out in three parts (header, ids and cuts, the
+        # segment from the caller's buffer): the retry resent all of them,
+        # so the recipe covers the file and nothing was stored flat.
+        base = os.path.join(str(tmp_path), "st")
+        assert _recipe_for(base, fid) is not None
+        assert _flat_for(base, fid) is None
+        assert cli.download_to_buffer(fid) == data
+        assert "storing flat" not in st.stderr_text + st.stdout_text
     finally:
         cli.close()
         st.stop()

@@ -277,3 +277,9 @@ def test_cli_sidecar_trace_prints_the_deltas(traced):
     assert float(rows["fdfs.engine.fingerprint"][2]) > 0
     assert "MB fingerprinted" in proc.stdout
     assert "device memory peak" in proc.stdout
+    # the receive counters beside the spans: a 70 KB body is one call
+    (recv,) = [ln for ln in proc.stdout.splitlines()
+               if ln.startswith("fingerprint bodies:")]
+    bodies, calls = int(recv.split()[2].rstrip(",")), int(recv.split()[7])
+    assert bodies == int(rows["fdfs.sidecar.parse"][1]) >= 1
+    assert calls == bodies and "(1.00 a body)" in recv
