@@ -1210,6 +1210,18 @@ def cmd_sidecar_trace(args: list[str]) -> int:
           f"{(after['recv_bytes'] - before['recv_bytes']) / 1e6:.1f} MB "
           f"received in {calls} recv calls"
           + (f" ({calls / bodies:.2f} a body)" if bodies else ""))
+    # how the tiles were sized: many on the small rungs means sparse
+    # buckets (small files), most on row_tile means dense ones
+    placed = (sum(after["device_bytes"].values())
+              - sum(before["device_bytes"].values()))
+    tiles = {rows: n - before["tiles_by_rows"].get(rows, 0)
+             for rows, n in after["tiles_by_rows"].items()}
+    print(f"device_bytes: {placed / 1e6:.1f} MB placed in tiles"
+          + (f" ({mb / (placed / 1e6):.3f} useful a shipped byte)"
+             if placed else "")
+          + ", tiles_by_rows: "
+          + (", ".join(f"{n} x {rows}" for rows, n in sorted(
+              tiles.items(), key=lambda t: int(t[0])) if n) or "none"))
     print(f"{'span':<28}{'n':>8}{'ms':>12}{'ms/MB':>10}")
     for name in sorted(after["span_us"]):
         n = after["span_n"][name] - before["span_n"].get(name, 0)

@@ -8,8 +8,9 @@ The directory is placed from outside when ``JAX_COMPILATION_CACHE_DIR``
 is set: JAX reads that variable itself, so nothing is set in code.
 Otherwise it is ONE fixed path inside the checkout.  The path is part of
 the cache key, so a directory built from a temp name, a pid or a time
-would never hit; a fixed one means a second sidecar start skips the six
-bucket shapes x two kernels that ``DedupEngine.warmup`` compiles.
+would never hit; a fixed one means a second sidecar start skips the tile
+shapes (``dedup.engine.plan_shapes``) x two kernels that
+``DedupEngine.warmup`` compiles.
 """
 
 from __future__ import annotations

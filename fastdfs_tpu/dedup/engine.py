@@ -4,14 +4,17 @@ Pipeline per ingested byte stream (north star; replaces the scalar CRC32
 loop in the reference's ``storage/storage_dio.c:dio_write_file()``):
 
     bytes ──CDC (gear, position-parallel)──► chunk spans
-          ──pad to pow2 buckets──► fixed-shape batches (XLA-friendly)
-          ──SHA1 batch + MinHash batch (one jit per bucket shape)──►
+          ──pad to pow2 buckets──► fixed-shape tiles (XLA-friendly)
+          ──SHA1 batch + MinHash batch (one jit per tile shape)──►
           digests + signatures
           ──exact index──► per-chunk write/skip verdicts
           ──LSH index──► file-level near-duplicate candidates
 
-Chunks are padded to power-of-two length buckets so every distinct jitted
-shape is reused across files (XLA traces once per bucket, not per file).
+Chunks are padded to power-of-two length buckets and shipped in tiles
+whose row count comes from a short ladder under ``row_tile``, chosen by
+what the request's buckets hold (``tile_plan``): the shapes are a fixed
+set, all compiled in ``warmup()``, and a sparse bucket does not ship a
+full tile of zeros.
 The file-level MinHash signature is the element-wise min over its chunks'
 signatures — exact for the union of their shingle sets (min of mins), so
 near-dup detection works at file granularity without rehashing the file.
@@ -51,10 +54,11 @@ class DedupConfig:
     lsh_bands: int = 16
     near_dup_threshold: float = 0.5
     near_dup_top_k: int = 5
-    # Fixed row tile per jitted batch: chunks are processed in groups of
-    # exactly this many rows (last group padded), so each pow2 length
-    # bucket compiles exactly ONE XLA shape — a varying chunk count would
-    # otherwise retrace per distinct N and dominate wall-clock.
+    # Rows of a full tile.  A bucket's rows ship in tiles of this many
+    # rows while it has them; a sparse remainder takes the small rung of
+    # _row_ladder (256 -> 32, at the wide widths), so the jitted shapes
+    # stay a fixed set (plan_shapes) — a free row count would retrace
+    # per distinct N and dominate wall-clock.
     row_tile: int = 256
     # None = auto: Pallas kernels on TPU, XLA reference elsewhere.  The
     # two paths are bit-identical (tests/test_pallas_kernels.py).
@@ -64,10 +68,11 @@ class DedupConfig:
     # policies are distinct content-address namespaces (the sidecar
     # discards snapshots on mismatch, same as a spec bump).
     cdc_policy: int = gear_cdc.CDC_POLICY_DEFAULT
-    # Fingerprint fan-out: shard each (row_tile, blen) batch's rows over
+    # Fingerprint fan-out: shard each (rows, blen) tile's rows over
     # this many local devices via parallel.make_fingerprint_step.
     # None = auto (all local devices when >1 and a TPU backend is up;
-    # otherwise 1); 1 = single-device paths.  row_tile must divide by it.
+    # otherwise 1); 1 = single-device paths.  Every tile's row
+    # count (plan_shapes) must divide by it.
     fan_out: int | None = None
 
 
@@ -109,10 +114,127 @@ def _bucket_len(n: int, min_size: int, max_size: int) -> int:
     return min(b, max_size) if n <= max_size else n
 
 
+def _widths(min_size: int, max_size: int) -> list[int]:
+    """The tile widths: every value ``_bucket_len`` gives a chunk no
+    longer than ``max_size``."""
+    widths = [max(min_size, 1)]
+    while widths[-1] < max_size:
+        widths.append(min(widths[-1] << 1, max_size))
+    return widths
+
+
+# What one more tile costs the host whatever its size (two device_put,
+# two kernel launches, the packing fusions' launches), in tile bytes
+# that cost as much.  Measured on the v5e: a tile alone costs 1.05 ms +
+# 0.39 ms per MB (2.7 MB); with two requests in the sidecar at once
+# plans made at 2 and 4 MiB read alike and best, and under the served
+# path a dispatch reads 1.5 ms whatever the tile holds (PERF.md
+# section 6, PR 30).
+_TILE_FIXED_BYTES = 4 << 20
+
+
+def _row_ladder(row_tile: int, blen: int) -> tuple[int, ...]:
+    """Row counts a tile of width ``blen`` may have, largest first:
+    ``row_tile`` (the full tile), and an 8th of it where that is a whole
+    multiple of 8 (so it divides by any fan-out a v5e host has) and saves
+    at least one tile's fixed cost against the full tile: a shape that
+    saves less is two programs to compile for nothing.  At the shipped
+    256: (256, 32) at 32K and 64K, (256,) at the narrower widths; a
+    ``row_tile`` under 64 is the only rung at any width."""
+    small = row_tile // 8
+    if small and small % 8 == 0 and (
+            (row_tile - small) * blen >= _TILE_FIXED_BYTES):
+        return (row_tile, small)
+    return (row_tile,)
+
+
+def _tiles_cost(tiles, blen: int) -> int:
+    return len(tiles) * _TILE_FIXED_BYTES + sum(tiles) * blen
+
+
+def _split_rows(n: int, rungs: tuple[int, ...], blen: int) -> list[int]:
+    """Row counts of the tiles that carry ``n`` rows of width ``blen``:
+    full tiles, and the remainder on small ones or rounded up to one
+    more full tile, whichever costs less (at 64K: 40 rows -> 32 + 32,
+    200 rows -> 256)."""
+    full, rem = divmod(n, rungs[0])
+    tiles = [rungs[0]] * full
+    if rem:
+        small = [rungs[-1]] * -(-rem // rungs[-1])
+        one_full = [rungs[0]]
+        tiles += (small if _tiles_cost(small, blen)
+                  < _tiles_cost(one_full, blen) else one_full)
+    return tiles
+
+
+def _cheapest_tiles(n: int, blen: int, widths: list[int], row_tile: int
+                    ) -> tuple[int, int, list[int]]:
+    """``(cost, width, row counts)`` of the cheapest tiles for ``n`` rows
+    no longer than ``blen``: at ``blen`` or at a wider width that has a
+    smaller rung (24 rows of 16K ride one 32 x 32K tile, 1 MB, and not a
+    256 x 16K one, 4 MB).  A chunk over ``max_size`` has only its own."""
+    options = []
+    for width in [w for w in widths if w >= blen] or [blen]:
+        tiles = _split_rows(n, _row_ladder(row_tile, width), width)
+        options.append((_tiles_cost(tiles, width), width, tiles))
+    return min(options)
+
+
+def tile_plan(lengths, min_size: int, max_size: int, row_tile: int
+              ) -> list[tuple[int, int, list[int]]]:
+    """The tiles one request ships, as ``(rows, blen, chunk indices)``.
+
+    Chunks are grouped by pow2 length bucket and a tile's row count comes
+    from ``_row_ladder`` by the rows there are, so sparse buckets ship a
+    small tile and dense ones full tiles of ``row_tile`` rows.  A chunk
+    may ride in any tile at least as wide as itself (rows are zero past
+    their length and both kernels mask by ``lens``; a word's MinHash
+    segment does not depend on the tile's width), so neighbouring
+    buckets share tiles of the wider width where that is cheaper by
+    ``_tiles_cost``: shipped bytes plus a fixed cost a tile.  Every
+    chunk is placed once and row 0 of every tile is a real chunk.  Pure
+    arithmetic, no JAX.
+    """
+    by_bucket: dict[int, list[int]] = {}
+    for i, ln in enumerate(lengths):
+        by_bucket.setdefault(_bucket_len(ln, min_size, max_size), []).append(i)
+    blens = sorted(by_bucket)
+    widths = _widths(min_size, max_size)
+    # best[j]: the cheapest (cost, groups) for the j narrowest buckets; a
+    # group (i, j, width, row counts) ships buckets i..j-1 together.
+    best: list[tuple[int, list]] = [(0, [])]
+    for j in range(1, len(blens) + 1):
+        n = 0
+        options = []
+        for i in range(j - 1, -1, -1):
+            n += len(by_bucket[blens[i]])
+            cost, width, tiles = _cheapest_tiles(n, blens[j - 1], widths,
+                                                 row_tile)
+            options.append((best[i][0] + cost,
+                            best[i][1] + [(i, j, width, tiles)]))
+        best.append(min(options, key=lambda o: o[0]))
+    plan = []
+    for i, j, width, tiles in best[-1][1]:
+        idxs = [c for b in blens[i:j] for c in by_bucket[b]]
+        start = 0
+        for rows in tiles:
+            plan.append((rows, width, idxs[start:start + rows]))
+            start += rows
+    return plan
+
+
+def plan_shapes(cfg: DedupConfig) -> list[tuple[int, int]]:
+    """Every ``(rows, blen)`` that ``tile_plan`` can emit at this
+    geometry: what ``DedupEngine.warmup`` compiles."""
+    return [(rows, blen) for blen in _widths(cfg.min_size, cfg.max_size)
+            for rows in _row_ladder(cfg.row_tile, blen)]
+
+
 @functools.lru_cache(maxsize=64)
 def _packed_concat(half: int):
     """Jitted (digests..., sigs...) -> one (T, 5+P) array, cached per
-    tile count (segment sizes repeat, so arities do too)."""
+    tile count (segment sizes repeat, so arities do too); the engine
+    hands it tiles of one row count at a time."""
     import jax
     import jax.numpy as jnp
 
@@ -156,9 +278,9 @@ class DedupEngine:
                 fan = len(jax.local_devices())
             else:
                 fan = 1
-        if fan > 1 and self.config.row_tile % fan:
-            raise ValueError(f"row_tile {self.config.row_tile} must divide "
-                             f"by fan_out {fan}")
+        if any(rows % fan for rows, _ in plan_shapes(self.config)):
+            raise ValueError(f"row_tile {self.config.row_tile} and its "
+                             f"smaller tiles must divide by fan_out {fan}")
         # Resolved from the config's None = auto; the sidecar's `stats`
         # reply reports both.  The fan-out step runs the XLA reference
         # kernels under shard_map, so Pallas means the one-device path.
@@ -166,20 +288,25 @@ class DedupEngine:
         self.use_pallas = use_pallas and fan == 1
         self._fp_step = None  # built lazily: jitted multi-device step
         # Batch bytes by the device whose rows they were, read off the
-        # result arrays' own shards: {device id: bytes}.  fingerprint()
-        # runs on many connection threads at once, hence the lock.
+        # result arrays' own shards: {device id: bytes}, and the tiles
+        # placed by their row count: {rows: tiles} (how often the small
+        # rungs engage).  fingerprint() runs on many connection threads
+        # at once, hence the lock.
         self.device_bytes: dict[int, int] = {}
+        self.tiles_by_rows: dict[int, int] = {}
         self._placed_lock = threading.Lock()
 
     def _count_placed(self, result, row_bytes: int) -> None:
+        rows = result.shape[0]
         with self._placed_lock:
+            self.tiles_by_rows[rows] = self.tiles_by_rows.get(rows, 0) + 1
             for shard in result.addressable_shards:
                 dev = shard.device.id
                 self.device_bytes[dev] = (self.device_bytes.get(dev, 0)
                                           + shard.data.shape[0] * row_bytes)
 
     def _fingerprint_batch(self, batch: np.ndarray, lens: np.ndarray):
-        """Dispatch one (row_tile, blen) batch; returns device arrays
+        """Dispatch one (rows, blen) tile; returns device arrays
         (futures) so callers can overlap multiple buckets in flight."""
         cfg = self.config
         if self.fan_out > 1:
@@ -267,15 +394,11 @@ class DedupEngine:
         sigs = np.zeros((len(spans), cfg.num_perms), dtype=np.uint32)
         arr = np.frombuffer(data, dtype=np.uint8)
 
-        # Group chunks by pow2 bucket so each jitted shape is reused.
-        by_bucket: dict[int, list[int]] = {}
-        for i, (off, ln) in enumerate(spans):
-            by_bucket.setdefault(_bucket_len(ln, cfg.min_size, cfg.max_size), []).append(i)
-
-        # Fixed (row_tile, blen) shapes: one compile per bucket, ever.
+        # A fixed set of (rows, blen) shapes, all compiled in warmup().
         # Transfer discipline (every device<->host transfer pays a fixed
-        # latency; what each costs on the v5e's PCIe link is not measured
-        # yet, see PERF.md):
+        # latency, and device_put blocks the caller: 3 ms a 256 x 64K
+        # tile on the v5e, PERF.md section 6, PR 26):
+        #   * tile_plan() sizes each tile by the rows its bucket holds,
         #   * tiles are packed into REUSED thread-local staging buffers,
         #   * all tiles dispatch asynchronously,
         #   * digests and signatures are concatenated ON DEVICE so the
@@ -283,81 +406,85 @@ class DedupEngine:
         # Device memory stays bounded by the segment size the daemon
         # streams (storage.conf:dedup_segment_bytes), not the file size.
         import jax
-        import jax.numpy as jnp
 
-        tile = cfg.row_tile
-        groups: list[list[int]] = []
+        plan = tile_plan([ln for _, ln in spans], cfg.min_size, cfg.max_size,
+                         cfg.row_tile)
         outs_d = []
         outs_s = []
         # Double-buffered staging (ADVICE r5): tiles dispatch
         # asynchronously and are fetched only once at the end, and PJRT
         # host-buffer semantics are backend-dependent — some clients
         # hold the host buffer zero-copy until the transfer completes.
-        # Rotate 2 staging slots per bucket size AND block on the tile
+        # Rotate 2 staging slots per buffer size AND block on the tile
         # that last used a slot before reusing it (its outputs being
         # ready implies its input transfer finished) — rotation alone
         # would still overwrite tile N while in flight once tile N+2
         # claims its slot.  Net effect: a pipeline depth of 2 dispatches
-        # with reused host buffers.  tests/test_dedup_engine.py pins the
-        # digests against the hashlib path on multi-tile input.
+        # with reused host buffers.  Buffers are keyed by their byte
+        # size, so two shapes of one size (256 x 8K, 32 x 64K) share
+        # slots and slot_last is keyed the same way.
+        # tests/test_dedup_engine.py pins the digests against the
+        # hashlib path on multi-tile input.
         _N_STAGING_SLOTS = 2
         slot_last: dict[tuple[int, int], tuple] = {}
-        for blen, idxs in sorted(by_bucket.items()):
-            for tile_no, start in enumerate(range(0, len(idxs), tile)):
-                slot = tile_no % _N_STAGING_SLOTS
-                prev = slot_last.get((blen, slot))
-                if prev is not None:
-                    with span("fdfs.engine.slot_wait", acc):
-                        jax.block_until_ready(prev)
-                group = idxs[start:start + tile]
-                with span("fdfs.engine.pack", acc, True):
-                    batch_buf = gear_cdc.staging_buffer(
-                        tile * blen, slot=slot).reshape(tile, blen)
-                    batch_buf[:] = 0
-                    lens = np.zeros(tile, dtype=np.int32)
-                    for row, i in enumerate(group):
-                        off, ln = spans[i]
-                        batch_buf[row, :ln] = arr[off:off + ln]
-                        lens[row] = ln
-                with span("fdfs.engine.dispatch", acc):
-                    d, s = self._fingerprint_batch(batch_buf, lens)
-                slot_last[(blen, slot)] = (d, s)
-                groups.append(group)
-                outs_d.append(d)
-                outs_s.append(s)
-        # ONE fetched array for the whole segment: digests (T,5) and
-        # signatures (T,P) concatenate along axis 1 (both uint32) so the
-        # fetch pays a single round-trip latency, then split on host.
-        # The concat itself runs as ONE jitted call — as eager ops it
-        # would be ~2 dispatches per tile.
-        with span("fdfs.engine.fetch", acc):
-            packed = np.asarray(jax.device_get(
-                _packed_concat(len(outs_d))(*outs_d, *outs_s)))
-        with span("fdfs.engine.scatter", acc, True):
-            d_all = packed[:, :5]
-            s_all = packed[:, 5:]
-            for gi, group in enumerate(groups):
-                base = gi * tile
+        tiles_of_size: dict[int, int] = {}
+        by_rows: dict[int, list[int]] = {}   # rows -> its tiles' numbers
+        for rows, blen, group in plan:
+            size = rows * blen
+            tile_no = tiles_of_size.get(size, 0)
+            tiles_of_size[size] = tile_no + 1
+            slot = tile_no % _N_STAGING_SLOTS
+            prev = slot_last.get((size, slot))
+            if prev is not None:
+                with span("fdfs.engine.slot_wait", acc):
+                    jax.block_until_ready(prev)
+            with span("fdfs.engine.pack", acc, True):
+                batch_buf = gear_cdc.staging_buffer(
+                    size, slot=slot).reshape(rows, blen)
+                batch_buf[:] = 0
+                lens = np.zeros(rows, dtype=np.int32)
                 for row, i in enumerate(group):
-                    digests[i] = d_all[base + row]
-                    sigs[i] = s_all[base + row]
+                    off, ln = spans[i]
+                    batch_buf[row, :ln] = arr[off:off + ln]
+                    lens[row] = ln
+            with span("fdfs.engine.dispatch", acc):
+                d, s = self._fingerprint_batch(batch_buf, lens)
+            slot_last[(size, slot)] = (d, s)
+            by_rows.setdefault(rows, []).append(len(outs_d))
+            outs_d.append(d)
+            outs_s.append(s)
+        # ONE fetch for the whole segment: the tiles of one row count
+        # concatenate ON DEVICE into one array, digests (T,5) beside
+        # signatures (T,P) (both uint32), in ONE jitted call a row count
+        # (as eager ops it would be ~2 dispatches per tile), and the
+        # arrays, one a row count, come back in one device_get.  A
+        # concat across row counts would be a new program for nearly
+        # every mix of tiles a request can have; this way a program is
+        # keyed by (tiles, rows), as few as before.
+        with span("fdfs.engine.fetch", acc):
+            packed = jax.device_get([
+                _packed_concat(len(ts))(*(outs_d[t] for t in ts),
+                                        *(outs_s[t] for t in ts))
+                for ts in by_rows.values()])
+        with span("fdfs.engine.scatter", acc, True):
+            for (rows, ts), arr in zip(by_rows.items(), packed):
+                for k, t in enumerate(ts):
+                    group = plan[t][2]
+                    out = arr[k * rows:k * rows + len(group)]
+                    digests[group] = out[:, :5]
+                    sigs[group] = out[:, 5:]
         return spans, digests, sigs
 
     def warmup(self) -> None:
-        """Compile every jitted shape the fingerprint path can hit (one
-        per pow2 length bucket) so the first real upload never pays a
-        trace.  Call once at process start (the sidecar does, before it
-        binds its socket)."""
-        cfg = self.config
-        blen = max(cfg.min_size, 1)
-        while True:
-            batch = np.zeros((cfg.row_tile, blen), dtype=np.uint8)
-            lens = np.ones(cfg.row_tile, dtype=np.int32)
+        """Compile every tile shape ``tile_plan`` can emit (every rung at
+        every pow2 length bucket) so no upload ever pays a trace.  Call
+        once at process start (the sidecar does, before it binds its
+        socket)."""
+        for rows, blen in plan_shapes(self.config):
+            batch = np.zeros((rows, blen), dtype=np.uint8)
+            lens = np.ones(rows, dtype=np.int32)
             d, s = self._fingerprint_batch(batch, lens)
             np.asarray(d), np.asarray(s)
-            if blen >= cfg.max_size:
-                break
-            blen = min(blen << 1, cfg.max_size)
 
     # -- stateful ingest ---------------------------------------------------
 

@@ -46,9 +46,10 @@ Opcodes
   call a body when the whole of it is waited for inside the kernel).
   Plus the device this process got (backend,
   device_kind, device_count, use_pallas, fan_out, device_bytes per
-  device id, and memory_peak_bytes, the most the fullest device has
-  held) — a reader learns from it whether the chip did the work, which
-  the daemon's fail-open path would otherwise hide.
+  device id, tiles_by_rows, the tiles placed there by their row count,
+  and memory_peak_bytes, the most the fullest device has held) — a
+  reader learns from it whether the chip did the work, which the
+  daemon's fail-open path would otherwise hide.
   ``trace start <dir>`` / ``trace stop`` start and stop a JAX profiler
   trace of this process (the one that holds the chip) into ``<dir>``:
   device operations and the ``fdfs.*`` spans on one clock.  Both are
@@ -304,7 +305,9 @@ class DedupSidecar:
                     for dev in devs),
                 # dict(): one atomic copy; connection threads add to it
                 "device_bytes": {str(dev): n for dev, n in sorted(
-                    dict(self.engine.device_bytes).items())}}
+                    dict(self.engine.device_bytes).items())},
+                "tiles_by_rows": {str(rows): n for rows, n in sorted(
+                    dict(self.engine.tiles_by_rows).items())}}
 
     # -- request handlers --------------------------------------------------
 

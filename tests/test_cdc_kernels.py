@@ -254,13 +254,18 @@ def test_fingerprint_step_bit_identical_across_mesh_sizes():
         jax.block_until_ready((d, s))
 
 
+# row_tile 8 is one rung; 64 has the rungs (64, 8) once a tile's fixed
+# cost is set to nothing (the shipped 1 MiB leaves these toy widths one).
 @pytest.mark.skipif(not _multi_device(), reason="needs 8 (virtual) devices")
-def test_engine_fan_out_matches_single_device():
+@pytest.mark.parametrize("row_tile", [8, 64])
+def test_engine_fan_out_matches_single_device(monkeypatch, row_tile):
+    from fastdfs_tpu.dedup import engine
     from fastdfs_tpu.dedup.engine import DedupConfig, DedupEngine
 
+    monkeypatch.setattr(engine, "_TILE_FIXED_BYTES", 0)
     rng = np.random.RandomState(4)
     data = rng.randint(0, 256, 20000, dtype=np.uint8).tobytes()
-    geo = dict(min_size=64, avg_bits=8, max_size=256, row_tile=8,
+    geo = dict(min_size=64, avg_bits=8, max_size=256, row_tile=row_tile,
                use_pallas=False)
     fan = DedupEngine(DedupConfig(fan_out=8, **geo))
     one = DedupEngine(DedupConfig(fan_out=1, **geo))
@@ -269,6 +274,18 @@ def test_engine_fan_out_matches_single_device():
     assert spans_f == spans_1
     assert (d_f == d_1).all()
     assert (s_f == s_1).all()
+    # The fan-out path places its tiles on the devices and counts them
+    # by their rows, each device an eighth of every tile; the host path
+    # places nothing.
+    plan = engine.tile_plan([ln for _, ln in spans_f], 64, 256, row_tile)
+    assert len({rows for rows, _, _ in plan}) == len(
+        engine._row_ladder(row_tile, 256))
+    assert fan.tiles_by_rows == {
+        rung: sum(rows == rung for rows, _, _ in plan)
+        for rung in engine._row_ladder(row_tile, 256)}
+    assert fan.device_bytes == {dev: sum(rows * blen for rows, blen, _
+                                         in plan) // 8 for dev in range(8)}
+    assert (one.tiles_by_rows, one.device_bytes) == ({}, {})
 
 
 def test_engine_rejects_indivisible_fan_out():

@@ -83,6 +83,7 @@ def test_stats_reply_carries_the_device_fields(tmp_path):
     assert stats["device_count"] >= 1
     assert stats["use_pallas"] is False and stats["fan_out"] == 1
     assert stats["device_bytes"] == {}  # the host path places nothing
+    assert stats["tiles_by_rows"] == {}
     assert stats["verify_host_fallbacks"] == 0
     assert stats["fingerprint_bytes"] == 0
 

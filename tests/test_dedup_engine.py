@@ -7,8 +7,11 @@ import numpy as np
 import pytest
 
 from fastdfs_tpu.dedup import DedupConfig, DedupEngine
+from fastdfs_tpu.dedup import engine as engine_mod
+from fastdfs_tpu.dedup.engine import plan_shapes, tile_plan
 from fastdfs_tpu.dedup.index import ExactDigestIndex, MinHashLSHIndex
 from fastdfs_tpu.ops import gear_cdc
+from fastdfs_tpu.ops import minhash as M
 
 CFG = DedupConfig(min_size=64, avg_bits=8, max_size=1024)
 
@@ -238,3 +241,179 @@ def test_stale_signature_spec_snapshot_rejected(tmp_path):
         refs=np.array(['"x"'], dtype=object), num_perms=64, bands=16)
     with pytest.raises(ValueError, match="spec-v1"):
         MinHashLSHIndex.load(p)
+
+
+# -- tile plan: a tile has as many rows as its bucket holds ----------------
+
+SHIPPED = DedupConfig()
+
+
+def _np_signature(chunk: bytes, perms: int, k: int) -> np.ndarray:
+    """NumPy MinHash (spec v2) of one chunk, written from the spec and not
+    from the kernels: shingle hashes, 1/256 survivors, the least survivor
+    per (word index mod NUM_SEGMENTS), then the min of a*x+b over those."""
+    buf = np.frombuffer(chunk, np.uint8)
+    n = len(buf)
+    d = np.concatenate([buf, np.zeros(k, np.uint8)]).astype(np.uint32)
+    h = np.zeros(n, np.uint32)
+    with np.errstate(over="ignore"):
+        for j in range(k):
+            h = h * M._POLY_B + d[j:j + n]
+        bound = n - k if n >= k else max(n, 1) - 1
+        pos = np.nonzero((h[:bound + 1] & M.SAMPLE_MASK) == 0)[0]
+        z = np.full(M.NUM_SEGMENTS, M.EMPTY, np.uint32)
+        np.minimum.at(z, (pos // 4) % M.NUM_SEGMENTS, h[pos])
+        z = z[z != M.EMPTY]
+        a, b = M._perm_constants(perms)
+        if not len(z):
+            return np.full(perms, M.EMPTY, np.uint32)
+        return (z[None, :] * a[:, None] + b[:, None]).min(axis=1)
+
+
+def _record_tiles(monkeypatch):
+    """Record the (rows, blen) of every tile an engine dispatches."""
+    seen = []
+    real = DedupEngine._fingerprint_batch
+
+    def recording(self, batch, lens):
+        seen.append(batch.shape)
+        return real(self, batch, lens)
+    monkeypatch.setattr(DedupEngine, "_fingerprint_batch", recording)
+    return seen
+
+
+# row_tile 64 -> rungs (64, 8).  The fixed cost of a tile is set in the
+# test: at these toy widths the shipped 4 MiB would fold every request
+# into one full tile and no small rung would exist.  Rows in the 1024
+# bucket: 1, rung - 1, rung, rung + 1, row_tile + 1; the last case rounds
+# 34 rows up to one full tile because four more tiles would cost more.
+TILE_CASES = {
+    (1, 0): [(8, 1024)],
+    (7, 0): [(8, 128), (8, 1024)],
+    (8, 0): [(8, 128), (8, 1024)],
+    (9, 0): [(8, 1024), (8, 1024)],
+    (65, 0): [(64, 1024), (8, 1024)],
+    (30, 16384): [(64, 1024)],
+}
+
+
+@pytest.mark.parametrize("n_rows,fixed_bytes", sorted(TILE_CASES))
+def test_tiles_sized_by_bucket_rows_match_hashlib_and_numpy(
+        monkeypatch, n_rows, fixed_bytes):
+    monkeypatch.setattr(engine_mod, "_TILE_FIXED_BYTES", fixed_bytes)
+    cfg = DedupConfig(min_size=64, avg_bits=8, max_size=1024, row_tile=64,
+                      use_pallas=False)
+    assert engine_mod._row_ladder(cfg.row_tile, 1024) == (64, 8)
+    rng = np.random.RandomState(100 + n_rows)
+    # n_rows chunks in the 1024 bucket, three in the 128 bucket, one of 3
+    # bytes (shorter than a shingle) in the 64 bucket: the narrow ones
+    # ride in a wider tile wherever its padding rows have room.
+    lens = ([int(x) for x in rng.randint(513, 1025, size=n_rows)]
+            + [100, 128, 65, 3])
+    rng.shuffle(lens)
+    data = _rand(rng, sum(lens))
+    cuts = np.cumsum(lens).tolist()
+    seen = _record_tiles(monkeypatch)
+    spans, digests, sigs = DedupEngine(cfg).fingerprint(data, cuts=cuts)
+    assert [ln for _, ln in spans] == lens
+    raw = digests.astype(">u4").tobytes()
+    for i, (off, ln) in enumerate(spans):
+        chunk = data[off:off + ln]
+        assert raw[i * 20:(i + 1) * 20] == hashlib.sha1(chunk).digest(), i
+        np.testing.assert_array_equal(
+            sigs[i], _np_signature(chunk, cfg.num_perms, cfg.shingle), str(i))
+    assert seen == TILE_CASES[n_rows, fixed_bytes]
+    assert set(seen) <= set(plan_shapes(cfg))
+
+
+def _bucket_lens(counts: dict[int, int]) -> list[int]:
+    """Chunk lengths with ``counts[blen]`` chunks in each pow2 bucket."""
+    rng = np.random.RandomState(5)
+    lens = [int(rng.randint(max(blen // 2, SHIPPED.min_size - 1) + 1, blen + 1))
+            for blen, n in counts.items() for _ in range(n)]
+    rng.shuffle(lens)
+    return lens
+
+
+K = 1024
+# Bucket counts of the reference CDC on seeded random bytes (PERF.md
+# section 6, PR 30) and what such a request may ship at the most: the
+# full-tile plan shipped 23.4 MB for a 200K file and 32.6 MB for 1M.
+PLAN_CASES = {
+    "200K": ({4 * K: 1, 8 * K: 3, 16 * K: 7, 32 * K: 3, 64 * K: 1}, 2.2e6),
+    "200K_no_64K": ({4 * K: 6, 8 * K: 9, 16 * K: 7, 32 * K: 2}, 2.2e6),
+    "1M": ({2 * K: 1, 4 * K: 24, 8 * K: 35, 16 * K: 28, 32 * K: 14,
+            64 * K: 4}, 7e6),
+    "10M": ({2 * K: 1, 4 * K: 227, 8 * K: 314, 16 * K: 318, 32 * K: 159,
+             64 * K: 17}, 26e6),
+    "one_chunk": ({2 * K: 1}, 1e6),
+    "small_16K": ({8 * K: 4, 16 * K: 5}, 1.1e6),   # rides one 32 x 32K tile
+    "dense_64K": ({64 * K: 513}, (512 + 32) * 64 * K),
+}
+
+
+@pytest.mark.parametrize("case", sorted(PLAN_CASES))
+def test_tile_plan_places_every_chunk_once(case):
+    counts, ceiling = PLAN_CASES[case]
+    lens = _bucket_lens(counts)
+    plan = tile_plan(lens, SHIPPED.min_size, SHIPPED.max_size,
+                     SHIPPED.row_tile)
+    placed = sorted(i for _, _, group in plan for i in group)
+    assert placed == list(range(len(lens)))
+    shapes = set(plan_shapes(SHIPPED))
+    for rows, blen, group in plan:
+        assert (rows, blen) in shapes
+        assert 1 <= len(group) <= rows          # row 0 is a real chunk
+        assert all(lens[i] <= blen for i in group)
+    shipped = sum(rows * blen for rows, blen, _ in plan)
+    assert shipped <= ceiling
+    # Never dearer than a full tile a bucket (the plan before PR 30), by
+    # the plan's own cost: shipped bytes + a fixed cost a tile.
+    full = sum(-(-n // SHIPPED.row_tile) for n in counts.values())
+    full_bytes = sum(-(-n // SHIPPED.row_tile) * SHIPPED.row_tile * blen
+                     for blen, n in counts.items())
+    fixed = engine_mod._TILE_FIXED_BYTES
+    assert shipped + len(plan) * fixed <= full_bytes + full * fixed
+    # Padding: under the smallest rung of its width, or less than the
+    # tiles that would avoid it cost (a remainder of 200 rows rounds up
+    # to one full tile: seven small ones would cost more than 56 rows).
+    for rows, blen, group in plan:
+        smallest = engine_mod._row_ladder(SHIPPED.row_tile, blen)[-1]
+        spare = rows - len(group)
+        assert spare < smallest or (
+            spare * blen < -(-len(group) // smallest) * fixed)
+
+
+def test_single_rung_below_64_rows_is_the_plan_before():
+    """row_tile 8 and 16 (this file's other tests, test_cdc_kernels.py)
+    have one rung: whole tiles of row_tile rows."""
+    for row_tile in (8, 16):
+        for blen in (64, 1024, 65536):
+            assert engine_mod._row_ladder(row_tile, blen) == (row_tile,)
+    plan = tile_plan([700] * 40, 64, 1024, 16)
+    assert [(rows, blen, len(g)) for rows, blen, g in plan] == [
+        (16, 1024, 16), (16, 1024, 16), (16, 1024, 8)]
+
+
+def test_warmup_dispatches_exactly_the_shapes_the_plan_can_emit(monkeypatch):
+    seen = []
+    monkeypatch.setattr(
+        DedupEngine, "_fingerprint_batch",
+        lambda self, batch, lens: (
+            seen.append(batch.shape) or
+            (np.zeros((batch.shape[0], 5), np.uint32),
+             np.zeros((batch.shape[0], SHIPPED.num_perms), np.uint32))))
+    DedupEngine(DedupConfig(use_pallas=False)).warmup()
+    assert len(seen) == len(set(seen))
+    emitted = set()
+    widths = sorted({blen for _, blen in seen})
+    rng = np.random.RandomState(11)
+    requests = [[blen] * n for blen in widths
+                for n in (1, 8, 9, 32, 33, 200, 256, 257, 300)]
+    for _ in range(200):    # mixed requests, sparse to dense
+        requests.append([int(rng.choice(widths)) - int(rng.randint(0, 1000))
+                         for _ in range(int(rng.choice([3, 20, 100, 700])))])
+    for lens in requests:
+        emitted |= {(rows, blen) for rows, blen, _ in tile_plan(
+            lens, SHIPPED.min_size, SHIPPED.max_size, SHIPPED.row_tile)}
+    assert emitted == set(seen)

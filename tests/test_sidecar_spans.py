@@ -283,3 +283,5 @@ def test_cli_sidecar_trace_prints_the_deltas(traced):
     bodies, calls = int(recv.split()[2].rstrip(",")), int(recv.split()[7])
     assert bodies == int(rows["fdfs.sidecar.parse"][1]) >= 1
     assert calls == bodies and "(1.00 a body)" in recv
+    # the host path (--platform cpu) places no tile on a device
+    assert "tiles_by_rows: none" in proc.stdout

@@ -5,9 +5,10 @@ Interpret mode (tests/test_pallas_kernels.py) proves the kernels compute
 the right bits; it cannot show what Mosaic refuses: a slice off the
 tiling, too much VMEM, a kernel that cannot be partitioned.  The TPU
 compiler is installed here and compiles for a described topology, so
-these tests ask it, at the widths the sidecar really runs: row_tile 256,
-the smallest and the largest pow2 length bucket, both Pallas kernels,
-the jitted result concat, the XLA SHA-1 the scrubber's DEDUP_VERIFY
+these tests ask it, at the widths the sidecar really runs: row_tile 256
+at the smallest and the largest pow2 length bucket and the smaller tiles
+of the plan (engine.plan_shapes) at their narrowest and widest, both
+Pallas kernels, the jitted result concat, the XLA SHA-1 the scrubber's DEDUP_VERIFY
 jits, and the four-device fan-out step.  Nothing executes, so they say
 nothing about results or speed (chip_smoke.py does, on the chip).
 
@@ -25,7 +26,8 @@ import jax.numpy as jnp
 from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
                           SingleDeviceSharding)
 
-from fastdfs_tpu.dedup.engine import DedupConfig, _packed_concat
+from fastdfs_tpu.dedup.engine import (DedupConfig, _packed_concat,
+                                       plan_shapes)
 from fastdfs_tpu.ops.pallas_minhash import minhash_batch_pallas
 from fastdfs_tpu.ops.pallas_sha1 import sha1_batch_pallas
 from fastdfs_tpu.ops.sha1 import _sha1_padded
@@ -33,9 +35,16 @@ from fastdfs_tpu.parallel.ingest_step import make_fingerprint_step
 
 CFG = DedupConfig()
 ROWS = CFG.row_tile
-# engine.py:_fingerprint_batch picks this from the row count.
-SUB = max(1, min(16, ROWS // 128))
 SMALLEST, LARGEST = CFG.min_size, CFG.max_size
+# The full tile at both ends of the widths, and the tiles under row_tile
+# that the plan ships for sparse buckets, the narrowest and the widest.
+_SMALL = [shape for shape in plan_shapes(CFG) if shape[0] < ROWS]
+TILES = [(ROWS, SMALLEST), (ROWS, LARGEST), _SMALL[0], _SMALL[-1]]
+
+
+def _sub(rows):
+    # engine.py:_fingerprint_batch picks this from the row count.
+    return max(1, min(16, rows // 128))
 
 
 @pytest.fixture(scope="module")
@@ -70,29 +79,29 @@ def _batch(rows, blen, sharding, lens_sharding=None):
                                  sharding=lens_sharding or sharding))
 
 
-@pytest.mark.parametrize("blen", [SMALLEST, LARGEST])
-def test_sha1_pallas_compiles_for_v5e(one_chip, blen):
-    data, lens = _batch(ROWS, blen, one_chip)
+@pytest.mark.parametrize("rows,blen", TILES)
+def test_sha1_pallas_compiles_for_v5e(one_chip, rows, blen):
+    data, lens = _batch(rows, blen, one_chip)
     compiled = sha1_batch_pallas.lower(data, lens, max_len=blen,
-                                       sub=SUB).compile()
+                                       sub=_sub(rows)).compile()
     assert "tpu_custom_call" in compiled.as_text()
 
 
-@pytest.mark.parametrize("blen", [SMALLEST, LARGEST])
-def test_minhash_pallas_compiles_for_v5e(one_chip, blen):
-    data, lens = _batch(ROWS, blen, one_chip)
+@pytest.mark.parametrize("rows,blen", TILES)
+def test_minhash_pallas_compiles_for_v5e(one_chip, rows, blen):
+    data, lens = _batch(rows, blen, one_chip)
     compiled = minhash_batch_pallas.lower(
         data, lens, num_perms=CFG.num_perms, k=CFG.shingle).compile()
     assert "tpu_custom_call" in compiled.as_text()
 
 
 def test_packed_concat_compiles_for_v5e(one_chip):
-    tiles = 3
-    digests = [jax.ShapeDtypeStruct((ROWS, 5), jnp.uint32, sharding=one_chip)
-               ] * tiles
-    sigs = [jax.ShapeDtypeStruct((ROWS, CFG.num_perms), jnp.uint32,
-                                 sharding=one_chip)] * tiles
-    compiled = _packed_concat(tiles).lower(*digests, *sigs).compile()
+    rows = [ROWS] + [r for r, _ in _SMALL[-2:]]  # one segment's mixed tiles
+    digests = [jax.ShapeDtypeStruct((r, 5), jnp.uint32, sharding=one_chip)
+               for r in rows]
+    sigs = [jax.ShapeDtypeStruct((r, CFG.num_perms), jnp.uint32,
+                                 sharding=one_chip) for r in rows]
+    compiled = _packed_concat(len(rows)).lower(*digests, *sigs).compile()
     assert compiled.output_shardings is not None
 
 
