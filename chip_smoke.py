@@ -4,9 +4,9 @@
 Starts what an operator starts (``fdfs_trackerd``, ``fdfs_storaged`` with
 ``dedup_mode = sidecar``, ``python -m fastdfs_tpu.sidecar``), drives it
 through ``fastdfs_tpu.client.FdfsClient`` with a seeded corpus at the
-shipped widths, and checks what comes back against a plain reference
-(``hashlib.sha1`` over the spans the NumPy/serial gear-CDC referees cut)
-computed here in the parent.
+shipped widths, and checks what comes back against the benchmark's plain
+reference (``benchmark/reference.py``: ``hashlib.sha1`` over the spans its
+NumPy and serial gear-CDC chunkers cut), computed here in the parent.
 
 The sidecar is the ONE process that touches the device.  This parent
 imports jax (the package does) but never initialises a backend: the
@@ -46,11 +46,12 @@ sys.path.insert(0, REPO)
 sys.path.insert(0, os.path.join(REPO, "tests"))
 
 import harness  # noqa: E402  (tests/harness.py: the one build + spawn routine)
+from benchmark import reference  # noqa: E402
+from benchmark.run import RecipeReader  # noqa: E402
 from fastdfs_tpu import compile_cache  # noqa: E402
 from fastdfs_tpu.client.client import FdfsClient  # noqa: E402
 from fastdfs_tpu.client.conn import StatusError  # noqa: E402
-from fastdfs_tpu.client.storage_client import StorageClient  # noqa: E402
-from fastdfs_tpu.common.protocol import StorageCmd, pack_group_name  # noqa: E402
+from fastdfs_tpu.common.protocol import StorageCmd  # noqa: E402
 from fastdfs_tpu.dedup.engine import DedupConfig, _bucket_len  # noqa: E402
 from fastdfs_tpu.ops import gear_cdc  # noqa: E402
 from fastdfs_tpu import sidecar as sidecar_mod  # noqa: E402
@@ -127,19 +128,19 @@ def make_corpus(spec: Corpus) -> list[dict]:
     return files
 
 
-def reference_recipe(data: bytes, segment_bytes: int,
-                     chunker=gear_cdc.chunk_stream_np) -> list[tuple[int, bytes]]:
-    """[(length, sha1)] as the daemon must store it: each
-    ``dedup_segment_bytes`` segment is chunked on its own (a segment end
-    is a cut), every chunk hashed with hashlib."""
-    out = []
-    for base in range(0, len(data), segment_bytes):
-        seg = data[base:base + segment_bytes]
-        last = 0
-        for cut in chunker(seg, CFG.min_size, CFG.avg_bits, CFG.max_size):
-            out.append((cut - last, hashlib.sha1(seg[last:cut]).digest()))
-            last = cut
-    return out
+def widths(segment_bytes: int) -> dict:
+    """The shipped widths, as ``benchmark/reference.py`` takes them."""
+    return {"cdc_min_size": CFG.min_size, "cdc_avg_bits": CFG.avg_bits,
+            "cdc_max_size": CFG.max_size,
+            "dedup_segment_bytes": segment_bytes}
+
+
+def serial_segments(data: bytes, w: dict) -> list[tuple[int, list[int]]]:
+    """``reference.segment_cuts`` by the per-byte serial chunker."""
+    seg = w["dedup_segment_bytes"]
+    return [(base, reference.cuts_serial(
+        data[base:base + seg], w["cdc_min_size"], w["cdc_avg_bits"],
+        w["cdc_max_size"])) for base in range(0, len(data), seg)]
 
 
 def _cache_entries(cache_dir: str) -> int:
@@ -155,31 +156,6 @@ def _device_line(stats: dict) -> dict:
 
 
 # -- the one-chip path -------------------------------------------------------
-
-def _fetch_recipe(port: int, file_id: str) -> list[tuple[int, bytes]] | None:
-    """The recipe the daemon stored, over the wire (FETCH_RECIPE, the
-    opcode a rebuilding peer uses); None when the file is stored flat."""
-    group, remote = file_id.split("/", 1)
-    with StorageClient("127.0.0.1", port) as s:
-        s.conn.send_request(StorageCmd.FETCH_RECIPE,
-                            pack_group_name(group) + remote.encode())
-        try:
-            body = s.conn.recv_response("fetch_recipe")
-        except StatusError as e:
-            if e.status == 2:  # ENOENT: flat
-                return None
-            raise
-    logical, count = struct.unpack_from(">qq", body)
-    need(len(body) == 16 + 28 * count, f"recipe body of {file_id} is torn")
-    out = []
-    for i in range(count):
-        off = 16 + 28 * i
-        out.append((struct.unpack_from(">q", body, off + 20)[0],
-                    body[off:off + 20]))
-    need(sum(ln for ln, _ in out) == logical,
-         f"recipe of {file_id} does not cover its {logical} bytes")
-    return out
-
 
 def _verify_batch(sock: str, chunks: list[bytes]) -> tuple[bytes, float]:
     """One DEDUP_VERIFY batch as the scrubber sends it; the LAST expected
@@ -213,9 +189,10 @@ def _served_path(spec: Corpus, sidecar_args, stack) -> dict:
     t0 = time.monotonic()
     files = make_corpus(spec)
     total = sum(len(f["data"]) for f in files)
+    w = widths(spec.segment_bytes)
     for f in files:
         f["eligible"] = len(f["data"]) >= CHUNK_THRESHOLD
-        f["recipe"] = (reference_recipe(f["data"], spec.segment_bytes)
+        f["recipe"] = (reference.recipe(f["data"], w)
                        if f["eligible"] else None)
     eligible_bytes = sum(len(f["data"]) for f in files if f["eligible"])
     buckets: dict[int, int] = {}
@@ -223,13 +200,13 @@ def _served_path(spec: Corpus, sidecar_args, stack) -> dict:
         for ln, _ in f["recipe"] or ():
             b = _bucket_len(ln, CFG.min_size, CFG.max_size)
             buckets[b] = buckets.get(b, 0) + 1
-    # The vectorised NumPy chunker made those cuts; hold it to the serial
-    # per-byte referee on a sample (it is far too slow for the corpus).
+    # The reference's vectorised chunker made those cuts; hold it to its
+    # serial per-byte one on a sample (far too slow for the corpus).
     sampled = 0
     for f in files:
         if f["eligible"] and sampled + len(f["data"]) <= SERIAL_SAMPLE_BYTES:
-            need(reference_recipe(f["data"], spec.segment_bytes,
-                                  gear_cdc.chunk_stream_ref) == f["recipe"],
+            need(reference.recipe(f["data"], w, serial_segments(f["data"], w))
+                 == f["recipe"],
                  f"NumPy and serial CDC referees disagree on {f['name']}")
             sampled += len(f["data"])
     need(sampled > 0, "no file small enough for the serial referee")
@@ -303,8 +280,11 @@ def _served_path(spec: Corpus, sidecar_args, stack) -> dict:
         seconds=round(time.monotonic() - t0, 2))
 
     n_recipes = n_chunks = 0
+    reader = RecipeReader(st.port)
+    stack.callback(reader.close)
     for f in files:
-        stored = _fetch_recipe(st.port, f["id"])
+        # None when stored flat; a torn body reads as an empty recipe
+        stored, logical = reader.fetch(f["id"]) or (None, 0)
         if not f["eligible"]:
             need(stored is None, f"{f['name']} is under the chunk "
                  "threshold yet has a recipe")
@@ -313,6 +293,8 @@ def _served_path(spec: Corpus, sidecar_args, stack) -> dict:
              "stored flat: the daemon fell back around the sidecar")
         need(stored == f["recipe"], f"recipe of {f['name']} differs from "
              "the hashlib/serial-CDC reference")
+        need(logical == len(f["data"]), f"recipe of {f['name']} covers "
+             f"{logical} bytes of {len(f['data'])}")
         n_recipes += 1
         n_chunks += len(stored)
     say("recipes", ok=True, files_compared=n_recipes,
@@ -452,13 +434,14 @@ def run_multichip(seed: int = 0, sidecar_args: tuple[str, ...] = ()) -> int:
 def _multichip_path(seed: int, sidecar_args, stack) -> dict:
     compile_cache.configure()
     rng = np.random.default_rng(seed)
+    w = widths(MULTICHIP_SEGMENT_BYTES)
     segments, want = [], []
     for _ in range(MULTICHIP_SEGMENTS):
         seg = rng.bytes(MULTICHIP_SEGMENT_BYTES)
         cuts = gear_cdc.chunk_stream_np(seg, CFG.min_size, CFG.avg_bits,
                                         CFG.max_size)
         segments.append((seg, cuts))
-        want.append(reference_recipe(seg, MULTICHIP_SEGMENT_BYTES))
+        want.append(reference.recipe(seg, w))
     total = MULTICHIP_SEGMENTS * MULTICHIP_SEGMENT_BYTES
     say("corpus", ok=True, seed=seed, segments=len(segments), bytes=total,
         chunks=sum(map(len, want)))

@@ -1,7 +1,7 @@
 """Where compiled XLA programs are kept between process starts.
 
-Every entry point that jits (the sidecar, bench.py, bench_configs.py,
-chip_smoke.py, the tests' conftest) calls :func:`configure` before its
+Every entry point that jits (the sidecar, chip_smoke.py, the tests'
+conftest) calls :func:`configure` before its
 first compile, and nothing else in the tree names a cache directory.
 
 The directory is placed from outside when ``JAX_COMPILATION_CACHE_DIR``

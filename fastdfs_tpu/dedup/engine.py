@@ -411,7 +411,7 @@ class DedupEngine:
                          cfg.row_tile)
         outs_d = []
         outs_s = []
-        # Double-buffered staging (ADVICE r5): tiles dispatch
+        # Double-buffered staging: tiles dispatch
         # asynchronously and are fetched only once at the end, and PJRT
         # host-buffer semantics are backend-dependent — some clients
         # hold the host buffer zero-copy until the transfer completes.

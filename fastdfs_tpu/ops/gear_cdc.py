@@ -112,8 +112,8 @@ def staging_buffer(size: int, slot: int = 0) -> np.ndarray:
     semantics are backend-dependent (some clients hold the host buffer
     zero-copy until the transfer completes), so a caller that dispatches
     tile N+1 before fetching tile N must rotate >= 2 slots per size or
-    risk overwriting bytes still in flight (ADVICE r5,
-    dedup/engine.py fingerprint()).
+    risk overwriting bytes still in flight (dedup/engine.py
+    fingerprint()).
     """
     bufs = getattr(_staging, "bufs", None)
     if bufs is None:

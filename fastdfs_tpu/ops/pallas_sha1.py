@@ -19,8 +19,7 @@ block, which lets variable-length chunks share one fixed-shape launch.
 
 Bit-exactness vs hashlib and vs the XLA reference is enforced by
 tests/test_pallas_kernels.py (interpret mode on CPU; the real kernel
-runs in bench.py and on the TPU sidecar via
-DedupEngine._fingerprint_batch).
+runs on the TPU sidecar via DedupEngine._fingerprint_batch).
 """
 
 from __future__ import annotations

@@ -68,8 +68,8 @@ class RecoveryManager {
   // The hook reports *chunks_fetched (pulled over the wire) and
   // *chunks_local (satisfied by refs on chunks this node already held)
   // so the recovery counters reflect wire traffic, not recipe sizes
-  // (ADVICE recovery.cc:591 — the old accounting charged every chunk of
-  // every recovered recipe as "pulled").
+  // (the old accounting charged every chunk of every recovered recipe
+  // as "pulled").
   using RecipeRecoverFn = std::function<bool(
       int spi, const std::string& remote, const Recipe& recipe,
       const FetchChunksFn& fetch_chunks, int64_t* chunks_fetched,

@@ -469,7 +469,7 @@ bool StorageServer::Init(std::string* error) {
               else
                 missing.push_back(e);
             }
-            // Honest wire accounting (ADVICE recovery.cc:591): only the
+            // Honest wire accounting: only the
             // misses cross the network; locally-ref'd chunks are the
             // savings the chunk-aware path exists for.
             *chunks_local = static_cast<int64_t>(done.chunks.size());
@@ -1222,8 +1222,8 @@ void StorageServer::RefreshDiskUsedPct() {
                            static_cast<double>(vfs.f_blocks)));
     if (pct > worst) worst = pct;
     // Inodes in use, deduped by filesystem id (two store paths on one
-    // filesystem must not double-count): the store.inodes_used gauge
-    // that the slab-packing bench (config9) reads before/after.
+    // filesystem must not double-count): the store.inodes_used gauge,
+    // which shows what slab packing saves.
     bool dup = false;
     for (unsigned long id : seen_fsids) dup = dup || id == vfs.f_fsid;
     if (!dup) {

@@ -261,8 +261,7 @@ int main(int argc, char** argv) {
   }
   if (cmd == "cdc-bench" && (argc == 5 || argc == 6)) {
     // Times the chunker itself over stdin (repeat passes, best-of),
-    // excluding process startup and pipe reads — the number
-    // bench_configs.py records as chunker_cpp_GBps.
+    // excluding process startup and pipe reads.
     std::string data = ReadStdin();
     const uint8_t* p = reinterpret_cast<const uint8_t*>(data.data());
     int64_t mn = strtoll(argv[2], nullptr, 10);

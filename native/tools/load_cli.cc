@@ -932,7 +932,7 @@ int main(int argc, char** argv) {
   if (!StripGlobalFlags(&argc, argv, &sh)) return 2;
   if (mode == "upload" && argc >= 7 &&
       std::string(argv[3]) == "--small-files") {
-    // Small-file corpus mode (ISSUE 9 / config9): --small-files N
+    // Small-file corpus mode (ISSUE 9): --small-files N
     // --file-bytes B <threads> <result>.  Every payload unique — the
     // worst case for per-object inodes, the best case for slabs.
     if (!SplitAddr(argv[2], &sh.tracker_host, &sh.tracker_port)) return 2;

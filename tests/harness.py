@@ -34,8 +34,8 @@ BINARIES = tuple(os.path.join(BUILD, name) for name in (
 def ensure_native_built(targets: tuple[str, ...] = ()) -> None:
     """Build the native tree (cmake + ninja, every target) unless every
     executable is already there.  The one build routine: the tests (once
-    per session, tests/conftest.py), bench_configs.py and chip_smoke.py
-    all come through here."""
+    per session, tests/conftest.py) and chip_smoke.py both come through
+    here."""
     wanted = (*BINARIES, *targets)
     if all(os.path.exists(t) for t in wanted):
         return

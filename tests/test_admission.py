@@ -28,6 +28,7 @@ import os
 import shutil
 import socket
 import subprocess
+import tempfile
 import time
 
 import pytest
@@ -354,7 +355,20 @@ def _admission(ip, port):
         return M.decode_admission(sc.admission_status())
 
 
+def _disk_reads_full() -> bool:
+    """The daemon's reading of the disk under the tests' store paths
+    (``server.cc``: 1 - f_bavail / f_blocks, space kept from this user
+    counted as used) is over the ``disk_fill_pct`` SLO's 90."""
+    v = os.statvfs(tempfile.gettempdir())
+    return v.f_blocks > 0 and int(100.0 * (1.0 - v.f_bavail / v.f_blocks)) > 90
+
+
 @needs_native
+@pytest.mark.xfail(
+    _disk_reads_full(), strict=True,
+    reason="ROADMAP R0: the disk_fill_pct SLO is breached on this host, one "
+           "breach is pressure 1.0, so the ladder never relaxes: after the "
+           "stalled upload closes, 20 shed-retries are all answered EBUSY")
 def test_live_ladder_sheds_and_recovers(tmp_path, capsys):
     """The acceptance arc: pinned in-flight bytes walk the ladder up one
     rung per tick; background sheds before normal while interactive

@@ -100,12 +100,12 @@ def _start_sidecar(tmp_path, state_dir=None):
     raise TimeoutError("sidecar did not come up")
 
 
-def _cluster(tmp_path, mode, sidecar_sock=""):
+def _cluster(tmp_path, mode, sidecar_sock="", extra=HB):
     tr = start_tracker(os.path.join(str(tmp_path), "tr"))
     st = start_storage(os.path.join(str(tmp_path), "st"),
                        trackers=[f"127.0.0.1:{tr.port}"],
                        dedup_mode=mode, dedup_sidecar=sidecar_sock,
-                       extra=HB)
+                       extra=extra)
     cli = FdfsClient([f"127.0.0.1:{tr.port}"])
     return tr, st, cli
 
@@ -120,7 +120,8 @@ def test_chunked_upload_dedups_and_gc(tmp_path, mode):
     sock = ""
     if mode == "sidecar":
         sidecar, sock = _start_sidecar(tmp_path)
-    tr, st, cli = _cluster(tmp_path, mode, sock)
+    tr, st, cli = _cluster(tmp_path, mode, sock,
+                           extra=HB + "\nuse_access_log = 1")
     st_base = os.path.join(str(tmp_path), "st")
     try:
         a, b = _mk_payloads()
@@ -161,11 +162,21 @@ def test_chunked_upload_dedups_and_gc(tmp_path, mode):
         cli.delete_file(fb)
         assert _wait(lambda: len(chunk_digests(st_base)) == 0)
     finally:
-        st.stop()
+        st.stop()       # flushes the access log
         tr.stop()
         if sidecar is not None:
             sidecar.kill()
             sidecar.wait()
+    # The access log puts a chunked upload's time down to its stages in
+    # either mode: the fingerprint call (column 9, `fp_us`) and the chunk
+    # store's writes (column 11, `cswrite_us`) inside the dio work.
+    with open(os.path.join(st_base, "logs", "access.log")) as fh:
+        rows = [ln.split() for ln in fh if not ln.startswith("{")]
+    uploads = [r for r in rows if r[2:4] == ["11", "0"]]
+    assert len(uploads) == 2, uploads
+    for row in uploads:
+        work_us, fp_us, cswrite_us = int(row[7]), int(row[8]), int(row[10])
+        assert 0 < fp_us <= work_us and 0 < cswrite_us <= work_us, row
 
 
 def test_restart_rebuilds_refcounts_and_collects_orphans(tmp_path):
@@ -550,7 +561,7 @@ def test_sidecar_restart_stale_pool_retries_and_still_chunks(tmp_path):
 
 
 def test_sidecar_stats_over_the_socket_name_the_device(tmp_path):
-    """The `stats` reply read the way bench_configs.py and chip_smoke.py
+    """The `stats` reply read the way chip_smoke.py and the benchmark
     read it (fastdfs_tpu.sidecar.read_stats): besides the counters it
     says what the process runs on, so a reader can tell a sidecar on
     the chip from one forced onto the host path."""

@@ -32,7 +32,7 @@ def test_fingerprint_digests_match_hashlib():
 
 
 def test_fingerprint_multi_tile_digests_match_hashlib():
-    """Backend-pinning regression (ADVICE r5): dispatch MANY tiles per
+    """Backend-pinning regression: dispatch MANY tiles per
     bucket so the rotated staging buffers are reused across
     asynchronously-dispatched batches — if a backend ever holds the host
     buffer zero-copy past dispatch, a reused buffer would corrupt an

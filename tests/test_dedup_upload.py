@@ -346,6 +346,41 @@ def test_negotiated_upload_falls_back_without_chunk_store(tmp_path_factory):
 
 
 @needs_native
+def test_tail_edited_reupload_ships_only_the_changed_chunks(tmp_path_factory):
+    """Cold, warm and tail-edited passes over one blob: the cold pass
+    ships it all, the warm pass nothing, and a blob whose last eighth was
+    rewritten ships strictly between the two: the head's chunks are
+    found, the tail's are sent."""
+    tracker = start_tracker(tmp_path_factory.mktemp("tr"))
+    taddr = f"127.0.0.1:{tracker.port}"
+    storage = start_storage(tmp_path_factory.mktemp("st"), trackers=[taddr],
+                            dedup_mode="cpu", extra=HB)
+    cli = FdfsClient([taddr])
+    blob = os.urandom(256 * 1024)
+    edited = blob[:-len(blob) // 8] + os.urandom(len(blob) // 8)
+    try:
+        upload_retry(cli, b"warmup " * 64, ext="bin")
+        sent = {}
+        for name, data in (("cold", blob), ("warm", blob), ("edited", edited)):
+            stats = {}
+            fid = cli.upload_buffer_dedup(data, ext="bin", min_dup_ratio=0,
+                                          stats=stats)
+            assert stats["fallback"] == "", (name, stats)
+            assert cli.download_to_buffer(fid) == data
+            sent[name] = stats["bytes_sent"]
+        assert sent["cold"] >= len(blob)
+        assert sent["warm"] == 0
+        assert len(blob) // 8 <= sent["edited"] < len(blob) // 2, sent
+        c, _ = _ingest_counters("127.0.0.1", storage.port)
+        assert c["ingest.recipe_uploads"] == 3
+        assert c["ingest.bytes_saved_wire"] >= len(blob) + len(blob) // 2
+    finally:
+        cli.close()
+        storage.stop()
+        tracker.stop()
+
+
+@needs_native
 def test_upload_session_timeout_releases_pins(tmp_path_factory):
     """A client that sends UPLOAD_RECIPE and vanishes must not leak pins:
     chunks it held present survive a concurrent delete only until the

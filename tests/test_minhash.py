@@ -125,3 +125,94 @@ def test_estimate_jaccard_shape():
     b = np.zeros((3, 64), dtype=np.uint32)
     out = np.asarray(M.estimate_jaccard(a, b))
     assert out.shape == (3,) and np.all(out == 1.0)
+
+
+# ---------------------------------------------------------------------------
+# recall referee: retrieval against ground truth, and against a witness
+# ---------------------------------------------------------------------------
+
+def _crawl(n_base=8, n_docs=32, L=64 << 10, seed=4):
+    """Base pages, near-duplicate variants and bait.  truth[i] is the base
+    a variant must retrieve (-1: not a query).  Variants are a base with
+    0.5% of its bytes rewritten, in 16-byte spans or as scattered single
+    bytes (each damages ``shingle`` shingles: the worst case a byte);
+    distractors are a base's tokens reshuffled: the same vocabulary and
+    almost no shared 5-grams, never a right answer."""
+    import random
+    rng, nprng = random.Random(seed), np.random.RandomState(seed)
+    words = [f"tok{j}" for j in range(8000)]
+    docs = np.zeros((n_docs, L), dtype=np.uint8)
+    truth = np.full(n_docs, -1)
+    for b in range(n_base):
+        body = " ".join(rng.choices(words, k=L // 8))
+        docs[b] = np.frombuffer(
+            (f"<html><body>{body}</body></html>".encode() + b" " * L)[:L],
+            dtype=np.uint8)
+    for i in range(n_base, n_docs):
+        b, kind = rng.randrange(n_base), rng.random()
+        row = docs[b].copy()
+        if kind < 0.4:
+            for _ in range(L // (200 * 16)):
+                at = nprng.randint(0, L - 16)
+                row[at:at + 16] = nprng.randint(97, 123, 16, dtype=np.uint8)
+            truth[i] = b
+        elif kind < 0.8:
+            at = nprng.choice(L, size=L // 200, replace=False)
+            row[at] = nprng.randint(97, 123, len(at), dtype=np.uint8)
+            truth[i] = b
+        else:
+            toks = bytes(row).split(b" ")
+            rng.shuffle(toks)
+            row = np.frombuffer((b" ".join(toks) + b" " * L)[:L],
+                                dtype=np.uint8)
+        docs[i] = row
+    return docs, truth
+
+
+def _textbook_minhash(docs, num_perms=64, shingle=5, seed=99):
+    """The textbook formulation (one universal hash a permutation over the
+    exact shingle set, one min each) in plain NumPy: no code, spec or hash
+    family shared with ``ops.minhash``'s survivor sketch, so agreement
+    between the two rankings is a finding, not an identity."""
+    rng = np.random.RandomState(seed)
+    p = np.uint64((1 << 61) - 1)
+    # a < 2^23 keeps a*x + b under 2^64 for 40-bit shingles
+    a = rng.randint(1, 1 << 23, size=num_perms).astype(np.uint64)
+    b = rng.randint(0, 1 << 61, size=num_perms).astype(np.uint64)
+    sigs = np.zeros((len(docs), num_perms), dtype=np.uint64)
+    for i, doc in enumerate(docs):
+        row = doc.astype(np.uint64)
+        x = np.zeros(len(row) - shingle + 1, dtype=np.uint64)
+        for k in range(shingle):
+            x |= row[k:len(row) - shingle + 1 + k] << np.uint64(8 * k)
+        y = a[:, None] * np.unique(x)[None, :] + b[:, None]
+        y = (y >> np.uint64(61)) + (y & p)          # mod the Mersenne prime
+        sigs[i] = np.where(y >= p, y - p, y).min(axis=1)
+    return sigs
+
+
+def test_lsh_recall_against_truth_and_a_textbook_minhash():
+    from fastdfs_tpu.dedup.index import MinHashLSHIndex
+
+    n_base = 8
+    docs, truth = _crawl(n_base)
+    lens = np.full(len(docs), docs.shape[1], dtype=np.int32)
+    sigs = np.asarray(M.minhash_batch(docs, lens))
+    queries = [int(q) for q in np.nonzero(truth >= 0)[0]]
+    assert len(queries) >= 12 and (truth[n_base:] < 0).sum() >= 2  # bait is in
+    idx = MinHashLSHIndex(64, 16)
+    for d in range(len(docs)):
+        if d not in queries:        # bases and distractors; variants only ask
+            idx.add(sigs[d], d)
+    top = {q: [ref for ref, _ in idx.query(sigs[q], top_k=5,
+                                           min_similarity=0.0)]
+           for q in queries}
+    recall1 = sum(top[q][:1] == [truth[q]] for q in queries) / len(queries)
+    recall5 = sum(truth[q] in top[q] for q in queries) / len(queries)
+    assert recall5 >= recall1 >= 0.98
+    witness = _textbook_minhash(docs)
+    agree = sum(
+        top[q][:1] == [int(np.argmax(
+            (witness[:n_base] == witness[q]).mean(axis=1)))]
+        for q in queries) / len(queries)
+    assert agree >= 0.98

@@ -10,8 +10,9 @@ Layers:
 - live clusters: the full corruption lifecycle (inject bit-rot ->
   scrub detects -> quarantine -> repair from the replica -> download is
   byte-identical), the single-replica unrepairable case, zero-ref GC
-  after DELETE_FILE, the recipe-sidecar delete satellite, and a
-  scrub-vs-traffic race (the TSan target in tools/run_sanitizers.sh).
+  after DELETE_FILE, the recipe-sidecar delete satellite, a
+  scrub-vs-traffic race (the TSan target in tools/run_sanitizers.sh),
+  and the scrubber's own timer, paced and unpaced, under foreground IO.
 """
 
 import os
@@ -410,5 +411,52 @@ def test_scrub_races_uploads_and_deletes(tmp_path):
         for fid, data in list(survivors.items())[:5]:
             assert cli.download_to_buffer(fid) == data
     finally:
+        st.stop()
+        tr.stop()
+
+
+@needs_native
+@pytest.mark.parametrize("mode,conf", [
+    ("off", "scrub_interval_s = 0"),
+    ("paced", "scrub_interval_s = 1\nscrub_bandwidth_mb_s = 16"),
+    ("unpaced", "scrub_interval_s = 1\nscrub_bandwidth_mb_s = 0")])
+def test_timed_passes_verify_under_foreground_io(tmp_path, mode, conf):
+    """No kick: the scrubber's own timer re-verifies the chunk store while
+    uploads, downloads and deletes run, paced or not, and flags nothing
+    that is sound; with the timer off nothing is verified."""
+    from fastdfs_tpu.client import FdfsClient
+
+    tmp = str(tmp_path)
+    tr = start_tracker(os.path.join(tmp, "tr"))
+    st = start_storage(os.path.join(tmp, "st"),
+                       trackers=[f"127.0.0.1:{tr.port}"],
+                       dedup_mode="cpu", extra=HB + "\n" + conf)
+    cli = FdfsClient([f"127.0.0.1:{tr.port}"])
+    try:
+        upload_retry(cli, b"warmup" * 64)
+        kept = {}
+        for _ in range(4):
+            data = os.urandom(256 << 10)
+            kept[cli.upload_buffer(data, ext="bin")] = data
+        deadline = time.time() + 3.0
+        ops = 0
+        while time.time() < deadline or ops < 10:
+            data = os.urandom(64 << 10)
+            fid = cli.upload_buffer(data, ext="bin")
+            assert cli.download_to_buffer(fid) == data
+            cli.delete_file(fid)
+            ops += 1
+        status = cli.scrub_status("127.0.0.1", st.port)
+        if mode == "off":
+            assert status["passes"] == 0 and status["chunks_verified"] == 0
+        else:
+            assert status["passes"] >= 1, status
+            assert status["chunks_verified"] > 0
+            assert status["bytes_verified"] > 0
+        assert status["chunks_corrupt"] == 0, status
+        for fid, data in kept.items():
+            assert cli.download_to_buffer(fid) == data
+    finally:
+        cli.close()
         st.stop()
         tr.stop()

@@ -30,8 +30,7 @@ additionally interleaves one compact-JSON line per slow request:
 ``cli.py trace --trace-id`` command that drills into each one.
 
 Usage:  python tools/access_log_stages.py <access.log> [--json] [--slow]
-Import: ``aggregate(path) -> dict``  (bench_configs embeds the result in
-its artifacts); ``slow_requests(path) -> list[dict]``.
+Import: ``aggregate(path) -> dict``; ``slow_requests(path) -> list[dict]``.
 """
 
 from __future__ import annotations
