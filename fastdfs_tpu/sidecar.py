@@ -607,11 +607,11 @@ class DedupSidecar:
 
     def _serve_conn(self, conn: socket.socket) -> None:
         # One receive buffer a connection, grown to the largest body it has
-        # carried and let go with the connection: the daemon keeps at most
-        # kMaxIdleFds (4) idle ones, so that many segments
-        # (dedup_segment_bytes) stay pinned.  A buffer per request cost
-        # 1.5 ms/MB more on the v5e's host, most of it unmapping 64 MB
-        # after every reply (PERF.md section 6, PR 27).
+        # carried and let go with the connection: the daemon keeps as many
+        # idle ones as it has dio workers (dedup.h, max_idle_fds_), so that
+        # many segments (dedup_segment_bytes) can stay pinned.  A buffer per
+        # request cost 1.5 ms/MB more on the v5e's host, most of it
+        # unmapping 64 MB after every reply (PERF.md section 6, PR 27).
         kept = bytearray()
         try:
             while not self._stop.is_set():

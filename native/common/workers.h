@@ -8,6 +8,7 @@
 // posted back to the owning connection's EventLoop.
 #pragma once
 
+#include <algorithm>
 #include <chrono>
 #include <condition_variable>
 #include <deque>
@@ -24,6 +25,40 @@
 #include "common/threadreg.h"
 
 namespace fdfs {
+
+// How many workers ONE store path's dio pool gets (storage.conf:
+// disk_writer_threads).  A positive `configured` is the operator's pin
+// and is taken as it stands (config.cc holds it to kDioWorkersCap).  0
+// derives the number from what the daemon can observe: the node's total
+// is the host's cores, and the store paths divide it, because the pools
+// are per path and what loads the host is their sum.
+//
+// Why the cores, and not upstream's constant two: a worker here mostly
+// waits (a sidecar round trip, a recipe's fsync, a slab's lock), so two
+// of them queue every upload behind the two before it.  The curve the
+// rule was read from (PERF.md section 6, PR 32; upstream_mix.updown on
+// a 13-core v5e host, ten closed-loop callers, MB/s at 2 / 4 / 6 / 8 /
+// 10 / 12 / 13 / 16 / 24 workers): 36.8 / 49.1 / 53.2 / 52.5 / 51.0 /
+// 49.7 / 53.0 / 48.2 / 54.9.  The knee is at 4-6; from 6 on the callers
+// bind, not the pool, and nothing is lost up to 24, so any number on the
+// plateau would do THERE.  The cores are the number that also holds on
+// another host: what a worker does when it does not wait is CPU work
+// (CDC, copies, cpu mode's SHA-1), and more runnable workers than cores
+// cannot add any.  backup_node.ingest (four callers) reads 197-213 at
+// every point.
+//
+// kDioWorkersFloor is what shipped before (a path never gets fewer, so
+// no host gets a narrower pool than it had, and a count the platform
+// does not know, 0, lands there); kDioWorkersCap bounds the threads and
+// the memory a pin or a very wide host can ask for (a worker on a
+// chunked upload holds one segment: OPERATIONS.md, "Host memory").
+constexpr int kDioWorkersFloor = 2;
+constexpr int kDioWorkersCap = 64;
+inline int DioWorkersPerPath(int configured, unsigned cores, int store_paths) {
+  if (configured > 0) return configured;
+  const int node_total = std::min(static_cast<int>(cores), kDioWorkersCap);
+  return std::max(node_total / std::max(store_paths, 1), kDioWorkersFloor);
+}
 
 class WorkerPool {
  public:

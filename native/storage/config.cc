@@ -1,5 +1,7 @@
 #include "storage/config.h"
 
+#include "common/workers.h"  // kDioWorkersCap
+
 namespace fdfs {
 
 bool StorageConfig::Load(const IniConfig& ini, std::string* error) {
@@ -57,10 +59,12 @@ bool StorageConfig::Load(const IniConfig& ini, std::string* error) {
   nio_reuseport = ini.GetBool("nio_reuseport", nio_reuseport);
   disk_writer_threads = static_cast<int>(
       ini.GetInt("disk_writer_threads", disk_writer_threads));
-  if (disk_writer_threads < 1) disk_writer_threads = 1;
-  if (disk_writer_threads > 64) {
-    note("disk_writer_threads clamped to 64");
-    disk_writer_threads = 64;
+  // 0 = derived at start from the host's cores and the store paths
+  // (workers.h:DioWorkersPerPath); a positive value pins it.
+  if (disk_writer_threads < 0) disk_writer_threads = 0;
+  if (disk_writer_threads > kDioWorkersCap) {
+    note("disk_writer_threads clamped to " + std::to_string(kDioWorkersCap));
+    disk_writer_threads = kDioWorkersCap;
   }
   max_connections =
       static_cast<int>(ini.GetInt("max_connections", max_connections));

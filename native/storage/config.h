@@ -38,8 +38,10 @@ struct StorageConfig {
   bool nio_reuseport = true;
   // dio pool size PER STORE PATH (reference storage.conf:
   // disk_writer_threads / storage_dio.c): chunk-store writes,
-  // fingerprint RPCs, trunk allocation, and deletes run here.
-  int disk_writer_threads = 2;
+  // fingerprint RPCs, trunk allocation, and deletes run here.  0 = the
+  // daemon derives it at start from the host's cores and the number of
+  // store paths (common/workers.h:DioWorkersPerPath).
+  int disk_writer_threads = 0;
   // Accept-time connection cap (reference storage.conf:max_connections /
   // fast_task_queue.c — the task-buffer pool is the bound upstream; here
   // the cap is explicit).  Past the cap the daemon answers one EBUSY

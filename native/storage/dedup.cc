@@ -6,6 +6,7 @@
 #include <time.h>
 #include <unistd.h>
 
+#include <algorithm>
 #include <atomic>
 #include <cstdio>
 
@@ -127,8 +128,9 @@ bool CpuDedup::LoadSnapshot() {
 
 // -- SidecarDedup ---------------------------------------------------------
 
-SidecarDedup::SidecarDedup(std::string socket_path)
-    : socket_path_(std::move(socket_path)) {}
+SidecarDedup::SidecarDedup(std::string socket_path, int max_idle_fds)
+    : socket_path_(std::move(socket_path)),
+      max_idle_fds_(std::max(max_idle_fds, kMinIdleFds)) {}
 
 SidecarDedup::~SidecarDedup() {
   for (int fd : pool_) close(fd);
@@ -164,7 +166,7 @@ int SidecarDedup::AcquireFd(bool* pooled) {
 
 void SidecarDedup::ReleaseFd(int fd) {
   std::lock_guard<RankedMutex> lk(mu_);
-  if (static_cast<int>(pool_.size()) >= kMaxIdleFds) {
+  if (static_cast<int>(pool_.size()) >= max_idle_fds_) {
     close(fd);
     return;
   }
@@ -177,7 +179,7 @@ bool SidecarDedup::Rpc(uint8_t cmd, const std::string& body, std::string* resp,
   // Each RPC borrows its own pooled connection, so concurrent dio
   // threads overlap their sidecar round-trips.  A failure on a POOLED
   // fd retries once on a fresh connection: after a sidecar restart the
-  // pool holds up to kMaxIdleFds dead sockets, and without the retry
+  // pool holds up to max_idle_fds_ dead sockets, and without the retry
   // each of those would fail one upload into the flat-store path.  The
   // request is header + body + tail, each sent from where it lies, and a
   // retry sends all three again.
@@ -393,13 +395,15 @@ bool SidecarDedup::VerifyChunks(const std::vector<ChunkFp>& chunks,
 
 std::unique_ptr<DedupPlugin> MakeDedupPlugin(const std::string& mode,
                                              const std::string& base_path,
-                                             const std::string& sidecar_path) {
+                                             const std::string& sidecar_path,
+                                             int sidecar_idle_conns) {
   if (mode == "cpu") {
     auto p = std::make_unique<CpuDedup>(base_path + "/data/dedup_index.dat");
     p->LoadSnapshot();
     return p;
   }
-  if (mode == "sidecar") return std::make_unique<SidecarDedup>(sidecar_path);
+  if (mode == "sidecar")
+    return std::make_unique<SidecarDedup>(sidecar_path, sidecar_idle_conns);
   return nullptr;  // none
 }
 

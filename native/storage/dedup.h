@@ -137,7 +137,11 @@ class CpuDedup : public DedupPlugin {
 // unchunkable) when the sidecar is unreachable.
 class SidecarDedup : public DedupPlugin {
  public:
-  explicit SidecarDedup(std::string socket_path);
+  // max_idle_fds: how many idle connections the pool keeps; the daemon
+  // passes its dio workers' total, so that no worker's connection is
+  // closed behind it (kMinIdleFds at least: nio threads, the scrubber
+  // and recovery make RPCs too).
+  SidecarDedup(std::string socket_path, int max_idle_fds);
   ~SidecarDedup() override;
   Verdict Judge(const std::string& sha1_hex, int64_t file_size) override;
   void Commit(const std::string& sha1_hex, const std::string& file_id) override;
@@ -161,8 +165,8 @@ class SidecarDedup : public DedupPlugin {
   // concurrent dio threads overlap their sidecar round-trips instead of
   // serializing on one shared connection (the sidecar itself only
   // serializes index mutation, not fingerprint compute).  Up to
-  // kMaxIdleFds idle connections are retained.
-  static constexpr int kMaxIdleFds = 4;
+  // max_idle_fds_ idle connections are retained.
+  static constexpr int kMinIdleFds = 4;
   // *pooled reports whether the fd came from the idle pool (a failure
   // on it retries once on a fresh connection — pooled sockets go stale
   // when the sidecar restarts).  -1 on connect failure.
@@ -174,13 +178,17 @@ class SidecarDedup : public DedupPlugin {
            uint8_t* status, int64_t max_resp = 1 << 20,
            const char* tail = nullptr, size_t tail_len = 0);
   std::string socket_path_;
+  const int max_idle_fds_;
   RankedMutex mu_{LockRank::kDedupPool};  // guards pool_
   std::vector<int> pool_;
 };
 
+// sidecar_idle_conns: see SidecarDedup's constructor (other modes
+// ignore it).
 std::unique_ptr<DedupPlugin> MakeDedupPlugin(const std::string& mode,
                                              const std::string& base_path,
-                                             const std::string& sidecar_path);
+                                             const std::string& sidecar_path,
+                                             int sidecar_idle_conns = 0);
 
 // Thread-local sidecar lock-wait accounting: SidecarDedup adds the time
 // THIS thread spent queued on the connection-pool mutex (connection

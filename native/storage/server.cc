@@ -155,7 +155,17 @@ bool StorageServer::Init(std::string* error) {
     acfg.retry_after_ms = cfg_.admission_retry_after_ms;
     admission_ = std::make_unique<AdmissionController>(acfg);
   }
-  dedup_ = MakeDedupPlugin(cfg_.dedup_mode, cfg_.base_path, cfg_.dedup_sidecar);
+  // The dio pools' size is fixed here, at start (workers.h has the
+  // rule), because the fingerprint plugin's idle connections follow it:
+  // every worker may be inside the sidecar at once, and a connection
+  // closed for want of room costs the next RPC a new sidecar thread and
+  // a cold receive buffer.
+  dio_workers_per_path_ =
+      DioWorkersPerPath(cfg_.disk_writer_threads,
+                        std::thread::hardware_concurrency(),
+                        store_.store_path_count());
+  dedup_ = MakeDedupPlugin(cfg_.dedup_mode, cfg_.base_path, cfg_.dedup_sidecar,
+                           dio_workers_per_path_ * store_.store_path_count());
   if (dedup_ != nullptr && cfg_.dedup_chunk_threshold > 0) {
     // Chunk-level dedup: one content-addressed store per store path;
     // refcounts rebuilt from recipes (doubles as orphan GC).
@@ -230,8 +240,7 @@ bool StorageServer::Init(std::string* error) {
   }
   for (int i = 0; i < store_.store_path_count(); ++i)
     dio_pools_.push_back(std::make_unique<WorkerPool>(
-        cfg_.disk_writer_threads, "dio.worker",
-        i * cfg_.disk_writer_threads));
+        dio_workers_per_path_, "dio.worker", i * dio_workers_per_path_));
 
   // Trace ring before the registry (its gauges read the ring) and before
   // the sync/recovery subsystems (they record spans into it).
@@ -876,6 +885,12 @@ void StorageServer::InitStatsRegistry() {
     int64_t n = 0;
     for (const auto& p : dio_pools_) n += static_cast<int64_t>(p->pending());
     return n;
+  });
+  // The node's total over all store paths: what disk_writer_threads = 0
+  // derived at start, or the operator's pin times the paths.
+  registry_.GaugeFn("dio.workers", [this] {
+    return static_cast<int64_t>(dio_workers_per_path_) *
+           static_cast<int64_t>(dio_pools_.size());
   });
   // Flight-recorder health: throughput and ring-overwrite pressure.
   registry_.GaugeFn("events.recorded", [this] {

@@ -582,6 +582,7 @@ class StorageServer {
   // dio pools, one per store path (storage.conf:disk_writer_threads;
   // reference: storage_dio.c per-path reader/writer queues).
   std::vector<std::unique_ptr<WorkerPool>> dio_pools_;
+  int dio_workers_per_path_ = 0;  // fixed in Init (workers.h: the rule)
   RankedMutex busy_mu_{LockRank::kBusyFiles};
   std::unordered_set<std::string> busy_files_;  // remote names being mutated
   RankedMutex log_mu_{LockRank::kAccessLog};  // access_log_ writes

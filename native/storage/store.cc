@@ -23,18 +23,20 @@ bool StoreManager::Init(const StorageConfig& cfg, std::string* error) {
     any_fresh_ = true;
     // Pre-create the two-level fan-out (reference:
     // storage_make_data_dirs()).
+    // std::string: a store path may be longer than any fixed buffer.
     for (int i = 0; i < subdir_count_; ++i) {
-      char sub[64];
-      std::snprintf(sub, sizeof(sub), "%s/%02X", data.c_str(), i);
+      char hex[8];
+      std::snprintf(hex, sizeof(hex), "/%02X", i);
+      const std::string sub = data + hex;
       if (!MakeDirs(sub)) {
-        *error = std::string("mkdir ") + sub + ": " + strerror(errno);
+        *error = "mkdir " + sub + ": " + strerror(errno);
         return false;
       }
       for (int j = 0; j < subdir_count_; ++j) {
-        char sub2[80];
-        std::snprintf(sub2, sizeof(sub2), "%s/%02X", sub, j);
-        if (mkdir(sub2, 0755) != 0 && errno != EEXIST) {
-          *error = std::string("mkdir ") + sub2 + ": " + strerror(errno);
+        std::snprintf(hex, sizeof(hex), "/%02X", j);
+        const std::string sub2 = sub + hex;
+        if (mkdir(sub2.c_str(), 0755) != 0 && errno != EEXIST) {
+          *error = "mkdir " + sub2 + ": " + strerror(errno);
           return false;
         }
       }

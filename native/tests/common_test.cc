@@ -406,6 +406,29 @@ static void TestWorkerPoolQueueStats() {
   CHECK(wait->sum() >= 30000);  // the queued tasks sat behind the sleeper
 }
 
+static void TestDioWorkersPerPath() {
+  // A positive value is the operator's pin: taken as it stands, whatever
+  // the host and however many store paths.
+  CHECK_EQ(DioWorkersPerPath(1, 64, 1), 1);
+  CHECK_EQ(DioWorkersPerPath(2, 1, 4), 2);
+  CHECK_EQ(DioWorkersPerPath(5, 128, 7), 5);
+  // 0 derives: the node's total follows the cores...
+  CHECK_EQ(DioWorkersPerPath(0, 8, 1), 8);
+  CHECK_EQ(DioWorkersPerPath(0, 13, 1), 13);
+  // ... between the floor (a one-core host, or a count the platform
+  // does not know) and the cap ...
+  CHECK_EQ(DioWorkersPerPath(0, 1, 1), kDioWorkersFloor);
+  CHECK_EQ(DioWorkersPerPath(0, 0, 1), kDioWorkersFloor);
+  CHECK_EQ(DioWorkersPerPath(0, 1024, 1), kDioWorkersCap);
+  // ... and the store paths divide it (a pool a path: the node's total
+  // is what is bounded), each keeping the floor.
+  CHECK_EQ(DioWorkersPerPath(0, 16, 2), 8);
+  CHECK_EQ(DioWorkersPerPath(0, 16, 3), 5);
+  CHECK_EQ(DioWorkersPerPath(0, 4, 4), kDioWorkersFloor);
+  CHECK_EQ(DioWorkersPerPath(0, 1024, 2), kDioWorkersCap / 2);
+  CHECK_EQ(DioWorkersPerPath(0, 13, 0), 13);  // no path count yet: one
+}
+
 static void TestStatsRegistryPruneGauges() {
   StatsRegistry reg;
   reg.SetGauge("sync.peer.10.0.0.2:23000.lag_s", 4);
@@ -1199,6 +1222,7 @@ int main(int argc, char** argv) {
   TestEventLogThreaded();
   TestEventLoopLagHook();
   TestWorkerPoolQueueStats();
+  TestDioWorkersPerPath();
   TestStatsRegistryPruneGauges();
   TestRankedMutex();
   TestRankedMutexThreaded();

@@ -111,12 +111,17 @@ def test_work_thread_configs(tmp_path, threads):
         tr.stop()
 
 
-def test_parallel_uploads_all_land(tmp_path):
-    # many concurrent client connections across the nio threads
+@pytest.mark.parametrize("pool, clients", [
+    ("", 6),                                # the derived dio pool
+    ("\ndisk_writer_threads = 16", 12),     # pinned wider than the clients
+], ids=["derived", "wide"])
+def test_parallel_uploads_all_land(tmp_path, pool, clients):
+    # many concurrent client connections across the nio threads, and as
+    # many chunked uploads past the receive stage as the dio pool is wide
     tr = start_tracker(str(tmp_path / "tr"))
     st = start_storage(str(tmp_path / "st"),
                        trackers=[f"127.0.0.1:{tr.port}"],
-                       dedup_mode="cpu", extra=HB)
+                       dedup_mode="cpu", extra=HB + pool)
     taddr = f"127.0.0.1:{tr.port}"
     try:
         upload_retry(FdfsClient([taddr]), b"warm" * 100, ext="bin")
@@ -128,7 +133,7 @@ def test_parallel_uploads_all_land(tmp_path):
             fid = c.upload_buffer(data, ext="bin")
             return fid, c.download_to_buffer(fid) == data
 
-        with concurrent.futures.ThreadPoolExecutor(max_workers=6) as ex:
+        with concurrent.futures.ThreadPoolExecutor(clients) as ex:
             results = list(ex.map(one, payloads))
         assert all(ok for _, ok in results)
         assert len({fid for fid, _ in results}) == len(payloads)
