@@ -23,6 +23,14 @@ _TRUE = {"1", "yes", "true", "on"}
 _FALSE = {"0", "no", "false", "off"}
 
 
+def parse_bytes(text: str) -> int:
+    """``256KB``, ``64M``, ``4G``, ``512`` → bytes."""
+    m = re.fullmatch(r"(\d+)\s*([A-Za-z]*)", text.strip())
+    if not m or m.group(2).upper() not in _SIZE_SUFFIX:
+        raise ValueError(f"bad size: {text!r}")
+    return int(m.group(1)) * _SIZE_SUFFIX[m.group(2).upper()]
+
+
 class IniConfig:
     """Parsed config: every key maps to a list of values in file order."""
 
@@ -111,10 +119,10 @@ class IniConfig:
         v = self.get(key)
         if v is None or v == "":
             return default
-        m = re.fullmatch(r"(\d+)\s*([A-Za-z]*)", v)
-        if not m or m.group(2).upper() not in _SIZE_SUFFIX:
-            raise ValueError(f"bad size for {key}: {v!r}")
-        return int(m.group(1)) * _SIZE_SUFFIX[m.group(2).upper()]
+        try:
+            return parse_bytes(v)
+        except ValueError:
+            raise ValueError(f"bad size for {key}: {v!r}") from None
 
     def get_seconds(self, key: str, default: int = 0) -> int:
         """Parse durations like ``30``, ``5m``, ``1h``, ``1d``."""

@@ -5,8 +5,9 @@ conftest) calls :func:`configure` before its
 first compile, and nothing else in the tree names a cache directory.
 
 The directory is placed from outside when ``JAX_COMPILATION_CACHE_DIR``
-is set: JAX reads that variable itself, so nothing is set in code.
-Otherwise it is ONE fixed path inside the checkout.  The path is part of
+is set: JAX reads that variable itself, so no directory is set in code.
+Otherwise it is ONE fixed path inside the checkout.  Either way every
+program is kept, whatever it took to compile.  The path is part of
 the cache key, so a directory built from a temp name, a pid or a time
 would never hit; a fixed one means a second sidecar start skips the tile
 shapes (``dedup.engine.plan_shapes``) x two kernels that
@@ -23,14 +24,17 @@ DEFAULT_DIR = os.path.join(_REPO, ".jax_cache")
 
 def configure() -> str:
     """Point JAX's persistent compilation cache; returns the directory."""
+    import jax
+
+    # Cache everything, wherever the directory is: the Pallas kernels
+    # compile in 0.7 to 2 s each, under JAX's default 1 s threshold as
+    # often as over it, and a program under it is compiled again at
+    # every start (sixteen of them: 6.5 s of warm-up on the v5e, PERF.md
+    # section 6, PR 33).
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
     placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if placed:
         return placed
-    import jax
-
     jax.config.update("jax_compilation_cache_dir", DEFAULT_DIR)
-    # Cache everything: the Pallas kernels compile in a second or two
-    # each, under JAX's default 1 s threshold as often as over it.
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
     return DEFAULT_DIR

@@ -11,10 +11,12 @@ loop in the reference's ``storage/storage_dio.c:dio_write_file()``):
           ──LSH index──► file-level near-duplicate candidates
 
 Chunks are padded to power-of-two length buckets and shipped in tiles
-whose row count comes from a short ladder under ``row_tile``, chosen by
-what the request's buckets hold (``tile_plan``): the shapes are a fixed
-set, all compiled in ``warmup()``, and a sparse bucket does not ship a
-full tile of zeros.
+whose row count comes from a short ladder under ``row_tile`` and under
+one byte bound (``_TILE_MAX_BYTES``: at chunk widths of megabytes a tile
+holds a few rows), chosen by what the request's buckets hold
+(``tile_plan``): the shapes are a fixed set that follows from the
+configured widths, all compiled in ``warmup()``, and a sparse bucket does
+not ship a full tile of zeros.
 The file-level MinHash signature is the element-wise min over its chunks'
 signatures — exact for the union of their shingle sets (min of mins), so
 near-dup detection works at file granularity without rehashing the file.
@@ -54,11 +56,12 @@ class DedupConfig:
     lsh_bands: int = 16
     near_dup_threshold: float = 0.5
     near_dup_top_k: int = 5
-    # Rows of a full tile.  A bucket's rows ship in tiles of this many
-    # rows while it has them; a sparse remainder takes the small rung of
-    # _row_ladder (256 -> 32, at the wide widths), so the jitted shapes
-    # stay a fixed set (plan_shapes) — a free row count would retrace
-    # per distinct N and dominate wall-clock.
+    # Rows of a full tile, where the tile's byte bound allows as many
+    # (_row_ladder).  A bucket's rows ship in full tiles while it has
+    # them; a sparse remainder takes the small rung (256 -> 32, at the
+    # wide widths), so the jitted shapes stay a fixed set (plan_shapes) —
+    # a free row count would retrace per distinct N and dominate
+    # wall-clock.
     row_tile: int = 256
     # None = auto: Pallas kernels on TPU, XLA reference elsewhere.  The
     # two paths are bit-identical (tests/test_pallas_kernels.py).
@@ -133,19 +136,37 @@ def _widths(min_size: int, max_size: int) -> list[int]:
 _TILE_FIXED_BYTES = 4 << 20
 
 
+# The most bytes a tile may hold: a full tile has ``row_tile`` rows only
+# while that many fit.  The widest tile the shipped widths make is
+# 256 x 64 KiB = 16 MiB, well under it; at chunk widths of megabytes
+# (512 KiB - 8 MiB, what backup tools cut) it is what keeps a tile, its
+# staging buffer and the words the SHA-1 step packs from growing with
+# ``row_tile`` x ``max_size`` (2 GiB there).  Eight rows of the widest
+# chunk the daemon may send (``max_size`` <= ``dedup_segment_bytes``,
+# shipped 64M) would be over it, so ``DedupEngine`` refuses a
+# ``max_size`` of which it does not hold eight rows.
+_TILE_MAX_BYTES = 64 << 20
+
+
 def _row_ladder(row_tile: int, blen: int) -> tuple[int, ...]:
-    """Row counts a tile of width ``blen`` may have, largest first:
-    ``row_tile`` (the full tile), and an 8th of it where that is a whole
-    multiple of 8 (so it divides by any fan-out a v5e host has) and saves
-    at least one tile's fixed cost against the full tile: a shape that
-    saves less is two programs to compile for nothing.  At the shipped
-    256: (256, 32) at 32K and 64K, (256,) at the narrower widths; a
-    ``row_tile`` under 64 is the only rung at any width."""
-    small = row_tile // 8
-    if small and small % 8 == 0 and (
-            (row_tile - small) * blen >= _TILE_FIXED_BYTES):
-        return (row_tile, small)
-    return (row_tile,)
+    """Row counts a tile of width ``blen`` may have, largest first.  The
+    full tile: ``row_tile`` rows, or as many as ``_TILE_MAX_BYTES`` holds
+    at this width (whole groups of 8).  The small rung: an 8th of the
+    full one, 8 rows at the least, where that is a whole multiple of 8
+    (so it divides by any fan-out a v5e host has) and saves at least one
+    tile's fixed cost against the full tile: a shape that saves less is
+    two programs to compile for nothing.  At the shipped 256: (256, 32) at
+    32K and 64K, (256,) at the narrower widths; at 512K - 8M: (128, 16),
+    (64, 8), (32, 8), (16, 8), (8,); a ``row_tile`` under 64 is the only
+    rung at any width up to 170K."""
+    full = min(row_tile, max(1, _TILE_MAX_BYTES // blen))
+    if full < row_tile and full > 8:
+        full -= full % 8
+    small = max(8, full // 8)
+    if small < full and small % 8 == 0 and (
+            (full - small) * blen >= _TILE_FIXED_BYTES):
+        return (full, small)
+    return (full,)
 
 
 def _tiles_cost(tiles, blen: int) -> int:
@@ -278,6 +299,13 @@ class DedupEngine:
                 fan = len(jax.local_devices())
             else:
                 fan = 1
+        if not 0 < self.config.min_size < self.config.max_size:
+            raise ValueError(f"chunk widths: min_size {self.config.min_size} "
+                             f"must be under max_size {self.config.max_size}")
+        if self.config.max_size * min(8, self.config.row_tile) > _TILE_MAX_BYTES:
+            raise ValueError(
+                f"max_size {self.config.max_size}: a tile holds "
+                f"{_TILE_MAX_BYTES} bytes, under eight rows of that width")
         if any(rows % fan for rows, _ in plan_shapes(self.config)):
             raise ValueError(f"row_tile {self.config.row_tile} and its "
                              f"smaller tiles must divide by fan_out {fan}")
@@ -294,6 +322,13 @@ class DedupEngine:
         # at once, hence the lock.
         self.device_bytes: dict[int, int] = {}
         self.tiles_by_rows: dict[int, int] = {}
+        # Every tile's SHA-1 launch, summed (the arguments of its
+        # fdfs.engine.dispatch span): rows that held a chunk, the lanes
+        # the kernel's layout gives the tile, and the 64-byte blocks it
+        # walks one after another (ops/pallas_sha1.py:launch_geometry;
+        # the host path launches nothing and counts the same arithmetic).
+        self.launched = {"rows_placed": 0, "lanes_launched": 0,
+                         "sha1_grid_steps": 0}
         self._placed_lock = threading.Lock()
 
     def _count_placed(self, result, row_bytes: int) -> None:
@@ -330,15 +365,21 @@ class DedupEngine:
             import jax
 
             from fastdfs_tpu.ops.pallas_minhash import minhash_batch_pallas
-            from fastdfs_tpu.ops.pallas_sha1 import sha1_batch_pallas
+            from fastdfs_tpu.ops.pallas_sha1 import (default_sub,
+                                                     sha1_batch_pallas)
+            rows, blen = batch.shape
+            # The rows cross as the words they already are on the host (a
+            # view, no copy): packing bytes into words on the device is a
+            # four-fold widening and a relayout that pads few rows to 128.
+            if blen % 4 == 0:
+                batch = batch.view(np.uint32)
             # ONE explicit transfer shared by both kernels: passing the
             # numpy batch to each jit would transfer it once per kernel.
             batch = jax.device_put(batch)
             lens = jax.device_put(lens)
-            sub = max(1, min(16, batch.shape[0] // 128))
-            d = sha1_batch_pallas(batch, lens, int(batch.shape[1]), sub=sub)
+            d = sha1_batch_pallas(batch, lens, blen, sub=default_sub(rows))
             s = minhash_batch_pallas(batch, lens, cfg.num_perms, cfg.shingle)
-            self._count_placed(d, batch.shape[1])
+            self._count_placed(d, blen)
         else:
             # Host path: hashlib per row.  The XLA sha1_batch exists as the
             # jittable reference (tests/test_sha1.py) but its 80-round
@@ -407,6 +448,8 @@ class DedupEngine:
         # streams (storage.conf:dedup_segment_bytes), not the file size.
         import jax
 
+        from fastdfs_tpu.ops.pallas_sha1 import launch_geometry
+
         plan = tile_plan([ln for _, ln in spans], cfg.min_size, cfg.max_size,
                          cfg.row_tile)
         outs_d = []
@@ -447,8 +490,14 @@ class DedupEngine:
                     off, ln = spans[i]
                     batch_buf[row, :ln] = arr[off:off + ln]
                     lens[row] = ln
-            with span("fdfs.engine.dispatch", acc):
+            lanes, blocks = launch_geometry(rows, blen)
+            with span("fdfs.engine.dispatch", acc, rows=len(group),
+                      lanes=lanes, blen=blen, blocks=blocks):
                 d, s = self._fingerprint_batch(batch_buf, lens)
+            with self._placed_lock:
+                self.launched["rows_placed"] += len(group)
+                self.launched["lanes_launched"] += lanes
+                self.launched["sha1_grid_steps"] += blocks
             slot_last[(size, slot)] = (d, s)
             by_rows.setdefault(rows, []).append(len(outs_d))
             outs_d.append(d)
@@ -476,10 +525,11 @@ class DedupEngine:
         return spans, digests, sigs
 
     def warmup(self) -> None:
-        """Compile every tile shape ``tile_plan`` can emit (every rung at
-        every pow2 length bucket) so no upload ever pays a trace.  Call
-        once at process start (the sidecar does, before it binds its
-        socket)."""
+        """Compile every tile shape ``tile_plan`` can emit at the
+        configured widths (every rung at every pow2 length bucket, each
+        under the tile's byte bound) so no upload ever pays a trace.
+        Call once at process start (the sidecar does, before it binds
+        its socket)."""
         for rows, blen in plan_shapes(self.config):
             batch = np.zeros((rows, blen), dtype=np.uint8)
             lens = np.ones(rows, dtype=np.int32)

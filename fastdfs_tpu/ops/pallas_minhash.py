@@ -8,7 +8,10 @@ hashes;
 this kernel keeps everything in registers and emits only the tiny
 ``(8, 128)`` survivor plane per chunk.
 
-Layout: one chunk per grid step.  The chunk's bytes are viewed as a
+Layout: one chunk per grid step while its plane fits one block, and
+``_BLOCK_ROWS`` rows of it a step beyond that (rows over 64 KiB: the
+grid's second axis walks the chunk and the survivor plane is revisited,
+one min a step).  The chunk's bytes are viewed as a
 ``(R, 128)`` plane of little-endian uint32 words (position-major:
 word ``q`` sits at row ``q // 128``, lane ``q % 128``).  Byte windows
 are rebuilt from aligned words only — each shingle phase ``r`` (byte
@@ -47,27 +50,38 @@ LANE = 128
 _BIAS = np.int32(np.uint32(0x80000000).astype(np.int64) - (1 << 32))  # -2^31
 
 
-def _survivor_kernel(k: int, R: int):
-    """Kernel over one chunk: words (1, R, 128) u32 + len (1, 1) i32 →
-    biased survivor plane (1, 8, 128) i32."""
+# Rows of the word plane a grid step holds at the most: 64 KiB of chunk,
+# the widest block the shipped widths ever had.
+_BLOCK_ROWS = 128
+
+
+def _survivor_kernel(k: int, R: int, steps: int):
+    """Kernel over ``R`` rows of one chunk's word plane, step ``j`` of
+    ``steps``: words (1, R, 128) u32 + len → biased survivor plane
+    (1, 8, 128) i32, the min over the steps.  With more than one step the
+    first 8 rows of the next block come in as a halo (the successor of a
+    block's last word)."""
     if k != 5:
         raise NotImplementedError("survivor kernel is specialized to k=5")
 
-    def kernel(lens_ref, w_ref, out_ref):
+    def kernel(lens_ref, w_ref, *rest):
+        out_ref = rest[-1]
         W = w_ref[0]                                   # (R, 128) uint32
         ln = lens_ref[pl.program_id(0)]
+        j = pl.program_id(1)
 
         # W1[q] = W[q+1] in flattened row-major word order: lane roll -1,
         # with lane 127 taking the next row's lane 0 (row+lane roll).
+        nxt = W[:1, :] if steps == 1 else rest[0][0][:1, :]
         r1 = jnp.concatenate([W[:, 1:], W[:, :1]], axis=1)
-        rr = jnp.concatenate([W[1:, :], W[:1, :]], axis=0)
+        rr = jnp.concatenate([W[1:, :], nxt], axis=0)
         r01 = jnp.concatenate([rr[:, 1:], rr[:, :1]], axis=1)
         lane = jax.lax.broadcasted_iota(jnp.int32, (R, LANE), 1)
         W1 = jnp.where(lane < LANE - 1, r1, r01)
         # Wrapped garbage in the last word's windows only reaches
         # positions p >= 4*NW - 4 > len - k, which the mask excludes.
 
-        row = jax.lax.broadcasted_iota(jnp.int32, (R, LANE), 0)
+        row = jax.lax.broadcasted_iota(jnp.int32, (R, LANE), 0) + j * R
         q4 = (row * LANE + lane) * 4                   # byte position of r=0
         # Valid positions are p <= bound (scalar select only: Mosaic has no
         # vector-of-bool select): complete shingles, or the degenerate
@@ -92,8 +106,19 @@ def _survivor_kernel(k: int, R: int):
             hb = h.astype(jnp.int32) ^ _BIAS           # biased unsigned order
             m = jnp.minimum(m, jnp.where(surv, hb, jnp.int32(0x7FFFFFFF)))
 
-        # segment = word q mod NUM_SEGMENTS = 128 * (row mod 8) + lane.
-        out_ref[0] = jnp.min(m.reshape(R // 8, 8, LANE), axis=0)
+        # segment = word q mod NUM_SEGMENTS = 128 * (row mod 8) + lane
+        # (R is a multiple of 8, so a block's rows keep their residues).
+        m = jnp.min(m.reshape(R // 8, 8, LANE), axis=0)
+        if steps == 1:
+            out_ref[0] = m
+        else:
+            @pl.when(j == 0)
+            def _():
+                out_ref[0] = m
+
+            @pl.when(j > 0)
+            def _():
+                out_ref[0] = jnp.minimum(out_ref[0], m)
 
     return kernel
 
@@ -105,30 +130,51 @@ def survivor_segmin_pallas(data, lengths, k: int = DEFAULT_SHINGLE,
     → uint32 (N, NUM_SEGMENTS), bit-identical.
 
     CONTRACT (shared with sha1_batch): rows are zero past their length.
+    ``data`` may be the rows' little-endian words, uint32 (N, L / 4), as
+    for ``sha1_batch_pallas``.
     """
-    data = jnp.asarray(data, dtype=jnp.uint8)
     lengths = jnp.asarray(lengths, dtype=jnp.int32)
-    n, L = data.shape
+    as_words = getattr(data, "dtype", None) == jnp.uint32
+    data = jnp.asarray(data, dtype=jnp.uint32 if as_words else jnp.uint8)
+    n = data.shape[0]
+    L = data.shape[1] * (4 if as_words else 1)
     block = 4 * NUM_SEGMENTS
+    if L > _BLOCK_ROWS * LANE * 4:     # whole steps of _BLOCK_ROWS rows
+        block = _BLOCK_ROWS * LANE * 4
     pad = (-L) % block
     if pad:
-        data = jnp.pad(data, ((0, 0), (0, pad)))
+        data = jnp.pad(data, ((0, 0), (0, pad // 4 if as_words else pad)))
     NW = (L + pad) // 4
-    R = NW // LANE                                      # multiple of 8
-    words = jax.lax.bitcast_convert_type(
-        data.reshape(n, R, LANE, 4), jnp.uint32)        # (N, R, 128)
+    rows = NW // LANE                                   # multiple of 8
+    R = min(rows, _BLOCK_ROWS)
+    steps = rows // R
+    if as_words:
+        words = data.reshape(n, rows, LANE)
+    else:
+        words = jax.lax.bitcast_convert_type(
+            data.reshape(n, rows, LANE, 4), jnp.uint32)  # (N, rows, 128)
 
+    in_specs = [pl.BlockSpec((1, R, LANE), lambda i, j, lens_ref: (i, j, 0))]
+    operands = [words]
+    if steps > 1:
+        # The next block's first 8 rows; past the chunk's end any rows
+        # do (see the kernel's note on the last word).
+        in_specs.append(pl.BlockSpec(
+            (1, 8, LANE), lambda i, j, lens_ref: (
+                i, jnp.minimum((j + 1) * (R // 8), rows // 8 - 1), 0)))
+        operands.append(words)
     out = pl.pallas_call(
-        _survivor_kernel(k, R),
+        _survivor_kernel(k, R, steps),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(n,),
-            in_specs=[pl.BlockSpec((1, R, LANE), lambda i, lens_ref: (i, 0, 0))],
-            out_specs=pl.BlockSpec((1, 8, LANE), lambda i, lens_ref: (i, 0, 0)),
+            grid=(n, steps),
+            in_specs=in_specs,
+            out_specs=pl.BlockSpec((1, 8, LANE),
+                                   lambda i, j, lens_ref: (i, 0, 0)),
         ),
         out_shape=jax.ShapeDtypeStruct((n, 8, LANE), jnp.int32),
         interpret=interpret,
-    )(lengths, words)
+    )(lengths, *operands)
     z = jax.lax.bitcast_convert_type(out, jnp.uint32) ^ jnp.uint32(0x80000000)
     return z.reshape(n, NUM_SEGMENTS)
 

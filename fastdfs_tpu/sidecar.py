@@ -25,7 +25,14 @@ Opcodes
 * ``DEDUP_COMMIT`` (122): text body, one of
   ``commitfile <sha1hex> <file_id>`` |
   ``commitchunks <session> <file_id>`` | ``abort <session>`` |
-  ``forget <file_id>`` | ``stats``.  ``abort`` is sent on flat-fallback
+  ``forget <file_id>`` | ``stats`` | ``widths <min> <avg_bits> <max>``.
+  ``widths`` is how a daemon opens every connection: the chunk widths it
+  cuts with (storage.conf ``dedup_cdc_widths``).  This engine plans its
+  tiles for, and its indexes hold chunks of, one set of widths
+  (``--cdc-widths``); any other set is answered status 22 with both sets
+  in the body and a line in the log, and the daemon then fingerprints
+  nothing over that connection: no recipe is stored under mixed widths.
+  ``abort`` is sent on flat-fallback
   or a failed upload; sessions older than ``_SESSION_TTL`` seconds are
   reaped in case a daemon dies without either message.  ``stats``
   returns the service counters as JSON: ``fingerprint_bytes``,
@@ -47,7 +54,11 @@ Opcodes
   Plus the device this process got (backend,
   device_kind, device_count, use_pallas, fan_out, device_bytes per
   device id, tiles_by_rows, the tiles placed there by their row count,
-  and memory_peak_bytes, the most the fullest device has held) — a
+  and memory_peak_bytes, the most the fullest device has held), the
+  chunk widths in force (``widths``) and the SHA-1 launches summed over
+  every tile: ``rows_placed`` (rows that held a chunk),
+  ``lanes_launched`` (the rows after the kernel's padding) and
+  ``sha1_grid_steps`` (64-byte blocks walked one after another) — a
   reader learns from it whether the chip did the work, which the
   daemon's fail-open path would otherwise hide.
   ``trace start <dir>`` / ``trace stop`` start and stop a JAX profiler
@@ -95,6 +106,7 @@ import time
 
 import numpy as np
 
+from fastdfs_tpu.common.ini_config import parse_bytes
 from fastdfs_tpu.common.protocol import HEADER_SIZE, StorageCmd, unpack_header
 from fastdfs_tpu.dedup.engine import DedupConfig, DedupEngine
 from fastdfs_tpu.dedup.spans import mark, new_acc, span
@@ -105,6 +117,8 @@ _FINGERPRINT_CMDS = (StorageCmd.DEDUP_FINGERPRINT,
                      StorageCmd.DEDUP_FINGERPRINT_CUTS)
 
 _SESSION_TTL = 600.0  # seconds before an uncommitted session is reaped
+_SHIPPED_WIDTHS = (DedupConfig.min_size, DedupConfig.avg_bits,
+                   DedupConfig.max_size)
 
 
 class _Session:
@@ -147,6 +161,15 @@ def read_stats(socket_path: str) -> dict:
     if status != 0:
         raise OSError(f"sidecar stats: status {status}")
     return json.loads(resp)
+
+
+def parse_widths(text: str) -> tuple[int, int, int]:
+    """``<min>:<avg_bits>:<max>`` as storage.conf's ``dedup_cdc_widths``
+    writes it (sizes take a K, M or G suffix) → (min, avg_bits, max)."""
+    parts = text.strip().split(":")
+    if len(parts) != 3:
+        raise ValueError(f"widths {text!r}: want <min>:<avg_bits>:<max>")
+    return parse_bytes(parts[0]), int(parts[1]), parse_bytes(parts[2])
 
 
 def _cuts_cover(ends: np.ndarray, n: int) -> bool:
@@ -225,9 +248,12 @@ class DedupSidecar:
             if isinstance(blob, dict) and "files" in blob:
                 spec = int(blob.get("cdc_spec", 1))
                 policy = int(blob.get("cdc_policy", 1))
+                # no widths record: written before they were a setting,
+                # so at the shipped ones
+                widths = tuple(blob.get("cdc_widths", _SHIPPED_WIDTHS))
                 files = blob["files"]
             else:
-                spec, policy, files = 1, 1, blob
+                spec, policy, widths, files = 1, 1, _SHIPPED_WIDTHS, blob
             if spec != CDC_SPEC_VERSION:
                 # Stale chunker spec: the same bytes now chunk at
                 # different offsets, so every stored chunk digest would
@@ -246,6 +272,13 @@ class DedupSidecar:
                 print(f"dedup sidecar: discarding snapshot built with "
                       f"cdc_policy {policy} (engine runs policy "
                       f"{self.engine.config.cdc_policy})", flush=True)
+                return
+            if widths != self._widths():
+                # And for the chunk widths: chunks cut at other widths
+                # share no digest with what this engine will see.
+                print(f"dedup sidecar: discarding snapshot built at chunk "
+                      f"widths {':'.join(map(str, widths))} (engine runs "
+                      f"{self._widths_text()})", flush=True)
                 return
             self.files = files
             self.by_file = {v: k for k, v in self.files.items()}
@@ -285,9 +318,17 @@ class DedupSidecar:
             with open(tmp, "w") as fh:
                 json.dump({"cdc_spec": CDC_SPEC_VERSION,
                            "cdc_policy": self.engine.config.cdc_policy,
+                           "cdc_widths": list(self._widths()),
                            "files": self.files}, fh)
             os.replace(tmp, files_p)
             self.engine.save(exact_p, near_p)
+
+    def _widths(self) -> tuple[int, int, int]:
+        cfg = self.engine.config
+        return cfg.min_size, cfg.avg_bits, cfg.max_size
+
+    def _widths_text(self) -> str:
+        return ":".join(map(str, self._widths()))
 
     def device_info(self) -> dict:
         """What this process actually runs on, as JAX and the engine
@@ -307,7 +348,10 @@ class DedupSidecar:
                 "device_bytes": {str(dev): n for dev, n in sorted(
                     dict(self.engine.device_bytes).items())},
                 "tiles_by_rows": {str(rows): n for rows, n in sorted(
-                    dict(self.engine.tiles_by_rows).items())}}
+                    dict(self.engine.tiles_by_rows).items())},
+                "widths": dict(zip(("min_size", "avg_bits", "max_size"),
+                                   self._widths())),
+                **self.engine.launched}
 
     # -- request handlers --------------------------------------------------
 
@@ -340,6 +384,16 @@ class DedupSidecar:
                 # with data would "succeed" with zero chunks and a recipe
                 # covering none of the bytes.
                 if not _cuts_cover(ends, len(data)):
+                    return 22, b""
+                # A caller that cut at other widths (one that never said
+                # `widths`: every daemon of this tree does) shows here at
+                # the latest: no chunk of this engine's is over max_size.
+                if n_cuts and int(np.diff(ends, prepend=0).max()) > \
+                        self.engine.config.max_size:
+                    print("dedup sidecar: REFUSING cuts over max_size "
+                          f"{self.engine.config.max_size}: the caller "
+                          "chunks at other widths than this engine "
+                          f"({self._widths_text()})", flush=True)
                     return 22, b""
                 cuts = ends.tolist()
             else:
@@ -421,6 +475,15 @@ class DedupSidecar:
             return 22, b""
         if parts[0] == "trace":     # the directory may hold blanks
             return self._trace(text.strip().split(None, 2)[1:])
+        if parts[0] == "widths" and len(parts) == 4:
+            if tuple(_parse_session(p) for p in parts[1:]) == self._widths():
+                return 0, b""
+            why = (f"the daemon chunks at {':'.join(parts[1:])}, this "
+                   f"engine at {self._widths_text()}")
+            print(f"dedup sidecar: REFUSING a daemon: {why}; nothing is "
+                  "fingerprinted for it (set storage.conf dedup_cdc_widths "
+                  "and --cdc-widths alike)", flush=True)
+            return 22, why.encode()
         with self._lock:
             if parts[0] == "commitfile" and len(parts) == 3:
                 self.files.setdefault(parts[1], parts[2])
@@ -758,6 +821,13 @@ def main(argv: list[str] | None = None) -> int:
                          "groups only, see OPERATIONS.md).  Snapshots "
                          "built under another policy are discarded at "
                          "load.")
+    ap.add_argument("--cdc-widths", type=parse_widths,
+                    default=_SHIPPED_WIDTHS, metavar="MIN:BITS:MAX",
+                    help="the chunk widths, as storage.conf's "
+                         "dedup_cdc_widths states them (default 2K:13:64K). "
+                         "A daemon that chunks at other widths is refused; "
+                         "snapshots built at other widths are discarded at "
+                         "load.")
     ap.add_argument("--fan-out", type=int, default=None,
                     help="shard each fingerprint batch's rows over this "
                          "many local devices (default: auto — all local "
@@ -787,15 +857,19 @@ def main(argv: list[str] | None = None) -> int:
           f"{jax.local_devices()[0].device_kind}, compile cache "
           f"{cache_dir}", flush=True)
 
-    config = DedupConfig(cdc_policy=args.cdc_policy, fan_out=args.fan_out)
+    min_size, avg_bits, max_size = args.cdc_widths
+    config = DedupConfig(min_size=min_size, avg_bits=avg_bits,
+                         max_size=max_size, cdc_policy=args.cdc_policy,
+                         fan_out=args.fan_out)
     sidecar = DedupSidecar(args.socket, state_dir=args.state_dir,
                            config=config)
     signal.signal(signal.SIGTERM, lambda *_: sidecar.stop())
     signal.signal(signal.SIGINT, lambda *_: sidecar.stop())
     t0 = time.monotonic()
     sidecar.engine.warmup()  # compile all shapes BEFORE accepting traffic
-    print(f"dedup sidecar warmed in {time.monotonic() - t0:.1f}s, "
-          f"listening on {args.socket}", flush=True)
+    print(f"dedup sidecar warmed in {time.monotonic() - t0:.1f}s at chunk "
+          f"widths {sidecar._widths_text()}, listening on {args.socket}",
+          flush=True)
     sidecar.serve_forever(snapshot_interval=args.snapshot_interval)
     return 0
 

@@ -15,7 +15,25 @@
 #include <cstdint>
 #include <vector>
 
+#include "common/gear_gen.h"  // kCdcDefault*
+
 namespace fdfs {
+
+// The three chunk widths, one choice: no cut before min_size bytes, a cut
+// where the low avg_bits of the gear hash are zero (2^avg_bits bytes
+// between candidates), a cut at max_size whatever the hash.  The
+// defaults are gear_gen.h's kCdcDefault* (2 KiB / 13 / 64 KiB);
+// storage.conf states them as dedup_cdc_widths.  Chunks cut at different
+// widths share no digests: a set of widths is a content-address namespace.
+struct CdcWidths {
+  int64_t min_size = kCdcDefaultMinSize;
+  int avg_bits = kCdcDefaultAvgBits;
+  int64_t max_size = kCdcDefaultMaxSize;
+  bool operator==(const CdcWidths& o) const {
+    return min_size == o.min_size && avg_bits == o.avg_bits &&
+           max_size == o.max_size;
+  }
+};
 
 // Exclusive chunk end offsets for data[0..n) (final offset is n; empty
 // input -> empty vector).  Semantics: hash resets at each chunk start; a

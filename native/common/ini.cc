@@ -131,8 +131,12 @@ bool IniConfig::GetBool(const std::string& key, bool dflt) const {
 int64_t IniConfig::GetBytes(const std::string& key, int64_t dflt) const {
   auto v = Get(key);
   if (!v.has_value() || v->empty()) return dflt;
+  return ParseBytes(*v, dflt);
+}
+
+int64_t IniConfig::ParseBytes(const std::string& text, int64_t dflt) {
   char* end = nullptr;
-  int64_t n = std::strtoll(v->c_str(), &end, 10);
+  int64_t n = std::strtoll(text.c_str(), &end, 10);
   std::string suffix = Trim(end);
   std::transform(suffix.begin(), suffix.end(), suffix.begin(), ::toupper);
   if (suffix.empty() || suffix == "B") return n;

@@ -24,6 +24,9 @@ class IniConfig {
   bool GetBool(const std::string& key, bool dflt) const;
   // Sizes with K/M/G/T suffixes (e.g. "256KB", "64MB").
   int64_t GetBytes(const std::string& key, int64_t dflt) const;
+  // The same for a value that is not a key's whole text (one field of
+  // dedup_cdc_widths); dflt on an unknown suffix.
+  static int64_t ParseBytes(const std::string& text, int64_t dflt);
   // Durations with s/m/h/d suffixes.
   int64_t GetSeconds(const std::string& key, int64_t dflt) const;
   bool Has(const std::string& key) const { return items_.count(key) > 0; }

@@ -4,6 +4,23 @@
 
 namespace fdfs {
 
+namespace {
+
+// "<min>:<avg_bits>:<max>", e.g. "2K:13:64K" or "512K:20:8M".
+bool ParseCdcWidths(const std::string& text, CdcWidths* out) {
+  const size_t a = text.find(':');
+  const size_t b = a == std::string::npos ? a : text.find(':', a + 1);
+  if (b == std::string::npos || text.find(':', b + 1) != std::string::npos)
+    return false;
+  out->min_size = IniConfig::ParseBytes(text.substr(0, a), -1);
+  const int64_t bits = IniConfig::ParseBytes(text.substr(a + 1, b - a - 1), -1);
+  out->max_size = IniConfig::ParseBytes(text.substr(b + 1), -1);
+  out->avg_bits = static_cast<int>(bits);
+  return out->min_size >= 0 && bits >= 0 && bits < 64 && out->max_size >= 0;
+}
+
+}  // namespace
+
 bool StorageConfig::Load(const IniConfig& ini, std::string* error) {
   anomalies.clear();
   auto note = [this](const std::string& what) { anomalies.push_back(what); };
@@ -79,6 +96,21 @@ bool StorageConfig::Load(const IniConfig& ini, std::string* error) {
   dedup_segment_bytes =
       ini.GetBytes("dedup_segment_bytes", 64LL * 1024 * 1024);
   if (dedup_segment_bytes < (1 << 20)) dedup_segment_bytes = 1 << 20;
+  const std::string widths = ini.GetStr("dedup_cdc_widths", "");
+  cdc_widths = CdcWidths();
+  if (!widths.empty() && !ParseCdcWidths(widths, &cdc_widths)) {
+    *error = "dedup_cdc_widths must be <min>:<avg_bits>:<max>, e.g. "
+             "2K:13:64K (got '" + widths + "')";
+    return false;
+  }
+  if (cdc_widths.min_size < 32 || cdc_widths.min_size >= cdc_widths.max_size ||
+      cdc_widths.max_size > dedup_segment_bytes || cdc_widths.avg_bits < 1 ||
+      cdc_widths.avg_bits > 31) {
+    *error = "dedup_cdc_widths = " + widths + ": the minimum must be at "
+             "least 32 (the gear window) and under the maximum, the maximum "
+             "no larger than dedup_segment_bytes, avg_bits in [1,31]";
+    return false;
+  }
   upload_session_timeout_s = static_cast<int>(
       ini.GetSeconds("upload_session_timeout", upload_session_timeout_s));
   if (upload_session_timeout_s < 1) upload_session_timeout_s = 1;

@@ -5,6 +5,7 @@
 #include <string>
 #include <vector>
 
+#include "common/cdc.h"  // CdcWidths
 #include "common/ini.h"
 
 namespace fdfs {
@@ -61,6 +62,12 @@ struct StorageConfig {
   // Segment size for streaming fingerprint RPCs (CDC restarts per
   // segment so a multi-GB upload never needs a contiguous buffer).
   int64_t dedup_segment_bytes = 64LL * 1024 * 1024;
+  // dedup_cdc_widths = <min>:<avg_bits>:<max> (sizes take K/M suffixes):
+  // the chunker's three widths, one key because they are one choice.
+  // Both plugins cut with them and a sidecar at other widths is refused
+  // (dedup.h).  Load() rejects min < 32 (the gear window), min >= max,
+  // max > dedup_segment_bytes, avg_bits outside [1, 31].
+  CdcWidths cdc_widths;
   // Negotiated-upload session lifetime: a client that sent
   // UPLOAD_RECIPE but never completed UPLOAD_CHUNKS holds pins on the
   // chunks its bitmap reported present; the sweep timer aborts (and

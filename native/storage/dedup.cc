@@ -21,8 +21,8 @@ namespace fdfs {
 
 // -- CpuDedup -------------------------------------------------------------
 
-CpuDedup::CpuDedup(std::string snapshot_path)
-    : snapshot_path_(std::move(snapshot_path)) {}
+CpuDedup::CpuDedup(std::string snapshot_path, CdcWidths widths)
+    : snapshot_path_(std::move(snapshot_path)), widths_(widths) {}
 
 DedupPlugin::Verdict CpuDedup::Judge(const std::string& sha1_hex, int64_t) {
   Verdict v;
@@ -87,11 +87,12 @@ static int64_t DedupMonoUs() {
 // The native chunker, timed: both plugins cut with it, and the access
 // log's cdc_us column says how much of fp_us it was.
 static std::vector<int64_t> TimedGearChunkStream(const char* data,
-                                                 size_t len) {
+                                                 size_t len,
+                                                 const CdcWidths& w) {
   const int64_t t0 = DedupMonoUs();
-  std::vector<int64_t> cuts = GearChunkStream(
-      reinterpret_cast<const uint8_t*>(data), len, kCdcDefaultMinSize,
-      kCdcDefaultAvgBits, kCdcDefaultMaxSize);
+  std::vector<int64_t> cuts =
+      GearChunkStream(reinterpret_cast<const uint8_t*>(data), len, w.min_size,
+                      w.avg_bits, w.max_size);
   tls_dedup_cdc_us += DedupMonoUs() - t0;
   return cuts;
 }
@@ -99,7 +100,7 @@ static std::vector<int64_t> TimedGearChunkStream(const char* data,
 bool CpuDedup::FingerprintChunks(int64_t /*session*/, const char* data,
                                  size_t len, int64_t base_offset,
                                  std::vector<ChunkFp>* out) {
-  std::vector<int64_t> cuts = TimedGearChunkStream(data, len);
+  std::vector<int64_t> cuts = TimedGearChunkStream(data, len, widths_);
   int64_t last = 0;
   for (int64_t cut : cuts) {
     ChunkFp fp;
@@ -128,9 +129,11 @@ bool CpuDedup::LoadSnapshot() {
 
 // -- SidecarDedup ---------------------------------------------------------
 
-SidecarDedup::SidecarDedup(std::string socket_path, int max_idle_fds)
+SidecarDedup::SidecarDedup(std::string socket_path, int max_idle_fds,
+                           CdcWidths widths)
     : socket_path_(std::move(socket_path)),
-      max_idle_fds_(std::max(max_idle_fds, kMinIdleFds)) {}
+      max_idle_fds_(std::max(max_idle_fds, kMinIdleFds)),
+      widths_(widths) {}
 
 SidecarDedup::~SidecarDedup() {
   for (int fd : pool_) close(fd);
@@ -157,11 +160,45 @@ int SidecarDedup::AcquireFd(bool* pooled) {
   memset(&addr, 0, sizeof(addr));
   addr.sun_family = AF_UNIX;
   strncpy(addr.sun_path, socket_path_.c_str(), sizeof(addr.sun_path) - 1);
-  if (connect(fd, reinterpret_cast<struct sockaddr*>(&addr), sizeof(addr)) != 0) {
+  if (connect(fd, reinterpret_cast<struct sockaddr*>(&addr), sizeof(addr)) != 0 ||
+      !Handshake(fd)) {
     close(fd);
     return -1;
   }
   return fd;
+}
+
+// Tell the sidecar the widths this daemon cuts with.  Its engine plans
+// tiles for, and its indexes hold chunks of, exactly one set of widths; a
+// sidecar at another set answers an error, and this connection is then
+// never used: the upload falls to the flat path (fail-open) with the
+// reason in the log, and nothing is stored under mixed widths.
+bool SidecarDedup::Handshake(int fd) {
+  const int timeout_ms = 60000;
+  const std::string body = "widths " + std::to_string(widths_.min_size) + " " +
+                           std::to_string(widths_.avg_bits) + " " +
+                           std::to_string(widths_.max_size);
+  uint8_t hdr[kHeaderSize];
+  PutInt64BE(static_cast<int64_t>(body.size()), hdr);
+  hdr[8] = static_cast<uint8_t>(StorageCmd::kDedupCommit);
+  hdr[9] = 0;
+  if (!SendAll(fd, hdr, sizeof(hdr), timeout_ms) ||
+      !SendAll(fd, body.data(), body.size(), timeout_ms) ||
+      !RecvAll(fd, hdr, sizeof(hdr), timeout_ms))
+    return false;
+  const int64_t len = GetInt64BE(hdr);
+  if (len < 0 || len > 4096) return false;
+  std::string why(static_cast<size_t>(len), '\0');
+  if (len > 0 && !RecvAll(fd, why.data(), why.size(), timeout_ms)) return false;
+  if (hdr[9] != 0) {
+    FDFS_LOG_ERROR(
+        "dedup(sidecar): REFUSED at dedup_cdc_widths %lld:%d:%lld (status %d): "
+        "%s -- start the sidecar with the same --cdc-widths",
+        static_cast<long long>(widths_.min_size), widths_.avg_bits,
+        static_cast<long long>(widths_.max_size), hdr[9], why.c_str());
+    return false;
+  }
+  return true;
 }
 
 void SidecarDedup::ReleaseFd(int fd) {
@@ -275,7 +312,7 @@ int64_t SidecarDedup::BeginChunked() {
 bool SidecarDedup::FingerprintChunks(int64_t session, const char* data,
                                      size_t len, int64_t base_offset,
                                      std::vector<ChunkFp>* out) {
-  std::vector<int64_t> cuts = TimedGearChunkStream(data, len);
+  std::vector<int64_t> cuts = TimedGearChunkStream(data, len, widths_);
   // The segment is the request's tail: Rpc sends it from the caller's
   // buffer, so it is not copied here for the sake of one send().
   std::string body;
@@ -396,14 +433,17 @@ bool SidecarDedup::VerifyChunks(const std::vector<ChunkFp>& chunks,
 std::unique_ptr<DedupPlugin> MakeDedupPlugin(const std::string& mode,
                                              const std::string& base_path,
                                              const std::string& sidecar_path,
-                                             int sidecar_idle_conns) {
+                                             int sidecar_idle_conns,
+                                             CdcWidths widths) {
   if (mode == "cpu") {
-    auto p = std::make_unique<CpuDedup>(base_path + "/data/dedup_index.dat");
+    auto p = std::make_unique<CpuDedup>(base_path + "/data/dedup_index.dat",
+                                        widths);
     p->LoadSnapshot();
     return p;
   }
   if (mode == "sidecar")
-    return std::make_unique<SidecarDedup>(sidecar_path, sidecar_idle_conns);
+    return std::make_unique<SidecarDedup>(sidecar_path, sidecar_idle_conns,
+                                          widths);
   return nullptr;  // none
 }
 

@@ -24,6 +24,7 @@
 #include <memory>
 #include <mutex>
 
+#include "common/cdc.h"  // CdcWidths
 #include "common/lockrank.h"
 #include <string>
 #include <unordered_map>
@@ -112,7 +113,7 @@ class DedupPlugin {
 // fingerprints via the serial gear CDC (common/cdc.h).
 class CpuDedup : public DedupPlugin {
  public:
-  explicit CpuDedup(std::string snapshot_path);
+  explicit CpuDedup(std::string snapshot_path, CdcWidths widths = {});
   Verdict Judge(const std::string& sha1_hex, int64_t file_size) override;
   void Commit(const std::string& sha1_hex, const std::string& file_id) override;
   void Forget(const std::string& file_id) override;
@@ -126,6 +127,7 @@ class CpuDedup : public DedupPlugin {
 
  private:
   std::string snapshot_path_;
+  const CdcWidths widths_;
   mutable RankedMutex mu_{LockRank::kDedupEngine};  // handlers run on every nio/dio thread
   std::unordered_map<std::string, std::string> by_digest_;  // sha1 -> file id
   std::unordered_map<std::string, std::string> by_file_;    // file id -> sha1
@@ -134,14 +136,19 @@ class CpuDedup : public DedupPlugin {
 // Sidecar: TPU dedup engine process over a unix-domain socket, speaking
 // the DEDUP_* opcodes on the standard framing (see
 // fastdfs_tpu/sidecar.py).  Falls open (treats everything as unique /
-// unchunkable) when the sidecar is unreachable.
+// unchunkable) when the sidecar is unreachable.  Every connection opens
+// with "widths <min> <avg_bits> <max>" (a DEDUP_COMMIT): a sidecar whose
+// engine runs other chunk widths answers an error, the connection is
+// dropped with an ERROR line, and nothing is fingerprinted over it, so no
+// recipe is ever stored under mixed widths.
 class SidecarDedup : public DedupPlugin {
  public:
   // max_idle_fds: how many idle connections the pool keeps; the daemon
   // passes its dio workers' total, so that no worker's connection is
   // closed behind it (kMinIdleFds at least: nio threads, the scrubber
   // and recovery make RPCs too).
-  SidecarDedup(std::string socket_path, int max_idle_fds);
+  SidecarDedup(std::string socket_path, int max_idle_fds,
+               CdcWidths widths = {});
   ~SidecarDedup() override;
   Verdict Judge(const std::string& sha1_hex, int64_t file_size) override;
   void Commit(const std::string& sha1_hex, const std::string& file_id) override;
@@ -177,18 +184,23 @@ class SidecarDedup : public DedupPlugin {
   bool Rpc(uint8_t cmd, const std::string& body, std::string* resp,
            uint8_t* status, int64_t max_resp = 1 << 20,
            const char* tail = nullptr, size_t tail_len = 0);
+  // The first exchange on a fresh connection; false = refused or dead.
+  bool Handshake(int fd);
   std::string socket_path_;
   const int max_idle_fds_;
+  const CdcWidths widths_;
   RankedMutex mu_{LockRank::kDedupPool};  // guards pool_
   std::vector<int> pool_;
 };
 
 // sidecar_idle_conns: see SidecarDedup's constructor (other modes
-// ignore it).
+// ignore it).  widths: storage.conf's dedup_cdc_widths; both plugins cut
+// with them.
 std::unique_ptr<DedupPlugin> MakeDedupPlugin(const std::string& mode,
                                              const std::string& base_path,
                                              const std::string& sidecar_path,
-                                             int sidecar_idle_conns = 0);
+                                             int sidecar_idle_conns = 0,
+                                             CdcWidths widths = {});
 
 // Thread-local sidecar lock-wait accounting: SidecarDedup adds the time
 // THIS thread spent queued on the connection-pool mutex (connection

@@ -165,7 +165,8 @@ bool StorageServer::Init(std::string* error) {
                         std::thread::hardware_concurrency(),
                         store_.store_path_count());
   dedup_ = MakeDedupPlugin(cfg_.dedup_mode, cfg_.base_path, cfg_.dedup_sidecar,
-                           dio_workers_per_path_ * store_.store_path_count());
+                           dio_workers_per_path_ * store_.store_path_count(),
+                           cfg_.cdc_widths);
   if (dedup_ != nullptr && cfg_.dedup_chunk_threshold > 0) {
     // Chunk-level dedup: one content-addressed store per store path;
     // refcounts rebuilt from recipes (doubles as orphan GC).
@@ -428,7 +429,8 @@ bool StorageServer::Init(std::string* error) {
       // second CpuDedup would pointlessly re-load the digest snapshot.
       if (cfg_.dedup_mode == "sidecar")
         recovery_dedup_ = MakeDedupPlugin(cfg_.dedup_mode, cfg_.base_path,
-                                          cfg_.dedup_sidecar);
+                                          cfg_.dedup_sidecar, 0,
+                                          cfg_.cdc_widths);
       DedupPlugin* rec_plugin =
           recovery_dedup_ != nullptr ? recovery_dedup_.get() : dedup_.get();
       recovery_->SetChunkedStore(
@@ -546,7 +548,7 @@ bool StorageServer::Init(std::string* error) {
   if (!chunk_stores_.empty()) {
     if (cfg_.dedup_mode == "sidecar")
       scrub_dedup_ = MakeDedupPlugin(cfg_.dedup_mode, cfg_.base_path,
-                                     cfg_.dedup_sidecar);
+                                     cfg_.dedup_sidecar, 0, cfg_.cdc_widths);
     ScrubOptions sopts;
     sopts.interval_s = cfg_.scrub_interval_s;
     sopts.bandwidth_bytes_s =

@@ -154,8 +154,18 @@ def test_every_span_of_the_table_lies_inside_its_request(traced, cmd):
     if cmd == FP_CUTS:      # 600 rows of one bucket: never a span per chunk
         assert tiles == 3
         assert sum(e[0] == "fdfs.engine.slot_wait" for e in inside) == 1
-    # only the identifiers and sizes that something reads ride on a span
-    assert all(set(e[4]) <= {"cmd", "bytes"} for e in inside)
+    # only the identifiers and sizes that something reads ride on a span:
+    # a dispatch carries its tile's SHA-1 launch (the benchmark's
+    # sha1_lane_fill and sha1_serial_steps_per_MB sum these)
+    launches = [e[4] for e in inside if e[0] == "fdfs.engine.dispatch"]
+    assert all(set(a) == {"rows", "lanes", "blen", "blocks"}
+               for a in launches)
+    assert all(0 < a["rows"] <= a["lanes"] and a["lanes"] % 128 == 0
+               and a["blocks"] > a["blen"] // 64 for a in launches)
+    if cmd == FP_CUTS:      # every one of the 600 chunks on one row
+        assert sum(a["rows"] for a in launches) == 600
+    assert all(set(e[4]) <= {"cmd", "bytes"} for e in inside
+               if e[0] != "fdfs.engine.dispatch")
     # recv before the root and send after it, on the same thread
     wire = {e[0]: e for e in traced["events"] if e[1] == thread
             and e[0] in ("fdfs.sidecar.recv", "fdfs.sidecar.send")

@@ -7,8 +7,10 @@ tiling, too much VMEM, a kernel that cannot be partitioned.  The TPU
 compiler is installed here and compiles for a described topology, so
 these tests ask it, at the widths the sidecar really runs: row_tile 256
 at the smallest and the largest pow2 length bucket and the smaller tiles
-of the plan (engine.plan_shapes) at their narrowest and widest, both
-Pallas kernels, the jitted result concat, the XLA SHA-1 the scrubber's DEDUP_VERIFY
+of the plan (engine.plan_shapes) at their narrowest and widest, and the
+tiles of a backup node's widths (512 KiB - 8 MiB: a few rows of
+megabytes, bounded in bytes) at both ends, rows as the words the engine
+passes, both Pallas kernels, the jitted result concat, the XLA SHA-1 the scrubber's DEDUP_VERIFY
 jits, and the four-device fan-out step.  Nothing executes, so they say
 nothing about results or speed (chip_smoke.py does, on the chip).
 
@@ -29,7 +31,7 @@ from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
 from fastdfs_tpu.dedup.engine import (DedupConfig, _packed_concat,
                                        plan_shapes)
 from fastdfs_tpu.ops.pallas_minhash import minhash_batch_pallas
-from fastdfs_tpu.ops.pallas_sha1 import sha1_batch_pallas
+from fastdfs_tpu.ops.pallas_sha1 import default_sub, sha1_batch_pallas
 from fastdfs_tpu.ops.sha1 import _sha1_padded
 from fastdfs_tpu.parallel.ingest_step import make_fingerprint_step
 
@@ -39,12 +41,13 @@ SMALLEST, LARGEST = CFG.min_size, CFG.max_size
 # The full tile at both ends of the widths, and the tiles under row_tile
 # that the plan ships for sparse buckets, the narrowest and the widest.
 _SMALL = [shape for shape in plan_shapes(CFG) if shape[0] < ROWS]
-TILES = [(ROWS, SMALLEST), (ROWS, LARGEST), _SMALL[0], _SMALL[-1]]
-
-
-def _sub(rows):
-    # engine.py:_fingerprint_batch picks this from the row count.
-    return max(1, min(16, rows // 128))
+# The same at the widths backup tools cut (restic_chunks): the one tile
+# of 128 rows (lane-major kernel, 64 MiB), the narrowest small one and
+# the widest (row-major kernel; 8 rows of 8 MiB).
+_WIDE = plan_shapes(DedupConfig(min_size=512 << 10, avg_bits=20,
+                                max_size=8 << 20))
+TILES = [(ROWS, SMALLEST), (ROWS, LARGEST), _SMALL[0], _SMALL[-1],
+         _WIDE[0], _WIDE[1], _WIDE[-1]]
 
 
 @pytest.fixture(scope="module")
@@ -73,26 +76,37 @@ def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
-def _batch(rows, blen, sharding, lens_sharding=None):
-    return (jax.ShapeDtypeStruct((rows, blen), jnp.uint8, sharding=sharding),
+def _batch(rows, blen, sharding, lens_sharding=None, words=False):
+    """A tile as bytes, or (``words``) as the uint32 view of the staging
+    buffer that engine.py:_fingerprint_batch hands the Pallas kernels."""
+    shape, dtype = ((rows, blen // 4), jnp.uint32) if words else (
+        (rows, blen), jnp.uint8)
+    return (jax.ShapeDtypeStruct(shape, dtype, sharding=sharding),
             jax.ShapeDtypeStruct((rows,), jnp.int32,
                                  sharding=lens_sharding or sharding))
 
 
 @pytest.mark.parametrize("rows,blen", TILES)
 def test_sha1_pallas_compiles_for_v5e(one_chip, rows, blen):
-    data, lens = _batch(rows, blen, one_chip)
+    data, lens = _batch(rows, blen, one_chip, words=True)
     compiled = sha1_batch_pallas.lower(data, lens, max_len=blen,
-                                       sub=_sub(rows)).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+                                       sub=default_sub(rows)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    # under 128 rows the row-major kernel: no lane padding in HBM
+    assert ("_sha1_rows_pallas" in text) == (rows < 128)
+    # the packed words and nothing much else: a tile is never multiplied
+    # by 128 / rows on the device
+    assert compiled.memory_analysis().temp_size_in_bytes <= 2.5 * rows * blen
 
 
 @pytest.mark.parametrize("rows,blen", TILES)
 def test_minhash_pallas_compiles_for_v5e(one_chip, rows, blen):
-    data, lens = _batch(rows, blen, one_chip)
+    data, lens = _batch(rows, blen, one_chip, words=True)
     compiled = minhash_batch_pallas.lower(
         data, lens, num_perms=CFG.num_perms, k=CFG.shingle).compile()
     assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes <= 2.5 * rows * blen
 
 
 def test_packed_concat_compiles_for_v5e(one_chip):
