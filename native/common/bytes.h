@@ -32,7 +32,15 @@ std::string Base64UrlEncode(const uint8_t* data, size_t len);
 bool Base64UrlDecode(std::string_view s, std::string* out);
 
 // -- CRC32 (IEEE, zlib-compatible; reference: hash.c crc32) ---------------
+// `seed` chains: Crc32(b, nb, Crc32(a, na)) == Crc32(a || b).
 uint32_t Crc32(const void* data, size_t len, uint32_t seed = 0);
+// The loop Crc32 runs, fixed at first use by what the CPU has (STAT gauge
+// `crc32.impl`), and the same sum by a named loop, for tests.  kFolded may
+// be asked for only where Crc32Chosen() returns it.
+enum class Crc32Impl { kSliced = 0, kFolded = 1 };
+Crc32Impl Crc32Chosen();
+uint32_t Crc32With(Crc32Impl impl, const void* data, size_t len,
+                   uint32_t seed = 0);
 
 // -- JSON string escaping (every hand-built wire-JSON emitter: STAT /
 // EVENT_DUMP / METRICS_HISTORY / HEAT_TOP).  Appends `s` quoted, with

@@ -3,6 +3,7 @@
 #include <errno.h>
 #include <fcntl.h>
 #include <string.h>
+#include <sys/mman.h>
 #include <sys/sendfile.h>
 #include <sys/socket.h>
 #include <sys/stat.h>
@@ -30,6 +31,82 @@ namespace {
 // chunk-aware replication messages against it).
 constexpr int64_t kBinlogRotateSize = 64LL << 20;
 constexpr size_t kIoBufSize = 256 * 1024;
+
+// SHA-1 of the first `size` bytes of `path`; nullopt when they cannot be
+// read or the file is shorter.
+std::optional<Sha1Digest> Sha1OfFile(const std::string& path, int64_t size) {
+  int fd = open(path.c_str(), O_RDONLY);
+  if (fd < 0) return std::nullopt;
+  Sha1Stream sha1;
+  char buf[kIoBufSize];
+  while (size > 0) {
+    ssize_t r = read(fd, buf, std::min<int64_t>(size, sizeof(buf)));
+    if (r < 0 && errno == EINTR) continue;
+    if (r <= 0) break;
+    sha1.Update(buf, static_cast<size_t>(r));
+    size -= r;
+  }
+  close(fd);
+  if (size > 0) return std::nullopt;
+  return sha1.Final();
+}
+
+// The segment buffer a thread keeps from one upload to the next.
+struct SegmentBuf {
+  std::unique_ptr<char[]> buf;
+  int64_t cap = 0;
+  int64_t written = 0;  // from the start, since the last ReleaseTmpSegment
+};
+thread_local SegmentBuf t_seg;
+
+// Bytes [off, off + len) of an upload's tmp file, for the chunker, the
+// fingerprint call and the chunk store to read: in a buffer the calling
+// thread keeps from one segment to the next, or nullptr when they cannot
+// be read.  The buffer grows to the largest segment the thread has seen
+// (at most dedup_segment_bytes) and is never zero-filled, so its pages
+// are faulted in once a thread and not once an upload: a fresh 64 MB
+// std::string a segment cost 2 ms per MB of upload.  Mapping the tmp
+// file instead read lower here and 26 ms per MB dearer in the send to the
+// sidecar (PERF.md section 6, PR 34).
+const char* ReadTmpSegment(int fd, int64_t off, int64_t len) {
+  SegmentBuf& s = t_seg;
+  if (s.cap < len) {
+    s.cap = 0;  // a throwing new leaves no capacity behind a null buffer
+    s.written = 0;
+    s.buf.reset();  // the old one first: never both resident
+    s.buf.reset(new char[static_cast<size_t>(len)]);
+    s.cap = len;
+  }
+  s.written = std::max(s.written, len);
+  int64_t got = 0;
+  while (got < len) {
+    ssize_t r = pread(fd, s.buf.get() + got, static_cast<size_t>(len - got),
+                      off + got);
+    if (r < 0 && errno == EINTR) continue;
+    if (r <= 0) return nullptr;
+    got += r;
+  }
+  return s.buf.get();
+}
+
+// After an upload's last segment: the buffer's bytes are dead, and the
+// kernel may take their pages when it needs memory, without swapping
+// them.  Until it does they stay mapped and the next upload writes over
+// them without a fault; after, that upload faults them in again, as
+// every upload did before there was a kept buffer.  So what idle workers
+// hold is a loan and not a reservation (OPERATIONS.md, "Host memory").
+void ReleaseTmpSegment() {
+#ifdef MADV_FREE
+  SegmentBuf& s = t_seg;
+  const uintptr_t page = static_cast<uintptr_t>(sysconf(_SC_PAGESIZE));
+  uintptr_t lo = reinterpret_cast<uintptr_t>(s.buf.get());
+  uintptr_t hi = (lo + static_cast<uintptr_t>(s.written)) & ~(page - 1);
+  lo = (lo + page - 1) & ~(page - 1);
+  if (hi > lo) madvise(reinterpret_cast<void*>(lo), hi - lo, MADV_FREE);
+  s.written = 0;
+#endif
+}
+
 // Per-chunk payload cap, shared by FETCH_CHUNK serving and the
 // SYNC_CREATE_RECIPE entry validation: no single declared chunk may make
 // a dio worker allocate more than this.
@@ -672,9 +749,11 @@ bool StorageServer::Init(std::string* error) {
     });
   }
 
-  FDFS_LOG_INFO("storage daemon up: group=%s port=%d store_paths=%d dedup=%s",
-                cfg_.group_name.c_str(), cfg_.port, store_.store_path_count(),
-                dedup_ != nullptr ? dedup_->Name() : "none");
+  FDFS_LOG_INFO(
+      "storage daemon up: group=%s port=%d store_paths=%d dedup=%s crc32=%s",
+      cfg_.group_name.c_str(), cfg_.port, store_.store_path_count(),
+      dedup_ != nullptr ? dedup_->Name() : "none",
+      Crc32Chosen() == Crc32Impl::kFolded ? "folded" : "sliced");
   return true;
 }
 
@@ -1026,6 +1105,15 @@ void StorageServer::InitStatsRegistry() {
   ctr_chunkfetch_batches_ = registry_.Counter("chunkfetch.batches");
   ctr_chunkfetch_chunks_ = registry_.Counter("chunkfetch.chunks");
   ctr_chunkfetch_bytes_ = registry_.Counter("chunkfetch.bytes");
+  // What the upload path's passes over a body cost and skip: bytes the
+  // receive stage SHA-1'd (uploads whose digest Judge reads: neither
+  // appender nor chunk-eligible), flat fall-backs of chunk-eligible
+  // uploads that took the digest from the tmp file instead, and the
+  // loop Crc32 chose at start (0 sliced, 1 folded).
+  ctr_recv_hashed_bytes_ = registry_.Counter("upload.recv_hashed_bytes");
+  ctr_fallback_rehash_ = registry_.Counter("upload.fallback_rehash");
+  registry_.GaugeFn("crc32.impl",
+                    [] { return static_cast<int64_t>(Crc32Chosen()); });
   ctr_dedup_chunk_hits_ = registry_.Counter("dedup.chunk_hits");
   ctr_dedup_chunk_misses_ = registry_.Counter("dedup.chunk_misses");
   // Negotiated uploads on the ingest edge (UPLOAD_RECIPE/UPLOAD_CHUNKS):
@@ -2380,6 +2468,7 @@ void StorageServer::ReadConn(Conn* c) {
         if (!c->discarding) {
           if (c->hashing) {
             c->sha1.Update(buf, static_cast<size_t>(n));
+            ctr_recv_hashed_bytes_->fetch_add(n, std::memory_order_relaxed);
           }
           c->crc32 = Crc32(buf, static_cast<size_t>(n), c->crc32);
           ssize_t w = write(c->file_fd, buf, static_cast<size_t>(n));
@@ -3990,7 +4079,13 @@ bool StorageServer::BeginUpload(Conn* c) {
   c->file_size = size;
   c->file_remaining = size;
   c->crc32 = 0;
-  c->hashing = dedup_ != nullptr;
+  // The whole-file digest is read by Judge / Commit in FinishUpload,
+  // which an appender never reaches and a chunk-eligible upload only
+  // when its chunked store failed (FinishUpload then takes the digest
+  // from the tmp file): the receive stage hashes the rest.
+  bool appender =
+      static_cast<StorageCmd>(c->cmd) == StorageCmd::kUploadAppenderFile;
+  c->hashing = dedup_ != nullptr && !appender && !ChunkEligible(size);
   if (c->hashing) c->sha1 = Sha1Stream();
   c->tmp_path = store_.NewTmpPath(spi);
   c->file_fd = open(c->tmp_path.c_str(), O_CREAT | O_WRONLY | O_TRUNC, 0644);
@@ -4469,6 +4564,20 @@ void StorageServer::FinishUpload(Conn* c) {
 
   // Dedup verdict (plugin boundary; appender files are mutable => exempt).
   if (dedup_ != nullptr && !appender) {
+    if (!c->hashing) {
+      // A chunk-eligible upload whose chunked store failed (a dead
+      // sidecar, refused widths): the digest the receive stage left out.
+      auto late = Sha1OfFile(c->tmp_path, c->file_size);
+      if (!late.has_value()) {
+        FDFS_LOG_ERROR("re-read of %s failed", c->tmp_path.c_str());
+        unlink(c->tmp_path.c_str());
+        c->tmp_path.clear();
+        Respond(c, 5);
+        return;
+      }
+      digest = late->Hex();
+      ctr_fallback_rehash_->fetch_add(1, std::memory_order_relaxed);
+    }
     auto verdict = dedup_->Judge(digest, c->file_size);
     if (verdict.duplicate) {
       auto dup = DecodeFileId(verdict.dup_of);
@@ -4625,22 +4734,15 @@ bool StorageServer::ChunkedStoreWith(DedupPlugin* plugin,
   const int64_t session = plugin->BeginChunked();
   Recipe recipe;
   recipe.logical_size = size;
-  std::string seg;
   int64_t seg_base = 0;
   bool ok = true;
   while (ok && seg_base < size) {
     int64_t want = std::min<int64_t>(cfg_.dedup_segment_bytes,
                                      size - seg_base);
     int64_t t_read = MonoUs();
-    seg.resize(static_cast<size_t>(want));
-    int64_t got = 0;
-    while (got < want) {
-      ssize_t r = read(fd, seg.data() + got, want - got);
-      if (r <= 0) break;
-      got += r;
-    }
+    const char* seg = ReadTmpSegment(fd, seg_base, want);
     if (stage != nullptr) stage->readback += MonoUs() - t_read;
-    if (got != want) {
+    if (seg == nullptr) {
       ok = false;
       break;
     }
@@ -4650,8 +4752,8 @@ bool StorageServer::ChunkedStoreWith(DedupPlugin* plugin,
     int64_t t0 = MonoUs();
     TakeDedupLockWaitUs();  // clear: attribute only this call's wait
     TakeDedupCdcUs();
-    bool fp_ok = plugin->FingerprintChunks(session, seg.data(), seg.size(),
-                                           seg_base, &fps);
+    bool fp_ok = plugin->FingerprintChunks(
+        session, seg, static_cast<size_t>(want), seg_base, &fps);
     if (stage != nullptr) {
       stage->fp += MonoUs() - t0;
       stage->fp_lock += TakeDedupLockWaitUs();
@@ -4665,9 +4767,8 @@ bool StorageServer::ChunkedStoreWith(DedupPlugin* plugin,
     for (const ChunkFp& fp : fps) {
       bool existed = false;
       std::string err;
-      if (!cs->PutAndRef(fp.digest_hex,
-                         seg.data() + (fp.offset - seg_base), fp.length,
-                         &existed, &err)) {
+      if (!cs->PutAndRef(fp.digest_hex, seg + (fp.offset - seg_base),
+                         fp.length, &existed, &err)) {
         FDFS_LOG_ERROR("chunk store: %s", err.c_str());
         ok = false;
         break;
@@ -4686,6 +4787,7 @@ bool StorageServer::ChunkedStoreWith(DedupPlugin* plugin,
     seg_base += want;
   }
   close(fd);
+  ReleaseTmpSegment();
   std::string err;
   if (!ok || !cs->StoreRecipe(rcp_path, recipe, &err)) {
     if (ok) FDFS_LOG_ERROR("recipe write: %s", err.c_str());

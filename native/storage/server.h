@@ -653,6 +653,8 @@ class StorageServer {
   std::atomic<int64_t>* ctr_chunkfetch_batches_ = nullptr;
   std::atomic<int64_t>* ctr_chunkfetch_chunks_ = nullptr;
   std::atomic<int64_t>* ctr_chunkfetch_bytes_ = nullptr;
+  std::atomic<int64_t>* ctr_recv_hashed_bytes_ = nullptr;
+  std::atomic<int64_t>* ctr_fallback_rehash_ = nullptr;
   std::atomic<int64_t>* ctr_dedup_chunk_hits_ = nullptr;
   std::atomic<int64_t>* ctr_dedup_chunk_misses_ = nullptr;
   // Negotiated-upload (ingest edge) accounting: completed recipe

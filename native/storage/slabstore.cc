@@ -684,6 +684,16 @@ SlabStore::CompactResult SlabStore::Compact(
   // starves the rest of the round — they retry next pass, after the
   // quarantine machinery marks their bad slots dead.
   std::set<int64_t> skip;
+  // A call compacts the slabs that were there when it began.  The slab
+  // it rolls live records into, and every later one, wait for the next
+  // call: a foreground that kills records as fast as a round copies them
+  // would hand this loop a newly ripe active slab every round, and the
+  // scrub pass behind it would end only when the foreground does.
+  int64_t newest = 0;
+  {
+    std::lock_guard<RankedMutex> lk(mu_);
+    for (const auto& [id, info] : slabs_) newest = std::max(newest, id);
+  }
   for (;;) {
     if (stop != nullptr && stop()) return res;
     // Pick the deadest eligible victim past the dead-share threshold
@@ -695,7 +705,7 @@ SlabStore::CompactResult SlabStore::Compact(
     {
       std::lock_guard<RankedMutex> lk(mu_);
       for (const auto& [id, info] : slabs_) {
-        if (skip.count(id)) continue;
+        if (skip.count(id) || id > newest) continue;
         bool empty = info.live_slots == 0 && id != active_id_;
         bool ripe = empty ||
                     (info.size_bytes > 0 &&

@@ -183,8 +183,11 @@ class SlabStore {
                                             const std::string& payload)>& fn)
       const;
 
-  // Online compaction: pick dead-enough slabs (never the active one),
-  // re-append their verified-live records, and unlink them.  pace(n) is
+  // Online compaction: pick dead-enough slabs among those present when
+  // the call began (the active one is retired first; slabs made during
+  // the call wait for the next, so a call ends whatever the foreground
+  // kills meanwhile), re-append their verified-live records, and unlink
+  // them.  pace(n) is
   // called per record copied with the bytes read (the scrub manager's
   // token bucket slots in here); stop() is polled between records so
   // shutdown never waits on a long compaction.  Records whose payload

@@ -67,10 +67,65 @@ static void TestBase64() {
   CHECK(!Base64UrlDecode("abcde", &dec));  // impossible length (5 % 4 == 1)
 }
 
+// The one-table loop Crc32 was until PR 34: the reference its loops are
+// held to.
+static uint32_t Crc32OneTable(const uint8_t* p, size_t len, uint32_t seed) {
+  static uint32_t table[256];
+  if (table[1] == 0) {
+    for (uint32_t i = 0; i < 256; ++i) {
+      uint32_t c = i;
+      for (int k = 0; k < 8; ++k) c = (c & 1) ? 0xEDB88320u ^ (c >> 1) : c >> 1;
+      table[i] = c;
+    }
+  }
+  uint32_t c = seed ^ 0xFFFFFFFFu;
+  for (size_t i = 0; i < len; ++i) c = table[(c ^ p[i]) & 0xFF] ^ (c >> 8);
+  return c ^ 0xFFFFFFFFu;
+}
+
 static void TestCrc32() {
   // zlib golden: crc32(b"123456789") == 0xCBF43926
   CHECK_EQ(Crc32("123456789", 9), 0xCBF43926u);
   CHECK_EQ(Crc32("", 0), 0u);
+
+  std::vector<Crc32Impl> impls = {Crc32Impl::kSliced};
+  if (Crc32Chosen() == Crc32Impl::kFolded) impls.push_back(Crc32Impl::kFolded);
+  std::vector<size_t> lengths;
+  for (size_t n = 0; n <= 70; ++n) lengths.push_back(n);
+  for (size_t n : {255u, 256u, 257u, 4095u, 4096u, 4097u, (1u << 20) + 3})
+    lengths.push_back(n);
+  std::vector<uint8_t> buf(lengths.back() + 8);
+  uint32_t x = 34;
+  for (auto& b : buf) {
+    x = x * 1664525u + 1013904223u;
+    b = static_cast<uint8_t>(x >> 24);
+  }
+  for (Crc32Impl impl : impls) {
+    // Every length at every offset into the buffer, with and without a
+    // register carried in.
+    for (size_t n : lengths) {
+      for (size_t off = 0; off < 8; ++off) {
+        const uint8_t* p = buf.data() + off;
+        CHECK_EQ(Crc32With(impl, p, n), Crc32OneTable(p, n, 0));
+        CHECK_EQ(Crc32With(impl, p, n, 0xDEADBEEFu),
+                 Crc32OneTable(p, n, 0xDEADBEEFu));
+      }
+    }
+    // Chained calls split at every position give the whole's sum: of a
+    // 64-byte string, and of one long enough that both parts fold.
+    for (size_t total : {size_t{64}, size_t{300}}) {
+      uint32_t whole = Crc32OneTable(buf.data(), total, 0);
+      for (size_t cut = 0; cut <= total; ++cut) {
+        uint32_t head = Crc32With(impl, buf.data(), cut);
+        CHECK_EQ(Crc32With(impl, buf.data() + cut, total - cut, head), whole);
+      }
+    }
+  }
+  // The receive stage's shape: 256 KB pieces, whatever loop was chosen.
+  uint32_t piecewise = 0;
+  for (size_t off = 0; off < (1u << 20); off += 256 << 10)
+    piecewise = Crc32(buf.data() + off, 256 << 10, piecewise);
+  CHECK_EQ(piecewise, Crc32OneTable(buf.data(), 1u << 20, 0));
 }
 
 static void TestSha1() {

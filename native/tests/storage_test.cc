@@ -706,6 +706,31 @@ static void TestSlabStoreAppendRescanCompact() {
     CHECK(ss2.Read(kSlabKindRecipe, "data/00/00/file.bin.rcp", &back));
     CHECK(back == "RECIPE");
     CHECK(stat(path.c_str(), &st1) != 0);  // victim unlinked
+    // A foreground that kills records as fast as a round copies them
+    // (here: from inside the pace callback) does not keep one call
+    // going: the slab the live records rolled into waits for the next.
+    CHECK(ss2.MarkDead(kSlabKindChunk, key_for(16)));
+    CHECK(ss2.MarkDead(kSlabKindChunk, key_for(17)));
+    int churned = 0;
+    auto churn = [&](int64_t) {
+      if (churned >= 64) return;  // the old loop would run on: bound the test
+      std::string p(4096, static_cast<char>('A' + churned % 26));
+      p += std::to_string(churned++);
+      std::string key = Sha1(p.data(), p.size()).Hex(), err;
+      CHECK(ss2.Append(kSlabKindChunk, key, p.data(), p.size(), false, &err));
+      CHECK(ss2.MarkDead(kSlabKindChunk, key));
+    };
+    res = ss2.Compact(churn, nullptr);
+    CHECK(res.slabs_compacted == 1);
+    CHECK(churned > 0 && churned < 64);
+    CHECK(ss2.slots_dead() == churned);
+    res = ss2.Compact(nullptr, nullptr);  // the next call takes that slab
+    CHECK(res.slabs_compacted == 1);
+    CHECK(ss2.slots_dead() == 0);
+    for (int i = 18; i < 20; ++i) {
+      CHECK(ss2.Read(kSlabKindChunk, key_for(i), &back));
+      CHECK(back == payload_for(i));
+    }
   }
 }
 

@@ -15,6 +15,7 @@ BUILD = os.path.join(REPO, "native", "build")
 CODEC = os.path.join(BUILD, "fdfs_codec")
 COMMON_TEST = os.path.join(BUILD, "common_test")
 TRACKER_TEST = os.path.join(BUILD, "tracker_test")
+STORAGE_TEST = os.path.join(BUILD, "storage_test")
 
 
 def _ensure_built():
@@ -22,7 +23,7 @@ def _ensure_built():
     # before the stats subsystem has codec+common_test but not it, and
     # must be rebuilt.
     from tests.harness import ensure_native_built
-    ensure_native_built((CODEC, COMMON_TEST, TRACKER_TEST))
+    ensure_native_built((CODEC, COMMON_TEST, TRACKER_TEST, STORAGE_TEST))
 
 
 @pytest.fixture(scope="module", autouse=True)
@@ -44,6 +45,13 @@ def test_cpp_tracker_tests_pass():
     # Built by the same configure pass; covers the beat-stats ->
     # ClusterStatJson round-trip under the generated field names.
     subprocess.run([TRACKER_TEST], check=True, capture_output=True)
+
+
+def test_cpp_storage_tests_pass():
+    # The stores under the daemon (trunk, chunk store, slabs, EC): among
+    # them that one slab compaction ends under a foreground that kills
+    # records as fast as it copies them.
+    subprocess.run([STORAGE_TEST], check=True, capture_output=True)
 
 
 def test_generated_protocol_header_current():
@@ -112,6 +120,19 @@ def test_sha1_matches():
 
 def test_crc32_matches_zlib():
     data = os.urandom(50_000)
+    assert int(_run("crc32", stdin=data)) == zlib.crc32(data)
+
+
+# One case a length: the ends of every loop `Crc32` has (the byte tail under
+# 8, eight bytes a step, 64 bytes a fold with 16-byte folds after it) and
+# a length past 1 MiB that is a multiple of none of them.
+CRC_LENGTHS = [*range(0, 71), 255, 256, 257, 4095, 4096, 4097, (1 << 20) + 3]
+_CRC_BYTES = random.Random(34).randbytes(CRC_LENGTHS[-1])
+
+
+@pytest.mark.parametrize("length", CRC_LENGTHS)
+def test_crc32_matches_zlib_at_length(length):
+    data = _CRC_BYTES[:length]
     assert int(_run("crc32", stdin=data)) == zlib.crc32(data)
 
 
