@@ -1201,6 +1201,12 @@ def cmd_sidecar_trace(args: list[str]) -> int:
           f"{after['requests'] - before['requests']} requests, host stall "
           f"{(after['host_stall_us'] - before['host_stall_us']) / 1e3:.1f} ms,"
           f" device memory peak {after['memory_peak_bytes'] / 1e6:.1f} MB")
+    # re-index requests: bytes the daemon already stores, sent for their
+    # signature (a negotiated upload's commit, a recovered file)
+    print(f"of them re-index: "
+          f"{(after.get('reindex_bytes', 0) - before.get('reindex_bytes', 0)) / 1e6:.1f}"
+          f" MB in {after.get('reindex_requests', 0) - before.get('reindex_requests', 0)}"
+          " requests (the rest: uploads)")
     # calls a body is how often the one-call receive engaged (1 when the
     # whole body was waited for inside the kernel)
     bodies = (after["span_n"].get("fdfs.sidecar.parse", 0)

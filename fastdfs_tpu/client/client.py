@@ -93,6 +93,12 @@ class FdfsClient:
         self.dedup_min_ratio = dedup_min_ratio
         self._dedup_digest_cache = dedup_digest_cache
         self._seen_digests: OrderedDict[bytes, None] = OrderedDict()
+        # How each storage node cuts, as it answered QUERY_CHUNKING:
+        # {(ip, port): ChunkingParams}.  A negotiated upload is cut with
+        # its target's entry and with nothing else; an entry is dropped
+        # when that node refuses an upload cut with it (a node restarted
+        # at other widths), so the next upload asks again.
+        self._chunking: dict[tuple[str, int], object] = {}
         # Parallel ranged downloads (opt-in): with parallel_downloads > 1
         # every read over ~one range splits into download_range_bytes
         # ranges fetched concurrently, each from the replica jump-hash
@@ -409,32 +415,49 @@ class FdfsClient:
         - the estimated dup ratio (recently-uploaded-digest LRU hit
           fraction) is under ``min_dup_ratio`` — fresh content would pay
           the extra round-trip for nothing (pass 0 to always negotiate);
-        - the daemon lacks the opcodes or a chunk store, or the session
-          fails mid-flight (StorageClient-level fallback).
+        - the target node does not state how it cuts (an older daemon,
+          no chunk store): the client has no parameters of its own;
+        - the daemon lacks the opcodes or a chunk store, refuses the
+          recipe, or the session fails mid-flight (StorageClient-level
+          fallback).
         """
         if stats is None:
             stats = {}
-        ratio_floor = (self.dedup_min_ratio if min_dup_ratio is None
-                       else min_dup_ratio)
-        if len(data) < self.dedup_min_bytes:
-            stats.update(fallback="small", bytes_sent=len(data))
+
+        def plain(reason: str) -> str:
+            stats.update(fallback=reason, bytes_sent=len(data))
             self._fallbacks["dedup_fallback_plain"] += 1
             return self._upload_buffer_plain(data, ext=ext, group=group,
                                              key=key)
+
+        ratio_floor = (self.dedup_min_ratio if min_dup_ratio is None
+                       else min_dup_ratio)
+        if len(data) < self.dedup_min_bytes:
+            return plain("small")
         from fastdfs_tpu.client.fingerprint import fingerprint_buffer
-        chunks = [(fp.length, fp.digest) for fp in fingerprint_buffer(data)]
+        tgt = self._with_tracker(lambda t: t.query_store(group, key=key))
+        node = (tgt.ip, tgt.port)
+        params = self._chunking.get(node)
+        if params is None:
+            with self._storage(tgt) as s:
+                params = s.query_chunking()
+            if params is None:
+                return plain("no_chunking_params")
+            self._chunking[node] = params
+        if len(data) < params.chunk_threshold:
+            # under the node's own chunking threshold: it would store the
+            # payload flat and answer the recipe ENOTSUP
+            return plain("small")
+        chunks = [(fp.length, fp.digest)
+                  for fp in fingerprint_buffer(data, params)]
         if ratio_floor > 0:
             hits = sum(1 for _, d in chunks if d in self._seen_digests)
             estimate = hits / len(chunks) if chunks else 0.0
             stats["estimated_dup_ratio"] = estimate
             if estimate < ratio_floor:
                 self._remember_digests(chunks)
-                stats.update(fallback="low_estimate", bytes_sent=len(data))
-                self._fallbacks["dedup_fallback_plain"] += 1
-                return self._upload_buffer_plain(data, ext=ext, group=group,
-                                                 key=key)
+                return plain("low_estimate")
         self._remember_digests(chunks)
-        tgt = self._with_tracker(lambda t: t.query_store(group, key=key))
         with self._storage(tgt) as s:
             fid = s.upload_buffer_dedup(
                 data, ext=ext, store_path_index=tgt.store_path_index,
@@ -444,6 +467,7 @@ class FdfsClient:
         # stats dict — one counter covers every dedup→plain path.
         if stats.get("fallback"):
             self._fallbacks["dedup_fallback_plain"] += 1
+            self._chunking.pop(node, None)
         return fid
 
     def download_to_buffer(self, file_id: str, offset: int = 0,

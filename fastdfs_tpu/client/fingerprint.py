@@ -3,9 +3,14 @@
 The negotiated upload protocol (UPLOAD_RECIPE / UPLOAD_CHUNKS) moves the
 fingerprint work from the storage daemon to the ingest edge: the client
 chunks and hashes the payload locally, and only ships chunk bytes the
-daemon's content-addressed store has never seen.  Correctness therefore
-depends on the client producing the SAME cut points and digests as every
-daemon-side path:
+daemon's content-addressed store has never seen.  That dedups against
+what the node stored itself only if the client cuts exactly as its node
+cuts, so the client cuts with the node's own parameters and with nothing
+else: :class:`ChunkingParams` is what the node answered to
+``QUERY_CHUNKING`` (its ``dedup_cdc_widths``, its chunker's policy, its
+``dedup_chunk_threshold`` and ``dedup_segment_bytes``), and
+:func:`fingerprint_buffer` cuts every ``segment_bytes`` segment on its
+own, as the daemon does (a segment end is a cut):
 
 - cut points come from the shared gear CDC spec (``ops.gear_cdc``: one
   generated table, 32-byte window, identical greedy selection) — the
@@ -15,9 +20,10 @@ daemon-side path:
   hosts (C speed, no batch to amortize), ``ops.sha1.sha1_batch`` on TPU
   where the batched kernel amortizes the device round-trip.
 
-Like every dedup feature here, this is an optimization layer: a caller
-getting ``fingerprint_buffer`` wrong cannot corrupt the store (the
-daemon re-verifies SHA1(payload) == digest before admitting any byte).
+A sidecar-mode node holds the recipe to its own cut of the stored bytes
+before it acknowledges (``server.cc:ReindexRecovered``) and re-verifies
+SHA1(payload) == digest of every shipped chunk, so a caller getting this
+wrong cannot corrupt the store: it is refused and uploads plain.
 """
 
 from __future__ import annotations
@@ -25,6 +31,7 @@ from __future__ import annotations
 import hashlib
 from dataclasses import dataclass
 
+from fastdfs_tpu.common.protocol import unpack_chunking
 from fastdfs_tpu.ops import gear_cdc
 
 
@@ -32,6 +39,30 @@ from fastdfs_tpu.ops import gear_cdc
 class ChunkFingerprint:
     length: int
     digest: bytes  # 20-byte raw SHA1
+
+
+@dataclass(frozen=True)
+class ChunkingParams:
+    """How one storage node cuts (``protocol.CHUNKING_FIELDS``)."""
+    min_size: int
+    avg_bits: int
+    max_size: int
+    cdc_policy: int
+    chunk_threshold: int
+    segment_bytes: int
+
+    @classmethod
+    def from_wire(cls, body: bytes) -> "ChunkingParams":
+        return cls(**unpack_chunking(body))
+
+
+# What conf/storage.conf ships, for tests and tools that have no node to
+# ask.  An upload never cuts with these: it asks its node, and
+# ``fingerprint_buffer`` has no default to fall back on.
+SHIPPED_PARAMS = ChunkingParams(
+    gear_cdc.DEFAULT_MIN_SIZE, gear_cdc.DEFAULT_AVG_BITS,
+    gear_cdc.DEFAULT_MAX_SIZE, gear_cdc.CDC_POLICY_DEFAULT, 64 << 10,
+    64 << 20)
 
 
 def _tpu_up() -> bool:
@@ -81,38 +112,33 @@ def _digests_tpu(data: bytes, cuts: list[int]) -> list[bytes] | None:
         return None
 
 
-def fingerprint_buffer(
-    data: bytes,
-    min_size: int = gear_cdc.DEFAULT_MIN_SIZE,
-    avg_bits: int = gear_cdc.DEFAULT_AVG_BITS,
-    max_size: int = gear_cdc.DEFAULT_MAX_SIZE,
-    cdc_policy: int = gear_cdc.CDC_POLICY_DEFAULT,
-) -> list[ChunkFingerprint]:
-    """CDC-chunk ``data`` and SHA1 each chunk, exactly as the daemons do.
+def fingerprint_buffer(data: bytes,
+                       params: ChunkingParams) -> list[ChunkFingerprint]:
+    """CDC-chunk ``data`` and SHA1 each chunk, exactly as a node at
+    ``params`` does: each ``segment_bytes`` segment is cut on its own.
 
     Returns one :class:`ChunkFingerprint` per chunk, in stream order
     (lengths sum to ``len(data)``).  Empty input -> empty list.
-
-    ``cdc_policy`` must match the target group's policy (the default is
-    the frozen ref-identical rule); a client chunking under a different
-    policy than the daemon simply gets zero dedup hits — never
-    corruption, since the daemon re-verifies every digest.
     """
     if not data:
         return []
     use_tpu = _tpu_up()
-    if use_tpu:
-        cuts = gear_cdc.chunk_stream(data, min_size, avg_bits, max_size,
-                                     cdc_policy=cdc_policy)
-    else:
-        cuts = gear_cdc.chunk_stream_np(data, min_size, avg_bits, max_size,
-                                        cdc_policy=cdc_policy)
+    chunker = gear_cdc.chunk_stream if use_tpu else gear_cdc.chunk_stream_np
+    view = memoryview(data)
+    cuts: list[int] = []
+    for base in range(0, len(data), params.segment_bytes):
+        # one copy of a segment, where the payload has more than one
+        seg = (data if len(data) <= params.segment_bytes
+               else bytes(view[base:base + params.segment_bytes]))
+        cuts += [base + c for c in chunker(
+            seg, params.min_size, params.avg_bits, params.max_size,
+            cdc_policy=params.cdc_policy)]
     digests = _digests_tpu(data, cuts) if use_tpu else None
     if digests is None:
         digests = []
         start = 0
         for end in cuts:
-            digests.append(hashlib.sha1(data[start:end]).digest())
+            digests.append(hashlib.sha1(view[start:end]).digest())
             start = end
     out = []
     start = 0

@@ -177,6 +177,23 @@ class StorageClient:
 
     # -- dedup-aware negotiated upload (UPLOAD_RECIPE / UPLOAD_CHUNKS) ----
 
+    def query_chunking(self):
+        """How this node cuts (QUERY_CHUNKING 149), as the
+        ``fingerprint.ChunkingParams`` a negotiated upload to it must be
+        cut with; None when the node does not say (ENOTSUP: no chunk
+        store; EINVAL: an older daemon; an answer that makes no sense)."""
+        from fastdfs_tpu.client.fingerprint import ChunkingParams
+        self.conn.send_request(StorageCmd.QUERY_CHUNKING)
+        try:
+            return ChunkingParams.from_wire(
+                self.conn.recv_response("query_chunking"))
+        except StatusError as e:
+            if e.status in _DEDUP_FALLBACK_STATUSES:
+                return None
+            raise
+        except ValueError:
+            return None
+
     def upload_buffer_dedup(self, data: bytes, ext: str = "",
                             store_path_index: int = AUTO_STORE_PATH,
                             chunks: list[tuple[int, bytes]] | None = None,
@@ -185,21 +202,30 @@ class StorageClient:
         """Upload via the negotiated two-round-trip protocol: fingerprint
         locally, ask the daemon which chunks it lacks, ship only those.
 
-        ``chunks`` short-circuits fingerprinting when the caller already
-        has [(length, 20B raw sha1)] (FdfsClient computes it once for its
-        dup-ratio estimate).  Falls back to a plain ``upload_buffer``
-        transparently when the daemon has no chunk store (ENOTSUP), is
-        too old to know the opcode (EINVAL), or the session fails
-        mid-flight — same file ID semantics either way.  ``stats`` (if
-        given) is updated with chunks_total / chunks_missing /
-        bytes_sent / fallback for accounting and tests.
+        The payload is cut with the parameters this node states
+        (``query_chunking``); ``chunks`` short-circuits both when the
+        caller already has [(length, 20B raw sha1)] cut that way
+        (FdfsClient asks once per node and computes the list once for
+        its dup-ratio estimate).  Falls back to a plain ``upload_buffer``
+        transparently when the daemon does not state its parameters, has
+        no chunk store (ENOTSUP), is too old to know the opcode (EINVAL),
+        refuses the recipe at commit (not its own cut of the bytes), or
+        the session fails mid-flight — same file ID semantics either
+        way.  ``stats`` (if given) is updated with chunks_total /
+        chunks_missing / bytes_sent / fallback for accounting and tests.
         """
-        if chunks is None:
-            from fastdfs_tpu.client.fingerprint import fingerprint_buffer
-            chunks = [(fp.length, fp.digest)
-                      for fp in fingerprint_buffer(data)]
         if stats is None:
             stats = {}
+        if chunks is None:
+            from fastdfs_tpu.client.fingerprint import fingerprint_buffer
+            params = self.query_chunking()
+            if params is None:
+                stats.update(fallback="no_chunking_params",
+                             bytes_sent=len(data))
+                return self.upload_buffer(data, ext=ext,
+                                          store_path_index=store_path_index)
+            chunks = [(fp.length, fp.digest)
+                      for fp in fingerprint_buffer(data, params)]
         stats.update(chunks_total=len(chunks), chunks_missing=len(chunks),
                      bytes_sent=len(data), fallback="")
         if not chunks:  # empty payload: nothing to negotiate over

@@ -83,6 +83,37 @@ BEAT_STAT_FIELDS = (
 BEAT_STAT_COUNT = len(BEAT_STAT_FIELDS)
 
 # ---------------------------------------------------------------------------
+# How a node cuts (``StorageCmd.QUERY_CHUNKING`` response body): one
+# big-endian int64 slot per name, append-only.  A client of the negotiated
+# upload cuts with exactly these and with nothing of its own; a blob that
+# lacks one of them teaches nothing (``unpack_chunking`` raises, the
+# client uploads plain).  Pinned by the ``fdfs_codec ingest-wire`` golden.
+# ---------------------------------------------------------------------------
+
+CHUNKING_FIELDS = ("min_size", "avg_bits", "max_size", "cdc_policy",
+                   "chunk_threshold", "segment_bytes")
+
+
+def pack_chunking(values: dict[str, int]) -> bytes:
+    """QUERY_CHUNKING response body from named values (tests/goldens; the
+    production encoder is the C++ daemon)."""
+    return b"".join(long2buff(int(values[name])) for name in CHUNKING_FIELDS)
+
+
+def unpack_chunking(buf: bytes) -> dict[str, int]:
+    """Name a QUERY_CHUNKING blob; later slots a newer daemon appends are
+    ignored, a missing or senseless one raises ValueError."""
+    if len(buf) < 8 * len(CHUNKING_FIELDS):
+        raise ValueError(f"short QUERY_CHUNKING response: {len(buf)} bytes")
+    out = {name: buff2long(buf, i * 8)
+           for i, name in enumerate(CHUNKING_FIELDS)}
+    if not (0 < out["min_size"] < out["max_size"] <= out["segment_bytes"]
+            and 1 <= out["avg_bits"] <= 31 and out["cdc_policy"] in (1, 2)):
+        raise ValueError(f"senseless QUERY_CHUNKING response: {out}")
+    return out
+
+
+# ---------------------------------------------------------------------------
 # Integrity-engine status blob (fastdfs_tpu extension; no reference
 # equivalent — upstream FastDFS never re-reads stored bytes).
 #
@@ -770,6 +801,19 @@ class StorageCmd(enum.IntEnum):
     # admission-json cross-language golden).  Always answers, even
     # while shedding — it is CONTROL class by construction.
     ADMISSION_STATUS = 148
+    # How this node cuts (fastdfs_tpu extension): what a client of the
+    # negotiated upload has to know before it chunks, from the node it is
+    # about to upload to and from nowhere else.  Empty body -> the
+    # CHUNKING_FIELDS as big-endian int64 slots (append-only):
+    # dedup_cdc_widths' three, the cut-selection policy of the daemon's
+    # chunker, dedup_chunk_threshold and dedup_segment_bytes (every
+    # segment of that length is cut on its own; a segment end is a cut).
+    # ENOTSUP when the daemon has no chunk store; an OLDER daemon answers
+    # the unknown opcode with EINVAL.  Either way the client uploads
+    # plain (UPLOAD_FILE) and counts it: it never sends a recipe cut
+    # under parameters it was not told.  Pinned by the fdfs_codec
+    # ingest-wire golden beside the exchange it serves.
+    QUERY_CHUNKING = 149
 
     RESP = 100
     ACTIVE_TEST = 111
@@ -814,6 +858,7 @@ WIRE_GOLDENS = {
     "StorageCmd.SCRUB_STATUS": "scrub-status",
     "StorageCmd.UPLOAD_RECIPE": "ingest-wire",
     "StorageCmd.UPLOAD_CHUNKS": "ingest-wire",
+    "StorageCmd.QUERY_CHUNKING": "ingest-wire",
     "TrackerCmd.QUERY_PLACEMENT": "placement-wire",
     "TrackerCmd.GROUP_DRAIN": "group-admin",
     "TrackerCmd.GROUP_REACTIVATE": "group-admin",

@@ -116,6 +116,12 @@ _I64X2 = struct.Struct(">qq")
 _FINGERPRINT_CMDS = (StorageCmd.DEDUP_FINGERPRINT,
                      StorageCmd.DEDUP_FINGERPRINT_CUTS)
 
+# A session the daemon opened to re-index bytes it already stores (a
+# negotiated upload's commit, a recovered file) carries this bit
+# (dedup.h, kDedupReindexSessionBit): the root span says `reindex=1` and
+# the bytes are also counted apart, so device time can be split by who asked.
+REINDEX_SESSION_BIT = 1 << 62
+
 _SESSION_TTL = 600.0  # seconds before an uncommitted session is reaped
 _SHIPPED_WIDTHS = (DedupConfig.min_size, DedupConfig.avg_bits,
                    DedupConfig.max_size)
@@ -222,7 +228,8 @@ class DedupSidecar:
                       "lock_wait_us": 0, "engine_us": 0,
                       "verify_host_fallbacks": 0,
                       "span_us": {}, "span_n": {}, "host_stall_us": 0,
-                      "recv_calls": 0, "recv_bytes": 0}
+                      "recv_calls": 0, "recv_bytes": 0,
+                      "reindex_bytes": 0, "reindex_requests": 0}
         if state_dir:
             self._load_state()
 
@@ -429,6 +436,9 @@ class DedupSidecar:
                                 else np.minimum(sess.sig, sig))
                 self.stats["fingerprint_bytes"] += len(data)
                 self.stats["chunks"] += len(spans)
+                if session_id & REINDEX_SESSION_BIT:
+                    self.stats["reindex_bytes"] += len(data)
+                    self.stats["reindex_requests"] += 1
             # The two old counters are those two spans: one clock for each.
             ns = acc["span_ns"]
             self.stats["engine_us"] += ns["fdfs.engine.fingerprint"] // 1000
@@ -648,6 +658,7 @@ class DedupSidecar:
         if cmd in _FINGERPRINT_CMDS and len(body) >= 16:
             ids = dict(zip(("session", "base_offset"),
                            _I64X2.unpack_from(body)))
+            ids["reindex"] = int(bool(ids["session"] & REINDEX_SESSION_BIT))
         with span("fdfs.sidecar.request", acc, cmd=cmd, bytes=len(body),
                   **ids):
             if cmd == StorageCmd.DEDUP_FINGERPRINT:

@@ -13,8 +13,10 @@
 
 #include <cstddef>
 #include <cstdint>
+#include <string>
 #include <vector>
 
+#include "common/bytes.h"     // PutInt64BE
 #include "common/gear_gen.h"  // kCdcDefault*
 
 namespace fdfs {
@@ -34,6 +36,25 @@ struct CdcWidths {
            max_size == o.max_size;
   }
 };
+
+// The cut-selection policy GearChunkStream implements (gear_cdc.py's
+// CDC_POLICY_DEFAULT; the daemon's chunker knows no other).
+constexpr int kCdcPolicyNative = 1;
+
+// QUERY_CHUNKING response body: how this node cuts, as six big-endian
+// int64 slots (protocol.py CHUNKING_FIELDS: min_size, avg_bits, max_size,
+// cdc_policy, chunk_threshold, segment_bytes).  A client of the
+// negotiated upload cuts with these and nothing of its own.
+inline std::string PackChunkingParams(const CdcWidths& w,
+                                      int64_t chunk_threshold,
+                                      int64_t segment_bytes) {
+  const int64_t slots[6] = {w.min_size,       w.avg_bits,      w.max_size,
+                            kCdcPolicyNative, chunk_threshold, segment_bytes};
+  std::string body(sizeof(slots), '\0');
+  for (int i = 0; i < 6; ++i)
+    PutInt64BE(slots[i], reinterpret_cast<uint8_t*>(body.data()) + i * 8);
+  return body;
+}
 
 // Exclusive chunk end offsets for data[0..n) (final offset is n; empty
 // input -> empty vector).  Semantics: hash resets at each chunk start; a

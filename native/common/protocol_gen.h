@@ -218,6 +218,7 @@ enum class StorageCmd : uint8_t {
   kHealthStatus = 146,
   kPriority = 147,
   kAdmissionStatus = 148,
+  kQueryChunking = 149,
   kResp = 100,
   kActiveTest = 111,
 };

@@ -38,6 +38,12 @@ struct ChunkFp {
   std::string digest_hex;  // 40-char lowercase SHA1 of the chunk bytes
 };
 
+// A session opened to re-index bytes the daemon already stores (a
+// negotiated upload's commit, a recovered file) carries this bit in its
+// id (sidecar.py, REINDEX_SESSION_BIT): the sidecar's spans and stats
+// tell such requests from an upload's.  BeginChunked never sets it.
+constexpr int64_t kDedupReindexSessionBit = int64_t{1} << 62;
+
 class DedupPlugin {
  public:
   virtual ~DedupPlugin() = default;

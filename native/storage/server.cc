@@ -903,6 +903,7 @@ constexpr ServedOp kServedOps[] = {
     {StorageCmd::kSyncCreateRecipe, "sync_create_recipe"},
     {StorageCmd::kUploadRecipe, "upload_recipe"},
     {StorageCmd::kUploadChunks, "upload_chunks"},
+    {StorageCmd::kQueryChunking, "query_chunking"},
     {StorageCmd::kFetchRecipe, "fetch_recipe"},
     {StorageCmd::kFetchChunk, "fetch_chunk"},
     {StorageCmd::kTraceDump, "trace_dump"},
@@ -1124,6 +1125,18 @@ void StorageServer::InitStatsRegistry() {
   ctr_ingest_bytes_saved_wire_ =
       registry_.Counter("ingest.bytes_saved_wire");
   ctr_ingest_fallbacks_ = registry_.Counter("ingest.recipe_fallbacks");
+  // Chunks of committed negotiated uploads by where their bytes came
+  // from (the store / the wire), and the commit's stages per request.
+  ctr_ingest_chunks_present_ = registry_.Counter("ingest.chunks_present");
+  ctr_ingest_chunks_shipped_ = registry_.Counter("ingest.chunks_shipped");
+  hist_ingest_negotiate_ = registry_.Histogram(
+      "ingest.negotiate_us", StatsRegistry::LatencyBucketsUs());
+  hist_ingest_present_ = registry_.Histogram(
+      "ingest.commit_present_us", StatsRegistry::LatencyBucketsUs());
+  hist_ingest_verify_ = registry_.Histogram(
+      "ingest.commit_verify_us", StatsRegistry::LatencyBucketsUs());
+  hist_ingest_reindex_ = registry_.Histogram(
+      "ingest.reindex_us", StatsRegistry::LatencyBucketsUs());
   registry_.GaugeFn("ingest.sessions_active", [this] {
     std::lock_guard<RankedMutex> lk(ingest_mu_);
     return static_cast<int64_t>(ingest_sessions_.size());
@@ -1819,6 +1832,11 @@ void StorageServer::ResetForNextRequest(Conn* c) {
   c->binlog_us = 0;
   c->cdc_us = 0;
   c->readback_us = 0;
+  c->negotiate_us = 0;
+  c->present_us = 0;
+  c->verify_us = 0;
+  c->recipe_us = 0;
+  c->reindex_us = 0;
   c->ingest_session = 0;
   c->ingest_chunks_total = 0;
   c->ingest_chunks_missing = 0;
@@ -1971,9 +1989,20 @@ void StorageServer::LogAccess(Conn* c, uint8_t status, int64_t bytes) {
     case StorageCmd::kUploadFile:
     case StorageCmd::kUploadAppenderFile:
     case StorageCmd::kUploadSlaveFile:
-    case StorageCmd::kUploadChunks:  // file_size = logical, not wire bytes
       if (status == 0 && hist_upload_bytes_ != nullptr)
         hist_upload_bytes_->Observe(c->file_size);
+      break;
+    case StorageCmd::kUploadChunks:  // file_size = logical, not wire bytes
+      if (status == 0 && hist_upload_bytes_ != nullptr) {
+        hist_upload_bytes_->Observe(c->file_size);
+        hist_ingest_present_->Observe(c->present_us);
+        hist_ingest_verify_->Observe(c->verify_us);
+        hist_ingest_reindex_->Observe(c->reindex_us);
+      }
+      break;
+    case StorageCmd::kUploadRecipe:
+      if (status == 0 && hist_ingest_negotiate_ != nullptr)
+        hist_ingest_negotiate_->Observe(c->negotiate_us);
       break;
     case StorageCmd::kDownloadFile:
       if (status == 0 && hist_download_bytes_ != nullptr)
@@ -1986,7 +2015,8 @@ void StorageServer::LogAccess(Conn* c, uint8_t status, int64_t bytes) {
     std::lock_guard<RankedMutex> lk(log_mu_);
     // "<epoch.sec> <client_ip> <cmd> <status> <bytes> <cost_us>
     //  <recv_us> <work_us> <fp_us> <fp_lock_us> <cswrite_us> <binlog_us>
-    //  <req_bytes> <cdc_us> <dio_wait_us> <readback_us>" — per-stage split
+    //  <req_bytes> <cdc_us> <dio_wait_us> <readback_us> <negotiate_us>
+    //  <present_us> <verify_us> <recipe_us> <reindex_us>" — per-stage split
     // (SURVEY.md §5): recv = body receive
     // window, work = dio-stage time, then the chunked-upload splits
     // inside the work window (fingerprint wall, its sidecar-lock-wait
@@ -1996,7 +2026,13 @@ void StorageServer::LogAccess(Conn* c, uint8_t status, int64_t bytes) {
     // cdc = the native chunker's share of fp, dio_wait = the wait in the
     // dio queue at the head of the work window, readback = the tmp file
     // read back segment by segment before each fingerprint call (inside
-    // work, outside fp).  Columns are 0 when a stage did not occur;
+    // work, outside fp); and last the negotiated upload's own stages:
+    // negotiate (UPLOAD_RECIPE: parse + PinAndMask), then inside an
+    // UPLOAD_CHUNKS commit present (RefOne + ReadChunk + CRC of the chunks
+    // the store had), verify (digest check + PutAndRef of the shipped
+    // ones; both inside cswrite), recipe (id + recipe write) and reindex
+    // (the stored file read back and fingerprinted for its signature).
+    // Columns are 0 when a stage did not occur;
     // tools/access_log_stages.py aggregates them into the bench stage
     // table.
     int64_t recv_us =
@@ -2005,7 +2041,7 @@ void StorageServer::LogAccess(Conn* c, uint8_t status, int64_t bytes) {
         c->work_start_us > 0 ? now_us - c->work_start_us : 0;
     fprintf(access_log_,
             "%lld %s %d %d %lld %lld %lld %lld %lld %lld %lld %lld %lld %lld "
-            "%lld %lld\n",
+            "%lld %lld %lld %lld %lld %lld %lld\n",
             static_cast<long long>(time(nullptr)), c->peer_ip.c_str(), c->cmd,
             status, static_cast<long long>(bytes),
             static_cast<long long>(now_us - c->req_start_us),
@@ -2018,7 +2054,12 @@ void StorageServer::LogAccess(Conn* c, uint8_t status, int64_t bytes) {
             static_cast<long long>(c->pkg_len),
             static_cast<long long>(c->cdc_us),
             static_cast<long long>(c->dio_wait_us),
-            static_cast<long long>(c->readback_us));
+            static_cast<long long>(c->readback_us),
+            static_cast<long long>(c->negotiate_us),
+            static_cast<long long>(c->present_us),
+            static_cast<long long>(c->verify_us),
+            static_cast<long long>(c->recipe_us),
+            static_cast<long long>(c->reindex_us));
   }
   // Spans AFTER the column line: the slow gate's immediate fflush then
   // pushes this request's own access-log record out with the JSON line
@@ -2036,6 +2077,11 @@ void StorageServer::LogAccess(Conn* c, uint8_t status, int64_t bytes) {
   c->binlog_us = 0;
   c->cdc_us = 0;
   c->readback_us = 0;
+  c->negotiate_us = 0;
+  c->present_us = 0;
+  c->verify_us = 0;
+  c->recipe_us = 0;
+  c->reindex_us = 0;
   c->heat_key.clear();
   c->heat_op = 0;
 }
@@ -2102,8 +2148,18 @@ void StorageServer::RecordRequestSpans(Conn* c, uint8_t status,
   uint32_t fp_span = child("storage.fingerprint", stage_wall, c->fp_us);
   child("storage.cdc", stage_wall, c->cdc_us, fp_span);
   child("storage.cs_write", stage_wall + c->fp_us, c->cswrite_us);
-  child("storage.binlog", stage_wall + c->fp_us + c->cswrite_us,
-        c->binlog_us);
+  // The negotiated upload's stages (0 elsewhere): negotiate is an
+  // UPLOAD_RECIPE's whole work; present and verify are the two halves
+  // of an UPLOAD_CHUNKS commit's chunk loop (inside cs_write,
+  // interleaved chunk by chunk, their sums laid out one after the
+  // other), and the re-index follows the recipe write.
+  child("storage.negotiate", stage_wall, c->negotiate_us);
+  child("storage.commit.present", stage_wall, c->present_us);
+  child("storage.commit.verify", stage_wall + c->present_us, c->verify_us);
+  child("storage.reindex", stage_wall + c->fp_us + c->cswrite_us,
+        c->reindex_us);
+  child("storage.binlog",
+        stage_wall + c->fp_us + c->cswrite_us + c->reindex_us, c->binlog_us);
   if (c->ingest_chunks_total > 0) {
     // Negotiated-upload annotation: how much of the recipe actually
     // crossed the wire (missing/total), spanning the request's work
@@ -2752,6 +2808,25 @@ void StorageServer::OnHeaderComplete(Conn* c) {
       c->fixed_need = static_cast<size_t>(kPriorityFrameLen);
       c->state = ConnState::kRecvFixed;
       return;
+    case StorageCmd::kQueryChunking:
+      // How this node cuts: empty body -> six BE int64 slots
+      // (PackChunkingParams).  Conf values only: fine on the nio loop.
+      // ENOTSUP without a chunk store: no recipe can be stored here.
+      if (c->pkg_len != 0) {
+        CloseConn(c);
+        return;
+      }
+      if (dedup_ == nullptr || chunk_stores_.empty()) {
+        // The client uploads plain: a fallback this node can see.
+        if (ctr_ingest_fallbacks_ != nullptr)
+          ctr_ingest_fallbacks_->fetch_add(1, std::memory_order_relaxed);
+        Respond(c, 95 /*ENOTSUP*/);
+      } else {
+        Respond(c, 0,
+                PackChunkingParams(cfg_.cdc_widths, cfg_.dedup_chunk_threshold,
+                                   cfg_.dedup_segment_bytes));
+      }
+      return;
     case StorageCmd::kAdmissionStatus:
       // Admission-controller state dump: empty body -> JSON (ladder
       // level, pressure/EWMA, per-class shed counts;
@@ -3291,19 +3366,25 @@ void StorageServer::SyncCreateComplete(Conn* c) {
 // plugin in upload-sized segments so its near-dup signature and chunk
 // attributions re-enter the engine's indexes — a sidecar-mode rebuild
 // would otherwise leave every recovered file invisible to NEAR_DUPS
-// and un-forgettable on delete.  Best-effort: failures only cost index
-// coverage, never the recovered data.
-void StorageServer::ReindexRecovered(DedupPlugin* plugin,
-                                     const std::string& local,
-                                     const std::string& file_ref) {
+// and un-forgettable on delete.  Best-effort for a recovered file:
+// failures only cost index coverage, never the recovered data.  A
+// negotiated commit passes the client's recipe as `expect`: what the
+// node's own chunker and the plugin's SHA-1 give for the stored bytes,
+// segment by segment, has to be that recipe (same cuts, same digests),
+// else the client cut under other parameters than this node's (or the
+// plugin's digests are wrong) and the caller rolls the commit back.
+StorageServer::Reindexed StorageServer::ReindexRecovered(
+    DedupPlugin* plugin, const std::string& local,
+    const std::string& file_ref, const Recipe* expect) {
   int64_t size = 0;
   int fd = OpenLogical(local, &size);
-  if (fd < 0) return;
-  const int64_t session = plugin->BeginChunked();
+  if (fd < 0) return Reindexed::kUnavailable;
+  const int64_t session = plugin->BeginChunked() | kDedupReindexSessionBit;
   std::string seg;
   int64_t base = 0;
-  bool ok = true;
-  while (ok && base < size) {
+  size_t at = 0;  // next entry of *expect
+  bool ok = true, foreign = false;
+  while (ok && !foreign && base < size) {
     int64_t want = std::min<int64_t>(cfg_.dedup_segment_bytes, size - base);
     seg.resize(static_cast<size_t>(want));
     int64_t got = 0;
@@ -3317,12 +3398,31 @@ void StorageServer::ReindexRecovered(DedupPlugin* plugin,
          plugin->FingerprintChunks(session, seg.data(), seg.size(), base,
                                    &fps);
     base += want;
+    if (!ok || expect == nullptr) continue;
+    for (const ChunkFp& fp : fps) {
+      if (at >= expect->chunks.size() ||
+          expect->chunks[at].length != fp.length ||
+          expect->chunks[at].digest_hex != fp.digest_hex) {
+        FDFS_LOG_WARN("negotiated upload %s: at offset %lld this node cuts "
+                      "%lld bytes %s, the client's recipe entry %zu differs",
+                      file_ref.c_str(), static_cast<long long>(fp.offset),
+                      static_cast<long long>(fp.length),
+                      fp.digest_hex.c_str(), at);
+        foreign = true;
+        break;
+      }
+      ++at;
+    }
   }
   close(fd);
-  if (ok)
+  if (ok && !foreign && expect != nullptr && at != expect->chunks.size())
+    foreign = true;
+  if (ok && !foreign) {
     plugin->CommitChunked(session, file_ref);
-  else
-    plugin->AbortChunked(session);
+    return Reindexed::kIndexed;
+  }
+  plugin->AbortChunked(session);
+  return foreign ? Reindexed::kForeignCuts : Reindexed::kUnavailable;
 }
 
 // FETCH_RECIPE (128): serve a recipe-stored file's chunk list to a
@@ -3575,6 +3675,7 @@ void StorageServer::HandleSyncQueryChunks(Conn* c) {
 // falls back to a plain UPLOAD_FILE (an older daemon without this
 // opcode answers EINVAL, same client reaction).
 void StorageServer::HandleUploadRecipe(Conn* c) {
+  const int64_t t_in = MonoUs();
   if (dedup_ == nullptr || chunk_stores_.empty()) {
     if (ctr_ingest_fallbacks_ != nullptr)
       ctr_ingest_fallbacks_->fetch_add(1, std::memory_order_relaxed);
@@ -3665,6 +3766,7 @@ void StorageServer::HandleUploadRecipe(Conn* c) {
     std::lock_guard<RankedMutex> lk(ingest_mu_);
     ingest_sessions_[s->id] = std::move(s);
   }
+  c->negotiate_us = MonoUs() - t_in;
   Respond(c, 0, body);
 }
 
@@ -3823,6 +3925,7 @@ void StorageServer::UploadChunksComplete(Conn* c) {
   uint32_t crc = 0;
   bool ok = true;
   std::string payload;
+  int64_t t_chunk = t0;  // the loop's time, put down chunk by chunk
   for (size_t i = 0; ok && i < s->recipe.chunks.size(); ++i) {
     const RecipeEntry& e = s->recipe.chunks[i];
     if (s->needed[i] != 0) {
@@ -3876,6 +3979,9 @@ void StorageServer::UploadChunksComplete(Conn* c) {
       ++hits;
     }
     crc = Crc32(payload.data(), static_cast<size_t>(e.length), crc);
+    const int64_t now = MonoUs();
+    (s->needed[i] != 0 ? c->verify_us : c->present_us) += now - t_chunk;
+    t_chunk = now;
   }
   close(tmp_fd);
   unlink(c->tmp_path.c_str());
@@ -3905,6 +4011,29 @@ void StorageServer::UploadChunksComplete(Conn* c) {
     return;
   }
   c->cswrite_us = MonoUs() - t0;
+  c->recipe_us = t0 + c->cswrite_us - t_chunk;
+  // Sidecar mode keeps its near-dup/attribution index OUTSIDE the chunk
+  // store, and the client-side fingerprint pipeline never talked to it:
+  // feed the assembled bytes through the plugin exactly as a recovered
+  // file is (the cpu plugin indexes in the chunk store itself, so
+  // re-fingerprinting there would be pure waste).  The same pass holds
+  // the client's recipe to this node's own cut of the content: a recipe
+  // cut under other parameters is rolled back before the binlog hears
+  // of it, and the client uploads plain.  A sidecar that cannot be
+  // reached fails open as everywhere (no signature, a WARN line).
+  if (dedup_ != nullptr && std::string(dedup_->Name()) == "sidecar") {
+    const int64_t t_ri = MonoUs();
+    const Reindexed got = ReindexRecovered(
+        dedup_.get(), *local, cfg_.group_name + "/" + parts->RemoteFilename(),
+        &done);
+    c->reindex_us = MonoUs() - t_ri;
+    if (got == Reindexed::kForeignCuts) {
+      s->cs->RemoveRecipe(*local + ".rcp", nullptr);
+      s->cs->UnrefAll(done);
+      fail(22);
+      return;
+    }
+  }
   stats_.dedup_hits += hits;
   stats_.dedup_bytes_saved += saved;
   if (ctr_dedup_chunk_hits_ != nullptr && hits > 0)
@@ -3917,19 +4046,14 @@ void StorageServer::UploadChunksComplete(Conn* c) {
     ctr_ingest_recipe_uploads_->fetch_add(1, std::memory_order_relaxed);
     ctr_ingest_bytes_saved_wire_->fetch_add(saved,
                                             std::memory_order_relaxed);
+    ctr_ingest_chunks_present_->fetch_add(hits, std::memory_order_relaxed);
+    ctr_ingest_chunks_shipped_->fetch_add(missing,
+                                          std::memory_order_relaxed);
   }
   int64_t t_bl = MonoUs();
   binlog_.Append(kBinlogOpCreate, parts->RemoteFilename());
   c->binlog_us = MonoUs() - t_bl;
   NoteTracedMutation(c, parts->RemoteFilename());
-  // Sidecar mode keeps its near-dup/attribution index OUTSIDE the chunk
-  // store, and the client-side fingerprint pipeline never talked to it:
-  // feed the assembled bytes through the plugin exactly as a recovered
-  // file is (best-effort; the cpu plugin indexes in the chunk store
-  // itself, so re-fingerprinting there would be pure waste).
-  if (dedup_ != nullptr && std::string(dedup_->Name()) == "sidecar")
-    ReindexRecovered(dedup_.get(), *local,
-                     cfg_.group_name + "/" + parts->RemoteFilename());
   stats_.success_upload++;
   stats_.last_source_update = time(nullptr);
   NoteHeat(c, HeatOp::kUpload, cfg_.group_name + "/" + parts->RemoteFilename());

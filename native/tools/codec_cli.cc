@@ -409,6 +409,12 @@ int main(int argc, char** argv) {
     PutInt64BE(static_cast<int64_t>(lens[0] + lens[2]), num);
     pre.append(reinterpret_cast<char*>(num), 8);
     printf("chunks_prefix=%s\n", hex(pre).c_str());
+    // QUERY_CHUNKING response of a node at 512K:20:8M, threshold 64 KiB,
+    // 64 MiB segments.
+    printf("chunking=%s\n",
+           hex(PackChunkingParams(CdcWidths{512 << 10, 20, 8 << 20}, 65536,
+                                  64LL << 20))
+               .c_str());
     return 0;
   }
   if (cmd == "placement-wire") {

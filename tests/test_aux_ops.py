@@ -41,15 +41,18 @@ def test_access_log_lines(tmp_path_factory):
     assert len(lines) >= 2  # upload + download
     # "<ts> <ip> <cmd> <status> <bytes> <cost_us> <recv_us> <work_us>
     #  <fp_us> <fp_lock_us> <cswrite_us> <binlog_us> <req_bytes>
-    #  <cdc_us> <dio_wait_us> <readback_us>" —
+    #  <cdc_us> <dio_wait_us> <readback_us> <negotiate_us> <present_us>
+    #  <verify_us> <recipe_us> <reindex_us>" —
     # per-stage split (SURVEY.md §5): recv = body window, work = dio,
     # then the chunked-upload splits inside the work window; the last
     # three were appended after req_bytes (native chunker inside fp,
-    # dio queue wait and tmp-file read-back inside work).
+    # dio queue wait and tmp-file read-back inside work), the last five
+    # after those (the negotiated upload's stages: 0 on these requests).
     for line in lines:
         (ts, ip, cmd, status, nbytes, cost, recv_us, work_us,
          fp_us, fp_lock_us, cswrite_us, binlog_us, req_bytes,
-         cdc_us, dio_wait_us, readback_us) = line.split()
+         cdc_us, dio_wait_us, readback_us, *negotiated) = line.split()
+        assert negotiated == ["0"] * 5
         assert int(ts) > 0 and ip == "127.0.0.1"
         assert int(status) == 0 and int(cost) >= 0
         assert int(recv_us) >= 0 and int(work_us) >= 0

@@ -10,6 +10,7 @@ acquire/release/sweep with fake connections and injected clocks — no
 sockets, no sleeps beyond the bounded cap wait.
 """
 
+import dataclasses
 import threading
 import time
 
@@ -17,6 +18,7 @@ import pytest
 
 from fastdfs_tpu.client.client import FdfsClient
 from fastdfs_tpu.client.conn import ConnectionPool, StatusError
+from fastdfs_tpu.client.fingerprint import SHIPPED_PARAMS
 from fastdfs_tpu.client.tracker_client import StoreTarget
 
 
@@ -51,11 +53,32 @@ def test_dedup_small_payload_counts_plain_fallback(monkeypatch):
     assert c.stats()["dedup_fallback_plain"] == 1
 
 
+class _NodeThatSaysHowItCuts:
+    """A storage connection that answers QUERY_CHUNKING and nothing else."""
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+    def query_chunking(self):
+        return dataclasses.replace(SHIPPED_PARAMS, chunk_threshold=1024)
+
+
+def _route_to_fake_node(monkeypatch, c, storage=_NodeThatSaysHowItCuts):
+    tgt = StoreTarget(group="g1", ip="127.0.0.1", port=2,
+                      store_path_index=0)
+    monkeypatch.setattr(c, "_with_tracker", lambda fn: tgt)
+    monkeypatch.setattr(c, "_storage", lambda tgt: storage())
+
+
 def test_dedup_low_estimate_counts_plain_fallback(monkeypatch):
     c = _client(dedup_uploads=True, dedup_min_bytes=8, dedup_min_ratio=0.5)
     monkeypatch.setattr(
         c, "_upload_buffer_plain",
         lambda data, ext="", group=None, appender=False, key=None: "g/p")
+    _route_to_fake_node(monkeypatch, c)
     # A cold digest cache means the estimated dup ratio is 0 < 0.5.
     stats: dict = {}
     assert c.upload_buffer_dedup(b"x" * 4096, stats=stats) == "g/p"
@@ -68,49 +91,41 @@ def test_dedup_storage_level_fallback_counts(monkeypatch):
     # the opcodes / chunk store); it reports through the stats dict and
     # must land in the SAME counter.
     c = _client(dedup_uploads=True, dedup_min_bytes=8, dedup_min_ratio=0)
-    tgt = StoreTarget(group="g1", ip="127.0.0.1", port=2,
-                      store_path_index=0)
-    monkeypatch.setattr(c, "_with_tracker", lambda fn: tgt)
 
-    class FakeStorage:
-        def __enter__(self):
-            return self
-
-        def __exit__(self, *exc):
-            return False
-
+    class FakeStorage(_NodeThatSaysHowItCuts):
         def upload_buffer_dedup(self, data, ext="", store_path_index=0,
                                 chunks=None, stats=None):
             stats.update(fallback="status95", bytes_sent=len(data))
             return "g1/plain"
 
-    monkeypatch.setattr(c, "_storage", lambda tgt: FakeStorage())
+    _route_to_fake_node(monkeypatch, c, FakeStorage)
     stats: dict = {}
     assert c.upload_buffer_dedup(b"x" * 4096, stats=stats) == "g1/plain"
     assert c.stats()["dedup_fallback_plain"] == 1
+    # a node that refused an upload cut with its parameters is asked again
+    assert c._chunking == {}
 
 
 def test_dedup_negotiated_success_counts_nothing(monkeypatch):
     c = _client(dedup_uploads=True, dedup_min_bytes=8, dedup_min_ratio=0)
-    tgt = StoreTarget(group="g1", ip="127.0.0.1", port=2,
-                      store_path_index=0)
-    monkeypatch.setattr(c, "_with_tracker", lambda fn: tgt)
 
-    class FakeStorage:
-        def __enter__(self):
-            return self
-
-        def __exit__(self, *exc):
-            return False
-
+    class FakeStorage(_NodeThatSaysHowItCuts):
         def upload_buffer_dedup(self, data, ext="", store_path_index=0,
                                 chunks=None, stats=None):
             stats.update(fallback="", bytes_sent=0)
             return "g1/dedup"
 
-    monkeypatch.setattr(c, "_storage", lambda tgt: FakeStorage())
+    _route_to_fake_node(monkeypatch, c, FakeStorage)
     assert c.upload_buffer_dedup(b"x" * 4096) == "g1/dedup"
     assert c.stats()["dedup_fallback_plain"] == 0
+    assert list(c._chunking) == [("127.0.0.1", 2)]    # asked once, kept
+    # under the node's own threshold there is no recipe to negotiate over
+    stats: dict = {}
+    monkeypatch.setattr(
+        c, "_upload_buffer_plain",
+        lambda data, ext="", group=None, appender=False, key=None: "g/p")
+    assert c.upload_buffer_dedup(b"x" * 512, stats=stats) == "g/p"
+    assert stats["fallback"] == "small"
 
 
 def test_placement_route_failure_counts_tracker_fallback(monkeypatch):

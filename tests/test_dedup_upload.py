@@ -30,7 +30,8 @@ import pytest
 
 from fastdfs_tpu.client import FdfsClient, StorageClient, TrackerClient
 from fastdfs_tpu.client.conn import Connection, ProtocolError, StatusError
-from fastdfs_tpu.client.fingerprint import fingerprint_buffer
+from fastdfs_tpu.client.fingerprint import (SHIPPED_PARAMS,
+                                            fingerprint_buffer)
 from fastdfs_tpu.client.storage_client import (
     pack_upload_chunks_prefix,
     pack_upload_recipe,
@@ -39,6 +40,7 @@ from fastdfs_tpu.client.storage_client import (
 from fastdfs_tpu.common.protocol import (
     HEADER_SIZE,
     StorageCmd,
+    pack_chunking,
     pack_header,
     unpack_header,
 )
@@ -86,7 +88,7 @@ def test_numpy_cdc_matches_serial_reference():
 def test_fingerprint_buffer_covers_stream_with_true_digests():
     rng = np.random.default_rng(12)
     data = rng.integers(0, 256, size=200_000, dtype=np.uint8).tobytes()
-    fps = fingerprint_buffer(data)
+    fps = fingerprint_buffer(data, SHIPPED_PARAMS)
     assert sum(fp.length for fp in fps) == len(data)
     cuts = gear_cdc.chunk_stream_ref(data)
     assert [fp.length for fp in fps] == [
@@ -95,7 +97,7 @@ def test_fingerprint_buffer_covers_stream_with_true_digests():
     for fp in fps:
         assert fp.digest == hashlib.sha1(data[start:start + fp.length]).digest()
         start += fp.length
-    assert fingerprint_buffer(b"") == []
+    assert fingerprint_buffer(b"", SHIPPED_PARAMS) == []
 
 
 # ---------------------------------------------------------------------------
@@ -205,6 +207,10 @@ def test_ingest_wire_golden():
     assert bitmap == b"\x01\x00\x01"
     assert got["chunks_prefix"] == pack_upload_chunks_prefix(
         0x0102030405060708, 4000).hex()
+    # QUERY_CHUNKING's answer: how a node at 512K:20:8M cuts
+    assert got["chunking"] == pack_chunking(dict(
+        min_size=512 << 10, avg_bits=20, max_size=8 << 20, cdc_policy=1,
+        chunk_threshold=65536, segment_bytes=64 << 20)).hex()
 
 
 # ---------------------------------------------------------------------------
@@ -333,7 +339,8 @@ def test_negotiated_upload_falls_back_without_chunk_store(tmp_path_factory):
         stats = {}
         fid = cli.upload_buffer_dedup(payload, ext="bin", min_dup_ratio=0,
                                       stats=stats)
-        assert stats["fallback"] == "status95"
+        # the node does not say how it cuts: nothing to cut with
+        assert stats["fallback"] == "no_chunking_params"
         assert cli.download_to_buffer(fid) == payload
         # the opt-in flag routes upload_buffer through the same path
         fid2 = cli.upload_buffer(payload, ext="bin")
@@ -402,7 +409,7 @@ def test_upload_session_timeout_releases_pins(tmp_path_factory):
 
         # Phase 1 on a raw socket, then "vanish" (no phase 2).
         chunks = [(fp.length, fp.digest)
-                  for fp in fingerprint_buffer(payload)]
+                  for fp in fingerprint_buffer(payload, SHIPPED_PARAMS)]
         body = pack_upload_recipe(0xFF, "bin", zlib.crc32(payload),
                                   len(payload), chunks)
         sock = socket.create_connection(("127.0.0.1", storage.port),

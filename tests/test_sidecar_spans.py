@@ -22,10 +22,11 @@ import pytest
 
 from fastdfs_tpu.common.protocol import StorageCmd
 from fastdfs_tpu.dedup import spans as spans_mod
-from fastdfs_tpu.sidecar import read_stats, rpc
+from fastdfs_tpu.sidecar import REINDEX_SESSION_BIT, read_stats, rpc
 from harness import REPO, Sidecar
 
 FP, FP_CUTS = StorageCmd.DEDUP_FINGERPRINT, StorageCmd.DEDUP_FINGERPRINT_CUTS
+REINDEX_1002 = 1002 | REINDEX_SESSION_BIT   # a session the daemon re-indexes
 
 # The table of OPERATIONS.md, "Tracing".
 ENGINE_STAGES = {"fdfs.engine.slot_wait", "fdfs.engine.pack",
@@ -71,7 +72,7 @@ def traced(tmp_path_factory):
         ends = [2048 * (i + 1) for i in range(600)]
         bodies = {
             FP: struct.pack(">qq", 1001, 4096) + plain,
-            FP_CUTS: struct.pack(">qqq", 1002, 0, len(ends))
+            FP_CUTS: struct.pack(">qqq", REINDEX_1002, 0, len(ends))
             + struct.pack(f">{len(ends)}q", *ends) + cut}
         payload = {FP: plain, FP_CUTS: cut}
         chunk = b"scrubbed chunk"
@@ -140,7 +141,9 @@ def test_every_span_of_the_table_lies_inside_its_request(traced, cmd):
     assert names == want
     # ... and nowhere else: no child of this request escaped its root
     session = args["session"]
-    assert session == {FP: 1001, FP_CUTS: 1002}[cmd]
+    assert session == {FP: 1001, FP_CUTS: REINDEX_1002}[cmd]
+    # bytes the daemon already stores, sent for their signature, say so
+    assert args["reindex"] == {FP: 0, FP_CUTS: 1}[cmd]
     assert args["base_offset"] == {FP: 4096, FP_CUTS: 0}[cmd]
     assert args["bytes"] == len(traced["payload"][cmd]) + {
         FP: 16, FP_CUTS: 24 + 8 * 600}[cmd]
@@ -259,7 +262,8 @@ def test_helper_costs_a_flag_test_when_no_trace_runs():
 
 def test_cli_sidecar_trace_prints_the_deltas(traced):
     out_dir = os.path.join(traced["base"], "cli_trace")
-    body = struct.pack(">qq", 1003, 0) + traced["payload"][FP][:70_000]
+    body = (struct.pack(">qq", 1003 | REINDEX_SESSION_BIT, 0)
+            + traced["payload"][FP][:70_000])
     done = threading.Event()
 
     def keep_sending():     # so that some request falls between its reads
@@ -287,6 +291,10 @@ def test_cli_sidecar_trace_prints_the_deltas(traced):
     assert float(rows["fdfs.engine.fingerprint"][2]) > 0
     assert "MB fingerprinted" in proc.stdout
     assert "device memory peak" in proc.stdout
+    # ... all of them a re-index here, told apart from uploads
+    (reidx,) = [ln for ln in proc.stdout.splitlines()
+                if ln.startswith("of them re-index:")]
+    assert int(reidx.split()[6]) >= 1 and float(reidx.split()[3]) > 0
     # the receive counters beside the spans: a 70 KB body is one call
     (recv,) = [ln for ln in proc.stdout.splitlines()
                if ln.startswith("fingerprint bodies:")]

@@ -7,9 +7,16 @@ The daemon (storage.conf:use_access_log) writes one line per request to
     <epoch> <ip> <cmd> <status> <bytes> <cost_us> <recv_us> <work_us>
     <fp_us> <fp_lock_us> <cswrite_us> <binlog_us> <req_bytes>
     <cdc_us> <dio_wait_us> <readback_us>
+    <negotiate_us> <present_us> <verify_us> <recipe_us> <reindex_us>
 
-(native/storage/server.cc:LogAccess; older 8- and 13-column logs parse
-too, with zeros for the stages they lack).  ``cdc_us`` is the native
+(native/storage/server.cc:LogAccess; older 8-, 13- and 16-column logs
+parse too, with zeros for the stages they lack).  The last five are the
+negotiated upload's: ``negotiate_us`` is an ``upload_recipe`` request's
+parse + pin-and-mask; inside an ``upload_chunks`` commit ``present_us``
+(chunks the store had: reference, read back, CRC) and ``verify_us``
+(shipped chunks: digest check, write) lie inside ``cswrite_us``, then
+``recipe_us`` and ``reindex_us`` (the stored file read back and
+fingerprinted for its signature, before the reply).  ``cdc_us`` is the native
 chunker's share of ``fp_us``; ``dio_wait_us`` (the wait in the dio queue)
 and ``readback_us`` (the tmp file read back before each fingerprint call)
 lie inside ``work_us``.  This tool answers the question the raw ingest rate
@@ -44,14 +51,18 @@ CMD_NAMES = {
     21: "upload_slave", 22: "query_info", 23: "upload_appender",
     24: "append", 26: "fetch_binlog", 34: "modify", 36: "truncate",
     124: "near_dups", 126: "sync_query_chunks", 127: "sync_recipe",
-    128: "fetch_recipe", 129: "fetch_chunk",
+    128: "fetch_recipe", 129: "fetch_chunk", 132: "upload_recipe",
+    133: "upload_chunks", 149: "query_chunking",
 }
 
 STAGES = ["recv_us", "work_us", "fp_us", "fp_lock_us", "cswrite_us",
           "binlog_us"]
 # appended after req_bytes, so they follow it in a line
 LATE_STAGES = ["cdc_us", "dio_wait_us", "readback_us"]
-ALL_STAGES = STAGES + LATE_STAGES
+# the negotiated upload's own stages, after those
+INGEST_STAGES = ["negotiate_us", "present_us", "verify_us", "recipe_us",
+                 "reindex_us"]
+ALL_STAGES = STAGES + LATE_STAGES + INGEST_STAGES
 
 
 def _pct(sorted_vals: list[int], q: float) -> int:
@@ -90,12 +101,12 @@ def aggregate(path: str) -> dict:
                 continue
             try:
                 cmd, status = int(f[2]), int(f[3])
-                nums = [int(x) for x in f[4:16]]
+                nums = [int(x) for x in f[4:21]]
             except ValueError:
                 continue
-            nums += [0] * (12 - len(nums))  # older column counts
+            nums += [0] * (17 - len(nums))  # older column counts
             bytes_, cost = nums[0], nums[1]
-            stages = nums[2:8] + nums[9:12]
+            stages = nums[2:8] + nums[9:17]
             req_bytes = nums[8]
             d = per_cmd.setdefault(cmd, {
                 "count": 0, "errors": 0, "bytes": 0, "req_bytes": 0,
@@ -127,7 +138,8 @@ def aggregate(path: str) -> dict:
         }
         if total_cost > 0:
             # fp_lock and cdc are subsets of fp; work contains dio_wait +
-            # readback + fp + cswrite + binlog.
+            # readback + fp + cswrite + binlog + negotiate + reindex;
+            # cswrite contains present + verify + recipe.
             # Report the orthogonal decomposition of cost_us.
             recv = d["recv_us"]
             fp = d["fp_us"]
@@ -137,12 +149,20 @@ def aggregate(path: str) -> dict:
             cdc = d["cdc_us"]
             wait = d["dio_wait_us"]
             rb = d["readback_us"]
-            other_work = max(d["work_us"] - fp - cs - bl - wait - rb, 0)
+            neg, ri = d["negotiate_us"], d["reindex_us"]
+            present, verify = d["present_us"], d["verify_us"]
+            other_work = max(
+                d["work_us"] - fp - cs - bl - wait - rb - neg - ri, 0)
             pre = max(total_cost - d["recv_us"] - d["work_us"], 0)
             for name, v in [("recv", recv), ("dio_wait", wait),
                             ("tmp_readback", rb), ("fp_cdc", cdc),
                             ("fp_rpc", fp - lock - cdc),
-                            ("fp_lock_wait", lock), ("cs_write", cs),
+                            ("fp_lock_wait", lock),
+                            ("negotiate", neg),
+                            ("commit_present", present),
+                            ("commit_verify", verify),
+                            ("cs_write", cs - present - verify),
+                            ("reindex", ri),
                             ("binlog", bl), ("work_other", other_work),
                             ("dispatch_other", pre)]:
                 row["stage_share"][name] = round(v / total_cost, 4)
