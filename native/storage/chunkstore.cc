@@ -337,12 +337,16 @@ std::string ChunkStore::HaveMask(
   return need;
 }
 
-bool ChunkStore::RefOne(const std::string& digest_hex) {
+bool ChunkStore::RefOne(const std::string& digest_hex, int64_t* stored_len) {
   Stripe& st = StripeFor(digest_hex);
   std::lock_guard<RankedMutex> lk(st.mu);
   auto it = st.refs.find(digest_hex);
   if (it == st.refs.end()) return false;
   it->second++;
+  if (stored_len != nullptr) {
+    auto l = st.lens.find(digest_hex);
+    *stored_len = l != st.lens.end() ? l->second : -1;
+  }
   return true;
 }
 

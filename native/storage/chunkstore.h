@@ -182,8 +182,12 @@ class ChunkStore {
 
   // Take one reference on an already-live chunk; false when absent
   // (the replication receiver then reports the race and the sender
-  // falls back to a full copy).
-  bool RefOne(const std::string& digest_hex);
+  // falls back to a full copy).  *stored_len (optional) gets the length
+  // the store holds under the digest, read in the same acquisition (-1
+  // when it knows none): a caller that reads the chunk through
+  // ReadChunkSlices, which checks bounds only, holds its own length
+  // against it first.
+  bool RefOne(const std::string& digest_hex, int64_t* stored_len = nullptr);
 
   // Read one chunk fully into *out (resized).  False when missing/short.
   bool ReadChunk(const std::string& digest_hex, int64_t expect_len,

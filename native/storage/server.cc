@@ -59,16 +59,15 @@ struct SegmentBuf {
 };
 thread_local SegmentBuf t_seg;
 
-// Bytes [off, off + len) of an upload's tmp file, for the chunker, the
-// fingerprint call and the chunk store to read: in a buffer the calling
-// thread keeps from one segment to the next, or nullptr when they cannot
-// be read.  The buffer grows to the largest segment the thread has seen
-// (at most dedup_segment_bytes) and is never zero-filled, so its pages
-// are faulted in once a thread and not once an upload: a fresh 64 MB
-// std::string a segment cost 2 ms per MB of upload.  Mapping the tmp
-// file instead read lower here and 26 ms per MB dearer in the send to the
-// sidecar (PERF.md section 6, PR 34).
-const char* ReadTmpSegment(int fd, int64_t off, int64_t len) {
+// Room for one segment of `len` bytes, for the chunker, the fingerprint
+// call and the chunk store to read: the buffer the calling thread keeps
+// from one segment to the next.  It grows to the largest segment the
+// thread has seen (at most dedup_segment_bytes) and is never zero-filled,
+// so its pages are faulted in once a thread and not once an upload: a
+// fresh 64 MB std::string a segment cost 2 ms per MB of upload.  Mapping
+// the tmp file instead read lower here and 26 ms per MB dearer in the
+// send to the sidecar (PERF.md section 6, PR 34).
+char* KeptSegment(int64_t len) {
   SegmentBuf& s = t_seg;
   if (s.cap < len) {
     s.cap = 0;  // a throwing new leaves no capacity behind a null buffer
@@ -78,15 +77,27 @@ const char* ReadTmpSegment(int fd, int64_t off, int64_t len) {
     s.cap = len;
   }
   s.written = std::max(s.written, len);
+  return s.buf.get();
+}
+
+// Bytes [off, off + len) of a file, all of them or false.
+bool PreadFull(int fd, char* dst, int64_t len, int64_t off) {
   int64_t got = 0;
   while (got < len) {
-    ssize_t r = pread(fd, s.buf.get() + got, static_cast<size_t>(len - got),
+    ssize_t r = pread(fd, dst + got, static_cast<size_t>(len - got),
                       off + got);
     if (r < 0 && errno == EINTR) continue;
-    if (r <= 0) return nullptr;
+    if (r <= 0) return false;
     got += r;
   }
-  return s.buf.get();
+  return true;
+}
+
+// Bytes [off, off + len) of an upload's tmp file in the kept buffer, or
+// nullptr when they cannot be read.
+const char* ReadTmpSegment(int fd, int64_t off, int64_t len) {
+  char* seg = KeptSegment(len);
+  return PreadFull(fd, seg, len, off) ? seg : nullptr;
 }
 
 // After an upload's last segment: the buffer's bytes are dead, and the
@@ -1129,6 +1140,14 @@ void StorageServer::InitStatsRegistry() {
   // from (the store / the wire), and the commit's stages per request.
   ctr_ingest_chunks_present_ = registry_.Counter("ingest.chunks_present");
   ctr_ingest_chunks_shipped_ = registry_.Counter("ingest.chunks_shipped");
+  // How a commit read the chunks the store had: the preadv calls
+  // ReadChunkSlices made for commits and the chunks those served (chunks
+  // a batch is the reading; chunks that are not slab-resident take its
+  // per-chunk fall-through and count in neither).
+  ctr_ingest_commit_read_batches_ =
+      registry_.Counter("ingest.commit_read_batches");
+  ctr_ingest_commit_read_chunks_ =
+      registry_.Counter("ingest.commit_read_chunks");
   hist_ingest_negotiate_ = registry_.Histogram(
       "ingest.negotiate_us", StatsRegistry::LatencyBucketsUs());
   hist_ingest_present_ = registry_.Histogram(
@@ -1837,6 +1856,8 @@ void StorageServer::ResetForNextRequest(Conn* c) {
   c->verify_us = 0;
   c->recipe_us = 0;
   c->reindex_us = 0;
+  c->commit_read_batches = 0;
+  c->commit_read_chunks = 0;
   c->ingest_session = 0;
   c->ingest_chunks_total = 0;
   c->ingest_chunks_missing = 0;
@@ -2027,11 +2048,14 @@ void StorageServer::LogAccess(Conn* c, uint8_t status, int64_t bytes) {
     // dio queue at the head of the work window, readback = the tmp file
     // read back segment by segment before each fingerprint call (inside
     // work, outside fp); and last the negotiated upload's own stages:
-    // negotiate (UPLOAD_RECIPE: parse + PinAndMask), then inside an
-    // UPLOAD_CHUNKS commit present (RefOne + ReadChunk + CRC of the chunks
-    // the store had), verify (digest check + PutAndRef of the shipped
-    // ones; both inside cswrite), recipe (id + recipe write) and reindex
-    // (the stored file read back and fingerprinted for its signature).
+    // negotiate (UPLOAD_RECIPE: parse + PinAndMask), then of an
+    // UPLOAD_CHUNKS commit, summed over its segments: present (RefOne of
+    // the chunks the store had, their one batched read into the segment
+    // buffer, the CRC over the assembled segment), verify (digest check +
+    // PutAndRef of the shipped ones), recipe (id + recipe write; cswrite
+    // is these three) and reindex (the assembled segment cut and
+    // fingerprinted for the file's signature, the answer held against the
+    // client's recipe, the fingerprint session committed).
     // Columns are 0 when a stage did not occur;
     // tools/access_log_stages.py aggregates them into the bench stage
     // table.
@@ -2082,6 +2106,8 @@ void StorageServer::LogAccess(Conn* c, uint8_t status, int64_t bytes) {
   c->verify_us = 0;
   c->recipe_us = 0;
   c->reindex_us = 0;
+  c->commit_read_batches = 0;
+  c->commit_read_chunks = 0;
   c->heat_key.clear();
   c->heat_op = 0;
 }
@@ -2149,13 +2175,22 @@ void StorageServer::RecordRequestSpans(Conn* c, uint8_t status,
   child("storage.cdc", stage_wall, c->cdc_us, fp_span);
   child("storage.cs_write", stage_wall + c->fp_us, c->cswrite_us);
   // The negotiated upload's stages (0 elsewhere): negotiate is an
-  // UPLOAD_RECIPE's whole work; present and verify are the two halves
-  // of an UPLOAD_CHUNKS commit's chunk loop (inside cs_write,
-  // interleaved chunk by chunk, their sums laid out one after the
-  // other), and the re-index follows the recipe write.
+  // UPLOAD_RECIPE's whole work; verify, present and the re-index are what
+  // an UPLOAD_CHUNKS commit does with each segment in turn (their sums
+  // laid out one after the other, verify and present inside cs_write).
+  // How the present chunks were read hangs under present as an
+  // annotation: chunks served by a preadv / preadv calls.
   child("storage.negotiate", stage_wall, c->negotiate_us);
-  child("storage.commit.present", stage_wall, c->present_us);
-  child("storage.commit.verify", stage_wall + c->present_us, c->verify_us);
+  child("storage.commit.verify", stage_wall, c->verify_us);
+  uint32_t present_span = child("storage.commit.present",
+                                stage_wall + c->verify_us, c->present_us);
+  if (present_span != 0 && c->commit_read_batches > 0) {
+    char ann[sizeof(TraceSpan{}.name)];
+    if (std::snprintf(ann, sizeof(ann), "ingest.commit_reads %lld/%lld",
+                      static_cast<long long>(c->commit_read_chunks),
+                      static_cast<long long>(c->commit_read_batches)) > 0)
+      child(ann, stage_wall + c->verify_us, c->present_us, present_span);
+  }
   child("storage.reindex", stage_wall + c->fp_us + c->cswrite_us,
         c->reindex_us);
   child("storage.binlog",
@@ -3366,25 +3401,19 @@ void StorageServer::SyncCreateComplete(Conn* c) {
 // plugin in upload-sized segments so its near-dup signature and chunk
 // attributions re-enter the engine's indexes — a sidecar-mode rebuild
 // would otherwise leave every recovered file invisible to NEAR_DUPS
-// and un-forgettable on delete.  Best-effort for a recovered file:
-// failures only cost index coverage, never the recovered data.  A
-// negotiated commit passes the client's recipe as `expect`: what the
-// node's own chunker and the plugin's SHA-1 give for the stored bytes,
-// segment by segment, has to be that recipe (same cuts, same digests),
-// else the client cut under other parameters than this node's (or the
-// plugin's digests are wrong) and the caller rolls the commit back.
-StorageServer::Reindexed StorageServer::ReindexRecovered(
-    DedupPlugin* plugin, const std::string& local,
-    const std::string& file_ref, const Recipe* expect) {
+// and un-forgettable on delete.  Best-effort: failures only cost index
+// coverage, never the recovered data.
+void StorageServer::ReindexRecovered(DedupPlugin* plugin,
+                                     const std::string& local,
+                                     const std::string& file_ref) {
   int64_t size = 0;
   int fd = OpenLogical(local, &size);
-  if (fd < 0) return Reindexed::kUnavailable;
+  if (fd < 0) return;
   const int64_t session = plugin->BeginChunked() | kDedupReindexSessionBit;
   std::string seg;
   int64_t base = 0;
-  size_t at = 0;  // next entry of *expect
-  bool ok = true, foreign = false;
-  while (ok && !foreign && base < size) {
+  bool ok = true;
+  while (ok && base < size) {
     int64_t want = std::min<int64_t>(cfg_.dedup_segment_bytes, size - base);
     seg.resize(static_cast<size_t>(want));
     int64_t got = 0;
@@ -3398,31 +3427,12 @@ StorageServer::Reindexed StorageServer::ReindexRecovered(
          plugin->FingerprintChunks(session, seg.data(), seg.size(), base,
                                    &fps);
     base += want;
-    if (!ok || expect == nullptr) continue;
-    for (const ChunkFp& fp : fps) {
-      if (at >= expect->chunks.size() ||
-          expect->chunks[at].length != fp.length ||
-          expect->chunks[at].digest_hex != fp.digest_hex) {
-        FDFS_LOG_WARN("negotiated upload %s: at offset %lld this node cuts "
-                      "%lld bytes %s, the client's recipe entry %zu differs",
-                      file_ref.c_str(), static_cast<long long>(fp.offset),
-                      static_cast<long long>(fp.length),
-                      fp.digest_hex.c_str(), at);
-        foreign = true;
-        break;
-      }
-      ++at;
-    }
   }
   close(fd);
-  if (ok && !foreign && expect != nullptr && at != expect->chunks.size())
-    foreign = true;
-  if (ok && !foreign) {
+  if (ok)
     plugin->CommitChunked(session, file_ref);
-    return Reindexed::kIndexed;
-  }
-  plugin->AbortChunked(session);
-  return foreign ? Reindexed::kForeignCuts : Reindexed::kUnavailable;
+  else
+    plugin->AbortChunked(session);
 }
 
 // FETCH_RECIPE (128): serve a recipe-stored file's chunk list to a
@@ -3875,11 +3885,43 @@ bool StorageServer::BeginUploadChunks(Conn* c) {
   return true;
 }
 
-// UPLOAD_CHUNKS completion (dio worker): verify each shipped chunk IS
-// its claimed digest, write it via PutAndRef, reference the present
-// ones, store the recipe, and mint/answer the file ID exactly like
-// UPLOAD_FILE.  All-or-nothing with ref rollback; any failure makes
-// the client fall back to a plain upload.
+// Whether the node's own cut of one segment (its chunker's ends, the
+// plugin's SHA-1s: `fps`) is entries [first, first + n) of the client's
+// recipe, entry for entry.  If not, the client cut under other parameters
+// than this node's (or the plugin's digests are wrong) and the caller
+// rolls the commit back; the WARN names the first entry that differs.
+static bool NodeCutsEqualRecipe(const std::vector<ChunkFp>& fps,
+                                const Recipe& recipe, size_t first, size_t n,
+                                int64_t session) {
+  for (size_t k = 0; k < fps.size(); ++k) {
+    if (k < n && recipe.chunks[first + k].length == fps[k].length &&
+        recipe.chunks[first + k].digest_hex == fps[k].digest_hex)
+      continue;
+    FDFS_LOG_WARN("negotiated upload session %lld: at offset %lld this node "
+                  "cuts %lld bytes %s, the client's recipe entry %zu differs",
+                  static_cast<long long>(session),
+                  static_cast<long long>(fps[k].offset),
+                  static_cast<long long>(fps[k].length),
+                  fps[k].digest_hex.c_str(), first + k);
+    return false;
+  }
+  return fps.size() == n;
+}
+
+// UPLOAD_CHUNKS completion (dio worker): assemble the logical stream
+// once, segment by segment as this node would have cut it, in the buffer
+// the thread keeps, and let everything that needs the bytes read them
+// there.  Of each dedup_segment_bytes segment of the recipe: the shipped
+// chunks are read from the upload's tmp file into their place, each
+// verified to BE its claimed digest and written via PutAndRef; the
+// present ones are referenced and then read from the store into theirs
+// by ONE ReadChunkSlices call (slab-resident chunks many to a preadv);
+// the CRC is folded over the whole segment; and in sidecar mode the same
+// buffer goes through the fingerprint RPC, whose answer has to be the
+// client's recipe entries of this segment.  Then the file ID is minted
+// from the content's CRC exactly like UPLOAD_FILE, the recipe stored and
+// the fingerprint session committed.  All-or-nothing with ref rollback;
+// any failure makes the client fall back to a plain upload.
 void StorageServer::UploadChunksComplete(Conn* c) {
   close(c->file_fd);
   c->file_fd = -1;
@@ -3906,133 +3948,206 @@ void StorageServer::UploadChunksComplete(Conn* c) {
     fail(2 /*ENOENT: expired mid-stream*/);
     return;
   }
-  c->file_size = s->recipe.logical_size;  // upload-size histogram basis
-  c->ingest_chunks_total = static_cast<int64_t>(s->recipe.chunks.size());
+  const Recipe& recipe = s->recipe;
+  c->file_size = recipe.logical_size;  // upload-size histogram basis
+  c->ingest_chunks_total = static_cast<int64_t>(recipe.chunks.size());
   int tmp_fd = open(c->tmp_path.c_str(), O_RDONLY);
   if (tmp_fd < 0) {
     fail(5);
     return;
   }
-  int64_t t0 = MonoUs();
-  Recipe done;  // refs taken so far (rollback set)
-  done.logical_size = s->recipe.logical_size;
+  // Sidecar mode keeps its near-dup/attribution index OUTSIDE the chunk
+  // store, and the client-side fingerprint pipeline never talked to it:
+  // each assembled segment goes through the plugin as an upload's would
+  // (the cpu plugin indexes in the chunk store itself, so fingerprinting
+  // there would be pure waste).  The same answer holds the client's
+  // recipe to this node's own cut of the content: a recipe cut under
+  // other parameters is rolled back before anything names the file, and
+  // the client uploads plain.  A sidecar that cannot be reached fails
+  // open as everywhere (no signature, the plugin's WARN line).
+  DedupPlugin* const fp_plugin =
+      dedup_ != nullptr && std::string(dedup_->Name()) == "sidecar"
+          ? dedup_.get()
+          : nullptr;
+  const int64_t fp_session =
+      fp_plugin != nullptr ? fp_plugin->BeginChunked() | kDedupReindexSessionBit
+                           : 0;
+  bool fp_ok = fp_plugin != nullptr;
+  Recipe done;  // refs taken so far (the rollback set, in no order)
   int64_t saved = 0, hits = 0, missing = 0;
   // The file ID's crc32 is identity metadata every consumer may check
-  // (trunk slots already do): compute it server-side over the logical
+  // (trunk slots already do): computed server-side over the logical
   // stream — shipped chunks from the wire payload, present chunks read
   // back from the store (local-disk cost, still far below re-shipping)
-  // — never trust the client's claim.
+  // — never the client's claim.
   uint32_t crc = 0;
-  bool ok = true;
-  std::string payload;
-  int64_t t_chunk = t0;  // the loop's time, put down chunk by chunk
-  for (size_t i = 0; ok && i < s->recipe.chunks.size(); ++i) {
-    const RecipeEntry& e = s->recipe.chunks[i];
-    if (s->needed[i] != 0) {
+  uint8_t status = 0;  // the commit's answer so far: 0, EIO or EINVAL
+  std::vector<ChunkStore::SliceReq> reads;
+  size_t first = 0;      // the segment's first recipe entry
+  int64_t base = 0;      // and its offset in the logical stream
+  int64_t tmp_off = 0;   // next shipped chunk's offset in the tmp file
+  while (status == 0 && base < recipe.logical_size) {
+    const int64_t seg_len = std::min<int64_t>(cfg_.dedup_segment_bytes,
+                                              recipe.logical_size - base);
+    // This node cuts every segment on its own, so its recipe has an
+    // entry ending at every segment end.  One that crosses it (or is
+    // longer than a segment) is a foreign cut, and has no place in the
+    // buffer either.
+    size_t end = first;
+    int64_t fill = 0;
+    while (end < recipe.chunks.size() &&
+           fill + recipe.chunks[end].length <= seg_len)
+      fill += recipe.chunks[end++].length;
+    if (fill != seg_len) {
+      FDFS_LOG_WARN("negotiated upload session %lld: the client's recipe "
+                    "entry %zu crosses offset %lld, where this node's cut "
+                    "of a %lld-byte segment ends",
+                    static_cast<long long>(s->id), end,
+                    static_cast<long long>(base + seg_len),
+                    static_cast<long long>(cfg_.dedup_segment_bytes));
+      status = 22;
+      break;
+    }
+    char* const seg = KeptSegment(seg_len);
+    const int64_t t_seg0 = MonoUs();
+    // Shipped entries first, so that a digest this commit ships is in
+    // the store before a later occurrence of it is read from there.
+    int64_t at = 0;  // the entry's place in the segment
+    for (size_t i = first; i < end; at += recipe.chunks[i].length, ++i) {
+      if (s->needed[i] == 0) continue;
+      const RecipeEntry& e = recipe.chunks[i];
       ++missing;
-      payload.resize(static_cast<size_t>(e.length));
-      int64_t got = 0;
-      while (got < e.length) {
-        ssize_t r = read(tmp_fd, payload.data() + got, e.length - got);
-        if (r <= 0) break;
-        got += r;
-      }
       // Content-addressed store: the payload must BE its claimed digest
       // before PutAndRef (same check the replication receiver runs) —
       // the client computed these digests, and a buggy or hostile one
       // must not poison future dedup hits under this digest.
-      if (got != e.length ||
-          Sha1(payload.data(), static_cast<size_t>(e.length)).Hex() !=
+      if (!PreadFull(tmp_fd, seg + at, e.length, tmp_off) ||
+          Sha1(seg + at, static_cast<size_t>(e.length)).Hex() !=
               e.digest_hex) {
         FDFS_LOG_WARN("negotiated upload: chunk %s failed digest check",
                       e.digest_hex.c_str());
-        ok = false;
+        status = 5;
         break;
       }
+      tmp_off += e.length;
       bool existed = false;
       std::string err;
-      if (!s->cs->PutAndRef(e.digest_hex, payload.data(),
+      if (!s->cs->PutAndRef(e.digest_hex, seg + at,
                             static_cast<size_t>(e.length), &existed, &err)) {
         FDFS_LOG_ERROR("negotiated upload chunk store: %s", err.c_str());
-        ok = false;
+        status = 5;
         break;
       }
       done.chunks.push_back(e);  // ref taken: in the rollback set
-    } else {
-      if (!s->cs->RefOne(e.digest_hex)) {
+    }
+    const int64_t t_seg1 = MonoUs();
+    c->verify_us += t_seg1 - t_seg0;
+    reads.clear();
+    at = 0;
+    for (size_t i = first; status == 0 && i < end;
+         at += recipe.chunks[i].length, ++i) {
+      if (s->needed[i] != 0) continue;
+      const RecipeEntry& e = recipe.chunks[i];
+      int64_t stored_len = -1;
+      if (!s->cs->RefOne(e.digest_hex, &stored_len)) {
         // Deleted between the bitmap and this commit (the pin only
         // defers the unlink, it does not preserve the reference):
         // report failure and let the client re-send the whole payload.
         FDFS_LOG_WARN("negotiated upload: chunk %s vanished before commit",
                       e.digest_hex.c_str());
-        ok = false;
+        status = 5;
         break;
       }
       done.chunks.push_back(e);
-      if (!s->cs->ReadChunk(e.digest_hex, e.length, &payload)) {
-        FDFS_LOG_WARN("negotiated upload: chunk %s unreadable at commit",
-                      e.digest_hex.c_str());
-        ok = false;
+      // The batched read checks bounds only: a stored chunk of another
+      // length than the recipe's is not the recipe's chunk.
+      if (stored_len != e.length) {
+        FDFS_LOG_WARN("negotiated upload: chunk %s is %lld bytes in the "
+                      "store, %lld in the recipe", e.digest_hex.c_str(),
+                      static_cast<long long>(stored_len),
+                      static_cast<long long>(e.length));
+        status = 5;
         break;
       }
+      reads.push_back({&e.digest_hex, 0, e.length, seg + at});
       saved += e.length;
       ++hits;
     }
-    crc = Crc32(payload.data(), static_cast<size_t>(e.length), crc);
-    const int64_t now = MonoUs();
-    (s->needed[i] != 0 ? c->verify_us : c->present_us) += now - t_chunk;
-    t_chunk = now;
+    std::string unreadable;
+    if (status == 0 && !reads.empty() &&
+        !s->cs->ReadChunkSlices(reads.data(), reads.size(),
+                                &c->commit_read_batches,
+                                &c->commit_read_chunks, &unreadable)) {
+      FDFS_LOG_WARN("negotiated upload: chunk %s unreadable at commit",
+                    unreadable.c_str());
+      status = 5;
+    }
+    if (status != 0) {
+      c->present_us += MonoUs() - t_seg1;
+      break;
+    }
+    crc = Crc32(seg, static_cast<size_t>(seg_len), crc);
+    const int64_t t_seg2 = MonoUs();
+    c->present_us += t_seg2 - t_seg1;
+    if (fp_ok) {
+      std::vector<ChunkFp> fps;
+      fp_ok = fp_plugin->FingerprintChunks(
+          fp_session, seg, static_cast<size_t>(seg_len), base, &fps);
+      if (fp_ok &&
+          !NodeCutsEqualRecipe(fps, recipe, first, end - first, s->id))
+        status = 22;
+      c->reindex_us += MonoUs() - t_seg2;
+    }
+    first = end;
+    base += seg_len;
   }
   close(tmp_fd);
   unlink(c->tmp_path.c_str());
   c->tmp_path.clear();
+  ReleaseTmpSegment();
   c->ingest_chunks_missing = missing;
-  if (ok && crc != s->crc32)
+  if (ctr_ingest_commit_read_batches_ != nullptr) {
+    ctr_ingest_commit_read_batches_->fetch_add(c->commit_read_batches,
+                                               std::memory_order_relaxed);
+    ctr_ingest_commit_read_chunks_->fetch_add(c->commit_read_chunks,
+                                              std::memory_order_relaxed);
+  }
+  const int64_t t_recipe = MonoUs();
+  if (status == 0 && crc != s->crc32)
     FDFS_LOG_WARN("negotiated upload: client declared crc %u, content is %u "
                   "(ID minted from content)", s->crc32, crc);
-  std::string id = ok ? MintFileId(s->spi, s->recipe.logical_size, crc,
-                                   s->ext, false)
-                      : "";
+  std::string id = status == 0 ? MintFileId(s->spi, recipe.logical_size, crc,
+                                            s->ext, false)
+                               : "";
   auto parts = id.empty() ? std::nullopt : DecodeFileId(id);
   std::optional<std::string> local =
       parts.has_value()
           ? LocalPath(store_.store_path(s->spi), parts->RemoteFilename())
           : std::nullopt;
+  if (status == 0 && !local.has_value()) status = 22;
   std::string err;
-  if (!ok || !local.has_value()) {
-    s->cs->UnrefAll(done);
-    fail(ok ? 22 : 5);
-    return;
-  }
-  if (!s->cs->StoreRecipe(*local + ".rcp", done, &err)) {
+  if (status == 0 && !s->cs->StoreRecipe(*local + ".rcp", recipe, &err)) {
     FDFS_LOG_ERROR("negotiated upload recipe write: %s", err.c_str());
+    status = 5;
+  }
+  if (status != 0) {
+    if (fp_plugin != nullptr) fp_plugin->AbortChunked(fp_session);
     s->cs->UnrefAll(done);
-    fail(5);
+    fail(status);
     return;
   }
-  c->cswrite_us = MonoUs() - t0;
-  c->recipe_us = t0 + c->cswrite_us - t_chunk;
-  // Sidecar mode keeps its near-dup/attribution index OUTSIDE the chunk
-  // store, and the client-side fingerprint pipeline never talked to it:
-  // feed the assembled bytes through the plugin exactly as a recovered
-  // file is (the cpu plugin indexes in the chunk store itself, so
-  // re-fingerprinting there would be pure waste).  The same pass holds
-  // the client's recipe to this node's own cut of the content: a recipe
-  // cut under other parameters is rolled back before the binlog hears
-  // of it, and the client uploads plain.  A sidecar that cannot be
-  // reached fails open as everywhere (no signature, a WARN line).
-  if (dedup_ != nullptr && std::string(dedup_->Name()) == "sidecar") {
-    const int64_t t_ri = MonoUs();
-    const Reindexed got = ReindexRecovered(
-        dedup_.get(), *local, cfg_.group_name + "/" + parts->RemoteFilename(),
-        &done);
-    c->reindex_us = MonoUs() - t_ri;
-    if (got == Reindexed::kForeignCuts) {
-      s->cs->RemoveRecipe(*local + ".rcp", nullptr);
-      s->cs->UnrefAll(done);
-      fail(22);
-      return;
-    }
+  const int64_t t_commit = MonoUs();
+  c->recipe_us = t_commit - t_recipe;
+  // present and verify are the chunk store's share of the commit, and
+  // the recipe's write with them (the access log's cswrite column).
+  c->cswrite_us = c->present_us + c->verify_us + c->recipe_us;
+  if (fp_plugin != nullptr) {
+    if (fp_ok)
+      fp_plugin->CommitChunked(
+          fp_session, cfg_.group_name + "/" + parts->RemoteFilename());
+    else
+      fp_plugin->AbortChunked(fp_session);
+    c->reindex_us += MonoUs() - t_commit;
   }
   stats_.dedup_hits += hits;
   stats_.dedup_bytes_saved += saved;

@@ -239,10 +239,13 @@ class StorageServer {
     // negotiated-upload stage splits (UPLOAD_RECIPE: negotiate;
     // UPLOAD_CHUNKS: the commit's stages; 0 on every other request)
     int64_t negotiate_us = 0;   // recipe parse + PinAndMask
-    int64_t present_us = 0;     // present chunks: RefOne + ReadChunk + CRC
+    int64_t present_us = 0;     // present chunks: RefOne + batched read; CRC
     int64_t verify_us = 0;      // shipped chunks: digest check + PutAndRef
     int64_t recipe_us = 0;      // id mint + recipe write
-    int64_t reindex_us = 0;     // ReindexRecovered: read-back + fingerprint
+    int64_t reindex_us = 0;     // cut + fingerprint RPC + compare + commit
+    // the preadv calls the commit's batched reads took, and their chunks
+    int64_t commit_read_batches = 0;
+    int64_t commit_read_chunks = 0;
     std::string peer_ip;
     // Negotiated upload (UPLOAD_CHUNKS): the session this request
     // commits, plus the missing/total split RecordRequestSpans turns
@@ -434,14 +437,8 @@ class StorageServer {
   void SweepIngestSessions();          // timer: expire vanished clients
   // Re-register a recovered file's signature/attributions with the
   // dedup plugin (sidecar-mode rebuilds; bytes are local, wire cost 0).
-  // With `expect` (a negotiated commit: the recipe the client cut), the
-  // fingerprints the node's own chunker and the plugin give for the
-  // stored bytes must be that recipe, entry for entry; if not, nothing
-  // is indexed and the answer is kForeignCuts.
-  enum class Reindexed { kIndexed, kUnavailable, kForeignCuts };
-  Reindexed ReindexRecovered(DedupPlugin* plugin, const std::string& local,
-                             const std::string& file_ref,
-                             const Recipe* expect = nullptr);
+  void ReindexRecovered(DedupPlugin* plugin, const std::string& local,
+                        const std::string& file_ref);
   void DeleteWork(Conn* c);          // delete body (dio worker)
 
   // -- handlers (storage_service.c analogues) ----------------------------
@@ -680,6 +677,8 @@ class StorageServer {
   std::atomic<int64_t>* ctr_ingest_fallbacks_ = nullptr;
   std::atomic<int64_t>* ctr_ingest_chunks_present_ = nullptr;
   std::atomic<int64_t>* ctr_ingest_chunks_shipped_ = nullptr;
+  std::atomic<int64_t>* ctr_ingest_commit_read_batches_ = nullptr;
+  std::atomic<int64_t>* ctr_ingest_commit_read_chunks_ = nullptr;
   // Negotiated-upload stage clocks (the access log's last five columns).
   StatHistogram* hist_ingest_negotiate_ = nullptr;
   StatHistogram* hist_ingest_present_ = nullptr;

@@ -781,10 +781,21 @@ static void TestChunkStoreSlabEndToEnd() {
   auto pinned = cs.ReadRecipeAndPin(rcp);
   CHECK(pinned.has_value());
   cs.UnpinRecipe(*pinned);
+  // RefOne names the length the store holds, in either layout (a
+  // negotiated commit holds its recipe's length against it), and gives
+  // none for a digest the store does not have.
+  int64_t stored = 0;
+  CHECK(cs.RefOne(dsmall, &stored) && stored == 1000);
+  CHECK(cs.RefOne(dbig, &stored) && stored == 8000);
+  CHECK(!cs.RefOne(std::string(40, '0'), &stored) && stored == 8000);
+  cs.UnrefAll(r);  // the two references back: the recipe holds the rest
   // Boot rescan: refs rebuilt from the slab-resident recipe.
   ChunkStore cs2(dir, 0, 0, so);
   cs2.RebuildFromRecipes();
   CHECK(cs2.Has(dsmall) && cs2.Has(dbig));
+  CHECK(cs2.RefOne(dsmall, &stored) && stored == 1000);
+  CHECK(cs2.RefOne(dbig, &stored) && stored == 8000);
+  cs2.UnrefAll(r);
   CHECK(cs2.ReadChunk(dsmall, 1000, &back) && back == small);
   // Quarantine a slab-resident chunk: record dies, bytes preserved in
   // quarantine/, heal-on-upload re-appends a fresh record.

@@ -12,7 +12,14 @@
   parameters is refused by the node and ends in a counted plain upload;
 * a client that cannot learn its node's parameters uploads plain;
 * a shipped chunk whose bytes are not its digest is refused and nothing
-  is stored under that digest.
+  is stored under that digest;
+* the commit assembles each segment once in the worker's buffer: a file
+  over three segments commits to the references' recipe, CRC and
+  signature with its present chunks read many to a ``preadv`` and nothing
+  left under ``tmp/``; a recipe entry across a segment end, a present
+  chunk of another stored length, a chunk gone before the commit all
+  fail it with every reference given back; a digest repeated in one
+  recipe reads right at every occurrence.
 
 Small widths and a 1 MB segment keep it fast.
 """
@@ -21,6 +28,8 @@ import hashlib
 import json
 import os
 import sys
+import time
+import zlib
 
 import numpy as np
 import pytest
@@ -29,11 +38,16 @@ from fastdfs_tpu.client import FdfsClient, StorageClient
 from fastdfs_tpu.client.conn import StatusError
 from fastdfs_tpu.client.fingerprint import (SHIPPED_PARAMS, ChunkingParams,
                                             fingerprint_buffer)
+from fastdfs_tpu.client.storage_client import (pack_upload_chunks_prefix,
+                                               pack_upload_recipe,
+                                               unpack_upload_recipe_resp)
+from fastdfs_tpu.common.fileid import decode_file_id
 from fastdfs_tpu.common.protocol import (StorageCmd, pack_chunking,
                                          pack_group_name, unpack_chunking)
 from fastdfs_tpu.ops import gear_cdc
-from harness import (Sidecar, chunk_digests, start_storage, start_tracker,
-                     upload_retry)
+from fastdfs_tpu.trace import decode_dump
+from harness import (Sidecar, chunk_digests, recipe_keys, start_storage,
+                     start_tracker, upload_retry)
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, os.path.join(REPO, "benchmark"))
@@ -356,5 +370,188 @@ def test_shipped_chunk_that_is_not_its_digest_is_refused(tmp_path):
         assert lie.hex() not in stored
         assert honest[1][1].hex() in stored                 # by its own name
         assert _fetch(st, fid)[0] == honest
+    finally:
+        st.stop()
+
+
+# -- (e) the commit's one pass over each segment -------------------------------------
+
+def _negotiate(sc, data, chunks):
+    """Phase 1 alone: (session, mask) for the recipe `chunks` of `data`."""
+    sc.conn.send_request(StorageCmd.UPLOAD_RECIPE, pack_upload_recipe(
+        0xFF, "bin", zlib.crc32(data), len(data), chunks))
+    return unpack_upload_recipe_resp(sc.conn.recv_response("upload_recipe"),
+                                     len(chunks))
+
+
+def _commit(sc, session, data, chunks, mask):
+    """Phase 2 alone: ships what the mask asks for; the reply's body, or
+    StatusError (no fall-back to a plain upload, as the client has)."""
+    body, off = [], 0
+    for (length, _), need in zip(chunks, mask):
+        if need:
+            body.append(data[off:off + length])
+        off += length
+    body = b"".join(body)
+    sc.conn.send_request(StorageCmd.UPLOAD_CHUNKS,
+                         pack_upload_chunks_prefix(session, len(body)) + body)
+    return sc.conn.recv_response("upload_chunks")
+
+
+def _tmp_files(base) -> list[str]:
+    return os.listdir(os.path.join(str(base), "tmp"))
+
+
+def _gone(base, timeout=10.0) -> bool:
+    """Every chunk of the store unlinked: no reference is left on any."""
+    deadline = time.time() + timeout
+    while chunk_digests(str(base)) and time.time() < deadline:
+        time.sleep(0.2)
+    return not chunk_digests(str(base))
+
+
+@pytest.mark.parametrize("mode", ["sidecar", "cpu"])
+def test_commit_assembles_each_segment_once(tmp_path, mode):
+    base = tmp_path / "st"
+    sidecar = None
+    if mode == "sidecar":
+        sidecar = Sidecar(str(tmp_path / "sc"), (
+            "--platform", "cpu", "--cdc-widths", "%d:%d:%d" % NARROW),
+            state_dir=str(tmp_path / "state"))
+    st = start_storage(str(base), dedup_mode=mode,
+                       dedup_sidecar=sidecar.sock if sidecar else "",
+                       extra=_conf(NARROW) + "\nslow_request_threshold_ms = 1")
+    widths = _widths(NARROW)
+    gen0 = _seeded(2 * M + M // 2, 51)
+    gen1 = gen0[:300 * K] + _seeded(5000, 52) + gen0[308 * K:]
+    try:
+        with StorageClient(st.ip, st.port, timeout=120.0) as sc:
+            sc.upload_buffer(gen0, ext="bin")
+            before = sc.stat()["counters"]
+            stats: dict = {}
+            fid = sc.upload_buffer_dedup(gen1, ext="bin", stats=stats)
+            after = sc.stat()["counters"]
+            spans = {s.name: s for s in decode_dump(sc.trace_dump())}
+            assert stats["fallback"] == ""
+            assert sc.download_to_buffer(fid) == gen1
+        assert _tmp_files(base) == []       # nothing materialised, nothing left
+        want, mask, _ = reference_negotiated.exchange([gen0], gen1, widths)
+        assert _fetch(st, fid) == (want, len(gen1))
+        assert decode_file_id(fid)[1].crc32 == zlib.crc32(gen1)
+
+        def delta(name):
+            return after.get(name, 0) - before.get(name, 0)
+        assert delta("ingest.recipe_uploads") == 1
+        assert delta("ingest.recipe_fallbacks") == 0
+        # the present chunks under slab_chunk_threshold came many to a preadv
+        in_slab = sum(1 for (n, _), need in zip(want, mask)
+                      if not need and n < 64 * K)
+        assert in_slab > 100
+        assert delta("ingest.commit_read_chunks") == in_slab
+        assert 0 < delta("ingest.commit_read_batches") < in_slab // 4
+        # and the commit's span says the same of itself
+        present = spans["storage.commit.present"]
+        (reads,) = [s for n, s in spans.items()
+                    if n.startswith("ingest.commit_reads ")]
+        assert reads.parent_id == present.span_id
+        assert reads.name.split()[1] == "%d/%d" % (
+            in_slab, delta("ingest.commit_read_batches"))
+        assert ("storage.reindex" in spans) == (mode == "sidecar")
+    finally:
+        st.stop()
+        if sidecar:
+            sidecar.stop()
+    if sidecar:         # stopped: it has written what it keeps
+        near = np.load(str(tmp_path / "state" / "sidecar_near.npz"),
+                       allow_pickle=True)
+        sigs = {json.loads(str(ref)): sig
+                for ref, sig in zip(near["refs"], near["sigs"])}
+        assert np.array_equal(np.asarray(sigs[fid], np.uint32),
+                              reference.file_signature(gen1, widths))
+
+
+@pytest.mark.parametrize("case,status,says", [
+    ("entry_across_a_segment_end", 22, "the client's recipe entry"),
+    ("entry_longer_than_a_segment", 22, "the client's recipe entry"),
+    ("present_chunk_of_another_length", 5, "bytes in the store"),
+    ("chunk_gone_before_commit", 5, "vanished before commit")])
+def test_commit_that_cannot_stand_gives_every_reference_back(tmp_path, case,
+                                                             status, says):
+    base = tmp_path / "st"
+    st = start_storage(str(base), dedup_mode="cpu", extra=_conf(NARROW))
+    gen0 = _seeded(2 * M + M // 2, 53)
+    node_cut = _recipe(fingerprint_buffer(gen0, _params(NARROW)))
+    if case == "entry_across_a_segment_end":
+        # the whole buffer cut at once: what a client cut before it asked
+        chunks = _recipe(fingerprint_buffer(gen0, _params(NARROW, 1, 64 * M)))
+        ends = set(np.cumsum([n for n, _ in chunks]).tolist())
+        assert M not in ends
+    elif case == "entry_longer_than_a_segment":
+        # the second segment as one entry of 1 MB + 1: no place in the buffer
+        ends = np.cumsum([n for n, _ in node_cut]).tolist()
+        chunks = node_cut[:ends.index(M) + 1] + [
+            (M + 1, hashlib.sha1(gen0[M:2 * M + 1]).digest()),
+            (M // 2 - 1, hashlib.sha1(gen0[2 * M + 1:]).digest())]
+    elif case == "present_chunk_of_another_length":
+        # one byte moved from an entry to its neighbour: both digests are
+        # in the store, under other lengths
+        (n0, d0), (n1, d1) = node_cut[5], node_cut[6]
+        chunks = node_cut[:5] + [(n0 + 1, d0), (n1 - 1, d1)] + node_cut[7:]
+    else:
+        chunks = node_cut
+    assert sum(n for n, _ in chunks) == len(gen0)
+    try:
+        with StorageClient(st.ip, st.port, timeout=60.0) as sc:
+            fid0 = sc.upload_buffer(gen0, ext="bin")
+            recipes = recipe_keys(str(base))
+            before = sc.stat()["counters"]
+            session, mask = _negotiate(sc, gen0, chunks)
+            if case == "chunk_gone_before_commit":
+                assert not any(mask)
+                with StorageClient(st.ip, st.port) as other:
+                    other.delete_file(fid0)     # the pins keep only the bytes
+                recipes = set()
+            with pytest.raises(StatusError) as refused:
+                _commit(sc, session, gen0, chunks, mask)
+            assert refused.value.status == status
+            after = sc.stat()["counters"]
+            assert (after["ingest.recipe_fallbacks"]
+                    - before.get("ingest.recipe_fallbacks", 0)) == 1
+            assert after.get("ingest.recipe_uploads", 0) == before.get(
+                "ingest.recipe_uploads", 0)
+            assert recipe_keys(str(base)) == recipes    # no recipe on disk
+            assert _tmp_files(base) == []
+            if case != "chunk_gone_before_commit":
+                assert sc.download_to_buffer(fid0) == gen0
+                sc.delete_file(fid0)
+        # the one file that referenced them is deleted: a chunk that is
+        # still there holds a reference the failed commit did not give back
+        assert _gone(base), sorted(chunk_digests(str(base)))[:3]
+    finally:
+        st.stop()
+    assert says in st.stderr_text + st.stdout_text
+
+
+def test_digest_repeated_in_one_recipe_reads_right_each_time(tmp_path):
+    base = tmp_path / "st"
+    st = start_storage(str(base), dedup_mode="cpu", extra=_conf(NARROW))
+    block = _seeded(300 * K, 54)
+    # the block four times, over two segments: cold, every occurrence is
+    # shipped (the first put, the later ones found); warm, all are read
+    cold = _seeded(100 * K, 55) + block * 4 + _seeded(50 * K, 56)
+    warm = block * 3 + _seeded(20 * K, 57) + block * 2
+    try:
+        with StorageClient(st.ip, st.port, timeout=60.0) as sc:
+            for data, all_new in ((cold, True), (warm, False)):
+                want = reference.recipe(data, _widths(NARROW))
+                digests = [d for _, d in want]
+                assert len(set(digests)) < len(digests) - 20
+                stats: dict = {}
+                fid = sc.upload_buffer_dedup(data, ext="bin", stats=stats)
+                assert stats["fallback"] == ""
+                assert (stats["chunks_missing"] == len(want)) == all_new
+                assert _fetch(st, fid) == (want, len(data))
+                assert decode_file_id(fid)[1].crc32 == zlib.crc32(data)
+                assert sc.download_to_buffer(fid) == data
     finally:
         st.stop()

@@ -12,11 +12,16 @@ The daemon (storage.conf:use_access_log) writes one line per request to
 (native/storage/server.cc:LogAccess; older 8-, 13- and 16-column logs
 parse too, with zeros for the stages they lack).  The last five are the
 negotiated upload's: ``negotiate_us`` is an ``upload_recipe`` request's
-parse + pin-and-mask; inside an ``upload_chunks`` commit ``present_us``
-(chunks the store had: reference, read back, CRC) and ``verify_us``
-(shipped chunks: digest check, write) lie inside ``cswrite_us``, then
-``recipe_us`` and ``reindex_us`` (the stored file read back and
-fingerprinted for its signature, before the reply).  ``cdc_us`` is the native
+parse + pin-and-mask; an ``upload_chunks`` commit assembles the file
+segment by segment in its worker's buffer, and its columns are sums over
+the segments: ``verify_us`` (shipped chunks: read into place, digest
+check, write), ``present_us`` (chunks the store had: referenced, read
+into place by one batched read a segment, then the CRC over the whole
+assembled segment) and ``recipe_us`` make up ``cswrite_us``;
+``reindex_us`` is the assembled segment cut and fingerprinted for the
+file's signature, the answer held against the client's recipe, and the
+fingerprint session's commit, all before the reply (0 in cpu mode).
+``cdc_us`` is the native
 chunker's share of ``fp_us``; ``dio_wait_us`` (the wait in the dio queue)
 and ``readback_us`` (the tmp file read back before each fingerprint call)
 lie inside ``work_us``.  This tool answers the question the raw ingest rate
@@ -139,7 +144,8 @@ def aggregate(path: str) -> dict:
         if total_cost > 0:
             # fp_lock and cdc are subsets of fp; work contains dio_wait +
             # readback + fp + cswrite + binlog + negotiate + reindex;
-            # cswrite contains present + verify + recipe.
+            # cswrite contains present + verify + recipe (of a negotiated
+            # commit it is their sum, so cs_write below is recipe_us).
             # Report the orthogonal decomposition of cost_us.
             recv = d["recv_us"]
             fp = d["fp_us"]
