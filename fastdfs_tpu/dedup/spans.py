@@ -5,10 +5,18 @@ sinks:
 
 * a ``jax.profiler.TraceAnnotation(name, **ids)``, which lands on the
   host plane of the same ``.xplane.pb`` as the device's "XLA Ops" line, so
-  a program span and a device operation share one clock.  It is made only
+  a program span and a device operation share one clock.  That clock is
+  the profiler session's own: it runs at ``CLOCK_MONOTONIC``'s rate and
+  counts from about the session's start, so it is neither the host's
+  monotonic nor its wall clock (measured on the v5e's host and on the CPU
+  backend alike: OPERATIONS.md, "Tracing").  ``mark`` therefore stamps
+  every marker with ``mono_us``, and ``benchmark/daemon_spans.py`` joins
+  the storage daemon's ``MonoUs()`` intervals to the trace by the median
+  of (marker's trace time - its ``mono_us``).  An annotation is made only
   while a profiler session runs (one flag test otherwise).  Nesting on a
   thread is the parent link; the root span of a request carries the
-  daemon's ``session`` and ``base_offset``.
+  daemon's ``session`` and ``base_offset``, which the daemon's
+  ``storage.fp_rpc`` interval of the same RPC carries too.
 * ``acc``, a plain dict that belongs to one request (``new_acc()``): the
   span's wall time and count by name.  The sidecar folds it into its
   ``stats`` reply (``span_us``, ``span_n``) under the lock it already
@@ -36,9 +44,13 @@ def new_acc() -> dict:
 
 
 def mark(name: str, **ids) -> None:
-    """A zero-length marker that carries ``ids`` as its arguments."""
+    """A zero-length marker that carries ``ids`` as its arguments, and
+    ``mono_us``: this host's ``CLOCK_MONOTONIC`` read beside it, so that
+    every marker is an anchor between the trace's clock and the one the
+    storage daemon stamps its stages with (``MonoUs()``)."""
     if TraceAnnotation.is_enabled():
-        with TraceAnnotation(name, **ids):
+        with TraceAnnotation(name, mono_us=time.monotonic_ns() // 1000,
+                             **ids):
             pass
 
 

@@ -24,6 +24,13 @@ The dump JSON shape is the cross-language contract (covered by the
      "spans": [{"trace_id": "16-hex", "span_id": "8-hex",
                 "parent_id": "8-hex", "name": str, "start_us": int,
                 "dur_us": int, "status": int, "flags": int}]}
+
+A storage daemon with ``use_access_log`` also writes every request's
+stage intervals after its column row, traced by its client or not
+(``native/common/trace.cc:StageLineJson``; golden: ``fdfs_codec
+stage-line``): ``decode_stage_line`` makes the same ``Span``s of one such
+line, ``logged_requests`` of a whole log, so that ``render_timeline``
+draws any logged request.
 """
 
 from __future__ import annotations
@@ -45,7 +52,8 @@ from fastdfs_tpu.common.protocol import (
 )
 
 __all__ = [
-    "TraceContext", "Span", "Tracer", "decode_dump", "stitch",
+    "TraceContext", "Span", "Tracer", "decode_dump", "decode_stage_line",
+    "logged_requests", "stitch",
     "render_timeline", "collect_cluster_spans", "traced_upload",
     "TRACE_FLAG_SAMPLED", "TRACE_FLAG_SLOW",
 ]
@@ -136,6 +144,52 @@ def decode_dump(obj: dict, node: str = "") -> list[Span]:
         except (KeyError, TypeError, ValueError) as e:
             raise ValueError(f"malformed span {s!r}: {e}") from None
     return out
+
+
+def decode_stage_line(line: str, seq: int = 1, node: str = "storage",
+                      cmd_names: dict[int, str] | None = None) -> list[Span]:
+    """One ``{"event":"stages",...}`` line of a storage daemon's access
+    log as Spans: the request's root (``storage.<op>`` where ``cmd_names``
+    knows the opcode, else ``storage.cmd<N>``) and one span per recorded
+    interval under its real parent, on the wall clock (``t0_wall_us`` +
+    the interval's offset).  The log carries no ids: ``seq`` (the line's
+    place in its log) becomes the trace id, the interval's index + 2 its
+    span id.  [] for any other line; ValueError for a malformed one."""
+    line = line.strip()
+    if not line.startswith('{"event":"stages"'):
+        return []
+    try:
+        rec = json.loads(line)
+        cmd, t0 = int(rec["cmd"]), int(rec["t0_wall_us"])
+        op = (cmd_names or {}).get(cmd, f"cmd{cmd}")
+        out = [Span(trace_id=seq, span_id=1, parent_id=0,
+                    name=f"storage.{op}", start_us=t0,
+                    dur_us=int(rec["dur_us"]), status=int(rec["status"]),
+                    node=node)]
+        for i, (name, off, dur, parent, *_args) in enumerate(rec["spans"]):
+            if not -1 <= int(parent) < i:
+                raise ValueError(f"parent {parent} of interval {i}")
+            out.append(Span(trace_id=seq, span_id=i + 2,
+                            parent_id=int(parent) + 2 if parent >= 0 else 1,
+                            name=str(name), start_us=t0 + int(off),
+                            dur_us=int(dur), node=node))
+    except (KeyError, TypeError, ValueError) as e:
+        raise ValueError(f"malformed stage line {line[:120]!r}: {e}") from None
+    return out
+
+
+def logged_requests(path: str, node: str = "storage",
+                    cmd_names: dict[int, str] | None = None) -> list[Span]:
+    """Every request of an access log that recorded stages, as Spans: one
+    trace per request, numbered from 1 in file order."""
+    spans: list[Span] = []
+    seq = 1
+    with open(path) as fh:
+        for line in fh:
+            got = decode_stage_line(line, seq, node, cmd_names)
+            seq += bool(got)
+            spans.extend(got)
+    return spans
 
 
 # ---------------------------------------------------------------------------

@@ -6,6 +6,7 @@
 #include <cstdio>
 
 #include "common/bytes.h"
+#include "common/net.h"
 #include "common/protocol_gen.h"
 #include "common/threadreg.h"
 
@@ -52,6 +53,118 @@ int64_t TraceWallUs() {
   struct timespec ts;
   clock_gettime(CLOCK_REALTIME, &ts);
   return static_cast<int64_t>(ts.tv_sec) * 1000000 + ts.tv_nsec / 1000;
+}
+
+// -- per-request stage intervals -----------------------------------------
+
+const char* StageName(Stage s) {
+  static const char* const kNames[] = {
+      "storage.recv",          "dio.queue_wait",
+      "storage.tmp_readback",  "storage.fingerprint",
+      "storage.cdc",           "storage.fp_lock",
+      "storage.fp_rpc",        "storage.cs_write",
+      "storage.negotiate",     "storage.commit.verify",
+      "storage.commit.present", "storage.commit.recipe",
+      "storage.reindex",       "storage.binlog"};
+  static_assert(sizeof(kNames) / sizeof(kNames[0]) ==
+                    static_cast<size_t>(Stage::kCount),
+                "one name per stage");
+  return kNames[static_cast<int>(s)];
+}
+
+const char* const* StageArgNames(Stage s) {
+  static const char* const kFpRpc[2] = {"session", "base_offset"};
+  static const char* const kPresent[2] = {"read_chunks", "read_batches"};
+  switch (s) {
+    case Stage::kFpRpc:
+      return kFpRpc;
+    case Stage::kPresent:
+      return kPresent;
+    default:
+      return nullptr;
+  }
+}
+
+void StageTrace::Add(Stage s, int64_t start_us, int64_t end_us) {
+  sum_us[static_cast<int>(s)] += end_us - start_us;
+  if (n >= kCapacity) {
+    truncated = true;
+    return;
+  }
+  iv[n++] = Interval{start_us, end_us, {0, 0}, s, open};
+}
+
+static thread_local StageTrace* tls_stage_trace = nullptr;
+
+StageTrace* CurrentStageTrace() { return tls_stage_trace; }
+
+StageTraceBinding::StageTraceBinding(StageTrace* t) : prev_(tls_stage_trace) {
+  tls_stage_trace = t;
+}
+
+StageTraceBinding::~StageTraceBinding() { tls_stage_trace = prev_; }
+
+StageScope::StageScope(StageTrace* t, Stage s)
+    : t_(t), start_us_(0), stage_(s), idx_(-1) {
+  if (t_ == nullptr) return;
+  start_us_ = MonoUs();
+  if (t_->n >= StageTrace::kCapacity) return;  // End() keeps the sum
+  idx_ = t_->n++;
+  t_->iv[idx_] =
+      StageTrace::Interval{start_us_, start_us_, {0, 0}, s, t_->open};
+  t_->open = idx_;
+}
+
+void StageScope::SetArgs(int64_t arg0, int64_t arg1) {
+  if (idx_ < 0) return;
+  t_->iv[idx_].args[0] = arg0;
+  t_->iv[idx_].args[1] = arg1;
+}
+
+void StageScope::End() {
+  if (t_ == nullptr) return;
+  const int64_t now = MonoUs();
+  t_->sum_us[static_cast<int>(stage_)] += now - start_us_;
+  if (idx_ >= 0) {
+    t_->iv[idx_].end_us = now;
+    t_->open = t_->iv[idx_].parent;
+  } else {
+    t_->truncated = true;
+  }
+  t_ = nullptr;
+}
+
+std::string StageLineJson(const StageTrace& t, int cmd, int status,
+                          int64_t t0_mono_us, int64_t t0_wall_us,
+                          int64_t dur_us) {
+  char buf[256];
+  std::snprintf(buf, sizeof(buf),
+                "{\"event\":\"stages\",\"cmd\":%d,\"status\":%d,"
+                "\"t0_mono_us\":%lld,\"t0_wall_us\":%lld,\"dur_us\":%lld,"
+                "\"truncated\":%d,\"spans\":[",
+                cmd, status, static_cast<long long>(t0_mono_us),
+                static_cast<long long>(t0_wall_us),
+                static_cast<long long>(dur_us), t.truncated ? 1 : 0);
+  std::string out = buf;
+  out.reserve(out.size() + static_cast<size_t>(t.n) * 64 + 2);
+  for (int i = 0; i < t.n; ++i) {
+    const StageTrace::Interval& v = t.iv[i];
+    // Escape-free by construction: names come from compile-time tables.
+    std::snprintf(buf, sizeof(buf), "%s[\"%s\",%lld,%lld,%d", i ? "," : "",
+                  StageName(v.stage),
+                  static_cast<long long>(v.start_us - t0_mono_us),
+                  static_cast<long long>(v.end_us - v.start_us), v.parent);
+    out += buf;
+    if (const char* const* names = StageArgNames(v.stage)) {
+      std::snprintf(buf, sizeof(buf), ",{\"%s\":%lld,\"%s\":%lld}", names[0],
+                    static_cast<long long>(v.args[0]), names[1],
+                    static_cast<long long>(v.args[1]));
+      out += buf;
+    }
+    out += "]";
+  }
+  out += "]}";
+  return out;
 }
 
 TraceRing::TraceRing(size_t capacity)

@@ -75,6 +75,112 @@ struct TraceSpan {
   }
 };
 
+// -- per-request stage intervals -----------------------------------------
+//
+// What one request did, stage by stage, as it happened: every stage of
+// the upload path opens an interval where its work starts and closes it
+// where it ends (StageScope), once per segment and stage, never per
+// chunk.  The recorder belongs to the request (StorageServer::Conn) and
+// is written by whichever single thread works on the request at the time:
+// no lock, no allocation, no system call, only the MonoUs() reads.  The
+// access log's stage columns and the ingest histograms are the sums kept
+// here; the span ring (a traced or slow request) and the access log's
+// "stages" line (use_access_log) are two sinks for the same intervals.
+enum class Stage : uint8_t {
+  kRecv,         // storage.recv: header parsed -> body received
+  kDioWait,      // dio.queue_wait: submitted -> picked up by a worker
+  kReadback,     // storage.tmp_readback: one segment of the tmp file read
+  kFingerprint,  // storage.fingerprint: one FingerprintChunks call
+  kCdc,          // storage.cdc: the native chunker inside it
+  kFpLock,       // storage.fp_lock: wait for a pooled sidecar connection
+  kFpRpc,        // storage.fp_rpc: first byte sent -> reply read
+  kCsWrite,      // storage.cs_write: chunk-store writes
+  kNegotiate,    // storage.negotiate: UPLOAD_RECIPE parse + PinAndMask
+  kVerify,       // storage.commit.verify: shipped chunks of one segment
+  kPresent,      // storage.commit.present: present chunks of one segment
+  kRecipe,       // storage.commit.recipe: a commit's id mint + recipe write
+  kReindex,      // storage.reindex: a commit's fingerprint of one segment
+  kBinlog,       // storage.binlog: the binlog append
+  kCount
+};
+const char* StageName(Stage s);
+
+struct StageTrace {
+  static constexpr int kCapacity = 48;  // a two-segment commit records ~20
+  struct Interval {
+    int64_t start_us;  // MonoUs() stamps
+    int64_t end_us;
+    int64_t args[2];   // meaning by stage: StageArgNames (else unused)
+    Stage stage;
+    int8_t parent;     // index of the enclosing interval, -1 = the request
+  };
+  Interval iv[kCapacity];
+  int64_t sum_us[static_cast<int>(Stage::kCount)] = {0};
+  int8_t n = 0;
+  int8_t open = -1;        // innermost interval still open
+  bool truncated = false;  // an interval did not fit (the sums hold it)
+
+  int64_t Sum(Stage s) const { return sum_us[static_cast<int>(s)]; }
+  void Reset() {
+    std::memset(sum_us, 0, sizeof(sum_us));
+    n = 0;
+    open = -1;
+    truncated = false;
+  }
+  // An interval whose two ends are known already (the body's receive,
+  // the dio queue wait); nests under whatever is open.
+  void Add(Stage s, int64_t start_us, int64_t end_us);
+};
+
+// The two argument names of a stage's intervals (nullptr = none):
+// storage.fp_rpc carries the session and base_offset of its request body,
+// storage.commit.present the chunks its batched read served and the
+// preadv calls that took.
+const char* const* StageArgNames(Stage s);
+
+// The recorder of the request this thread is working on, for code that
+// has no Conn at hand (dedup.cc; ChunkedStoreWith).  Null outside a
+// request's dio work (recovery, replication senders): StageScope then
+// does nothing.
+StageTrace* CurrentStageTrace();
+class StageTraceBinding {  // sets it for a scope
+ public:
+  explicit StageTraceBinding(StageTrace* t);
+  ~StageTraceBinding();
+  StageTraceBinding(const StageTraceBinding&) = delete;
+  StageTraceBinding& operator=(const StageTraceBinding&) = delete;
+
+ private:
+  StageTrace* prev_;
+};
+
+// Opens an interval now, closes it at End() or scope exit.
+class StageScope {
+ public:
+  StageScope(StageTrace* t, Stage s);
+  ~StageScope() { End(); }
+  StageScope(const StageScope&) = delete;
+  StageScope& operator=(const StageScope&) = delete;
+  void SetArgs(int64_t arg0, int64_t arg1);
+  void End();
+
+ private:
+  StageTrace* t_;
+  int64_t start_us_;
+  Stage stage_;
+  int8_t idx_;  // -1: did not fit (or no recorder)
+};
+
+// The access log's line for one request's intervals: compact JSON, no
+// spaces (a single token to every column parser), no newline:
+//   {"event":"stages","cmd":11,"status":0,"t0_mono_us":..,"t0_wall_us":..,
+//    "dur_us":..,"truncated":0,"spans":[[name,start_offset_us,dur_us,
+//    parent_index(,{"arg":value,..})],..]}
+// Offsets are from t0_mono_us, the request's first MonoUs() stamp.
+std::string StageLineJson(const StageTrace& t, int cmd, int status,
+                          int64_t t0_mono_us, int64_t t0_wall_us,
+                          int64_t dur_us);
+
 class TraceRing {
  public:
   explicit TraceRing(size_t capacity);

@@ -3,7 +3,6 @@
 #include <string.h>
 #include <sys/socket.h>
 #include <sys/un.h>
-#include <time.h>
 #include <unistd.h>
 
 #include <algorithm>
@@ -16,6 +15,7 @@
 #include "common/log.h"
 #include "common/net.h"
 #include "common/protocol_gen.h"
+#include "common/trace.h"
 
 namespace fdfs {
 
@@ -63,38 +63,15 @@ bool CpuDedup::Save() {
   return rename(tmp.c_str(), snapshot_path_.c_str()) == 0;
 }
 
-static thread_local int64_t tls_dedup_lock_wait_us = 0;
-static thread_local int64_t tls_dedup_cdc_us = 0;
-
-int64_t TakeDedupLockWaitUs() {
-  int64_t v = tls_dedup_lock_wait_us;
-  tls_dedup_lock_wait_us = 0;
-  return v;
-}
-
-int64_t TakeDedupCdcUs() {
-  int64_t v = tls_dedup_cdc_us;
-  tls_dedup_cdc_us = 0;
-  return v;
-}
-
-static int64_t DedupMonoUs() {
-  struct timespec ts;
-  clock_gettime(CLOCK_MONOTONIC, &ts);
-  return static_cast<int64_t>(ts.tv_sec) * 1000000 + ts.tv_nsec / 1000;
-}
-
-// The native chunker, timed: both plugins cut with it, and the access
-// log's cdc_us column says how much of fp_us it was.
+// The native chunker as its own interval (storage.cdc) of the request
+// this thread works on: both plugins cut with it, and the access log's
+// cdc_us column says how much of fp_us it was.
 static std::vector<int64_t> TimedGearChunkStream(const char* data,
                                                  size_t len,
                                                  const CdcWidths& w) {
-  const int64_t t0 = DedupMonoUs();
-  std::vector<int64_t> cuts =
-      GearChunkStream(reinterpret_cast<const uint8_t*>(data), len, w.min_size,
-                      w.avg_bits, w.max_size);
-  tls_dedup_cdc_us += DedupMonoUs() - t0;
-  return cuts;
+  StageScope cdc(CurrentStageTrace(), Stage::kCdc);
+  return GearChunkStream(reinterpret_cast<const uint8_t*>(data), len,
+                         w.min_size, w.avg_bits, w.max_size);
 }
 
 bool CpuDedup::FingerprintChunks(int64_t /*session*/, const char* data,
@@ -139,13 +116,14 @@ SidecarDedup::~SidecarDedup() {
   for (int fd : pool_) close(fd);
 }
 
-int SidecarDedup::AcquireFd(bool* pooled) {
+int SidecarDedup::AcquireFd(bool* pooled, StageTrace* stages) {
   {
-    // Only the pool-mutex wait counts as "lock wait" — connection setup
-    // below is transport cost, not serialization.
-    const int64_t t0 = DedupMonoUs();
+    // Only the pool-mutex wait counts as "lock wait" (storage.fp_lock, of
+    // a fingerprint RPC) — connection setup below is transport cost, not
+    // serialization.
+    StageScope wait(stages, Stage::kFpLock);
     std::lock_guard<RankedMutex> lk(mu_);
-    tls_dedup_lock_wait_us += DedupMonoUs() - t0;
+    wait.End();
     if (!pool_.empty()) {
       int fd = pool_.back();
       pool_.pop_back();
@@ -212,19 +190,27 @@ void SidecarDedup::ReleaseFd(int fd) {
 
 bool SidecarDedup::Rpc(uint8_t cmd, const std::string& body, std::string* resp,
                        uint8_t* status, int64_t max_resp, const char* tail,
-                       size_t tail_len) {
+                       size_t tail_len, const int64_t* fp_rpc_args) {
   // Each RPC borrows its own pooled connection, so concurrent dio
   // threads overlap their sidecar round-trips.  A failure on a POOLED
   // fd retries once on a fresh connection: after a sidecar restart the
   // pool holds up to max_idle_fds_ dead sockets, and without the retry
   // each of those would fail one upload into the flat-store path.  The
   // request is header + body + tail, each sent from where it lies, and a
-  // retry sends all three again.
+  // retry sends all three again.  A fingerprint RPC (fp_rpc_args: the
+  // session and base_offset its body carries, by which the sidecar's
+  // fdfs.sidecar.request span of the same RPC is found) is an interval of
+  // the request this thread works on, storage.fp_rpc, from the first byte
+  // sent to the reply read; the wait for the connection lies before it.
   const int timeout_ms = 60000;
+  StageTrace* const stages =
+      fp_rpc_args != nullptr ? CurrentStageTrace() : nullptr;
   for (int attempt = 0; attempt < 2; ++attempt) {
     bool pooled = false;
-    int fd = AcquireFd(&pooled);
+    int fd = AcquireFd(&pooled, stages);
     if (fd < 0) return false;
+    StageScope rpc(stages, Stage::kFpRpc);
+    if (stages != nullptr) rpc.SetArgs(fp_rpc_args[0], fp_rpc_args[1]);
     uint8_t hdr[kHeaderSize];
     PutInt64BE(static_cast<int64_t>(body.size() + tail_len), hdr);
     hdr[8] = cmd;
@@ -330,8 +316,9 @@ bool SidecarDedup::FingerprintChunks(int64_t session, const char* data,
   }
   std::string resp;
   uint8_t status = 0;
+  const int64_t fp_rpc_args[2] = {session, base_offset};
   if (!Rpc(static_cast<uint8_t>(StorageCmd::kDedupFingerprintCuts), body,
-           &resp, &status, /*max_resp=*/256 << 20, data, len) ||
+           &resp, &status, /*max_resp=*/256 << 20, data, len, fp_rpc_args) ||
       status != 0 || resp.size() < 8) {
     FDFS_LOG_WARN("dedup(sidecar): fingerprint unavailable, storing flat");
     return false;

@@ -32,6 +32,8 @@
 
 namespace fdfs {
 
+struct StageTrace;  // common/trace.h: a request's stage intervals
+
 struct ChunkFp {
   int64_t offset = 0;
   int64_t length = 0;
@@ -183,13 +185,17 @@ class SidecarDedup : public DedupPlugin {
   // *pooled reports whether the fd came from the idle pool (a failure
   // on it retries once on a fresh connection — pooled sockets go stale
   // when the sidecar restarts).  -1 on connect failure.
-  int AcquireFd(bool* pooled);
+  // The wait for the pool's mutex is an interval (storage.fp_lock) of
+  // `stages`, a fingerprint RPC's request; null records nothing.
+  int AcquireFd(bool* pooled, StageTrace* stages);
   void ReleaseFd(int fd);   // return a healthy fd to the pool
   // The request's bytes are `body` then `tail` (a fingerprint segment,
-  // sent from the caller's buffer).
+  // sent from the caller's buffer).  fp_rpc_args: {session, base_offset}
+  // of a fingerprint RPC, which is then recorded as storage.fp_rpc.
   bool Rpc(uint8_t cmd, const std::string& body, std::string* resp,
            uint8_t* status, int64_t max_resp = 1 << 20,
-           const char* tail = nullptr, size_t tail_len = 0);
+           const char* tail = nullptr, size_t tail_len = 0,
+           const int64_t* fp_rpc_args = nullptr);
   // The first exchange on a fresh connection; false = refused or dead.
   bool Handshake(int fd);
   std::string socket_path_;
@@ -207,16 +213,5 @@ std::unique_ptr<DedupPlugin> MakeDedupPlugin(const std::string& mode,
                                              const std::string& sidecar_path,
                                              int sidecar_idle_conns = 0,
                                              CdcWidths widths = {});
-
-// Thread-local sidecar lock-wait accounting: SidecarDedup adds the time
-// THIS thread spent queued on the connection-pool mutex (connection
-// setup is excluded — it is transport cost, not serialization).  The
-// upload path reads-and-clears it around its fingerprint calls to
-// attribute the wait per request in the access log.
-int64_t TakeDedupLockWaitUs();
-// The same for the native chunker: time THIS thread spent in
-// GearChunkStream inside FingerprintChunks (either plugin) since the last
-// take; the access log's cdc_us column.
-int64_t TakeDedupCdcUs();
 
 }  // namespace fdfs

@@ -1722,6 +1722,7 @@ void StorageServer::OffloadToDio(Conn* c, int spi, std::function<void()> work) {
     pool = dio_pools_[i].get();
   }
   if (pool == nullptr) {  // degraded: run inline (still correct)
+    StageTraceBinding stages(&c->stages);
     work();
     return;
   }
@@ -1736,15 +1737,19 @@ void StorageServer::OffloadToDio(Conn* c, int spi, std::function<void()> work) {
     // Worker context: `work` may Respond()/RespondError() — both only
     // BUILD the response while async_pending is set; the socket and
     // epoll are touched exclusively from the loop thread below.
-    // Queue-wait stamp: time between submit (work_start_us) and this
-    // pickup is saturation, not service — traced requests surface it as
-    // a dio.queue_wait child span (the conn is worker-owned while
-    // async_pending, so writing the field here is race-free).  Floor of
-    // 1µs: an idle pool can pick up within the clock tick, and a 0
-    // would suppress the child span — the timeline should always show
-    // the wait stage, even when it reads "~0".
-    c->dio_wait_us = std::max<int64_t>(MonoUs() - c->work_start_us, 1);
-    work();
+    // Queue-wait interval: time between submit (work_start_us) and this
+    // pickup is saturation, not service — the dio.queue_wait stage (the
+    // conn is worker-owned while async_pending, so writing its recorder
+    // here is race-free).  Floor of 1µs: an idle pool can pick up within
+    // the clock tick, and a 0 would suppress the span — the timeline
+    // should always show the wait stage, even when it reads "~0".
+    c->stages.Add(Stage::kDioWait, c->work_start_us,
+                  std::max(MonoUs(), c->work_start_us + 1));
+    {
+      // dedup.cc and ChunkedStoreWith reach the recorder through this.
+      StageTraceBinding stages(&c->stages);
+      work();
+    }
     loop->Post([this, c, loop] {
       c->async_pending = false;
       if (c->dead) {  // closed while the worker ran
@@ -1843,25 +1848,12 @@ void StorageServer::ResetForNextRequest(Conn* c) {
   c->send_off = 0;
   c->send_remaining = 0;
   c->rstream.reset();
-  c->recv_done_us = 0;
   c->work_start_us = 0;
-  c->fp_us = 0;
-  c->fp_lock_us = 0;
-  c->cswrite_us = 0;
-  c->binlog_us = 0;
-  c->cdc_us = 0;
-  c->readback_us = 0;
-  c->negotiate_us = 0;
-  c->present_us = 0;
-  c->verify_us = 0;
-  c->recipe_us = 0;
-  c->reindex_us = 0;
   c->commit_read_batches = 0;
   c->commit_read_chunks = 0;
   c->ingest_session = 0;
   c->ingest_chunks_total = 0;
   c->ingest_chunks_missing = 0;
-  c->dio_wait_us = 0;
   c->trace_ctx = TraceCtx{};
   c->traced = false;
   c->trace_span = 0;
@@ -2016,14 +2008,14 @@ void StorageServer::LogAccess(Conn* c, uint8_t status, int64_t bytes) {
     case StorageCmd::kUploadChunks:  // file_size = logical, not wire bytes
       if (status == 0 && hist_upload_bytes_ != nullptr) {
         hist_upload_bytes_->Observe(c->file_size);
-        hist_ingest_present_->Observe(c->present_us);
-        hist_ingest_verify_->Observe(c->verify_us);
-        hist_ingest_reindex_->Observe(c->reindex_us);
+        hist_ingest_present_->Observe(c->stages.Sum(Stage::kPresent));
+        hist_ingest_verify_->Observe(c->stages.Sum(Stage::kVerify));
+        hist_ingest_reindex_->Observe(c->stages.Sum(Stage::kReindex));
       }
       break;
     case StorageCmd::kUploadRecipe:
       if (status == 0 && hist_ingest_negotiate_ != nullptr)
-        hist_ingest_negotiate_->Observe(c->negotiate_us);
+        hist_ingest_negotiate_->Observe(c->stages.Sum(Stage::kNegotiate));
       break;
     case StorageCmd::kDownloadFile:
       if (status == 0 && hist_download_bytes_ != nullptr)
@@ -2033,57 +2025,72 @@ void StorageServer::LogAccess(Conn* c, uint8_t status, int64_t bytes) {
       break;
   }
   if (access_log_ != nullptr) {
-    std::lock_guard<RankedMutex> lk(log_mu_);
     // "<epoch.sec> <client_ip> <cmd> <status> <bytes> <cost_us>
     //  <recv_us> <work_us> <fp_us> <fp_lock_us> <cswrite_us> <binlog_us>
     //  <req_bytes> <cdc_us> <dio_wait_us> <readback_us> <negotiate_us>
     //  <present_us> <verify_us> <recipe_us> <reindex_us>" — per-stage split
-    // (SURVEY.md §5): recv = body receive
-    // window, work = dio-stage time, then the chunked-upload splits
-    // inside the work window (fingerprint wall, its sidecar-lock-wait
-    // share, chunk-store writes, binlog append); req_bytes = request body
-    // size (wire accounting — e.g. chunk-aware replication's savings show
-    // up here); then, appended so that readers by position keep working:
-    // cdc = the native chunker's share of fp, dio_wait = the wait in the
-    // dio queue at the head of the work window, readback = the tmp file
-    // read back segment by segment before each fingerprint call (inside
-    // work, outside fp); and last the negotiated upload's own stages:
-    // negotiate (UPLOAD_RECIPE: parse + PinAndMask), then of an
-    // UPLOAD_CHUNKS commit, summed over its segments: present (RefOne of
-    // the chunks the store had, their one batched read into the segment
-    // buffer, the CRC over the assembled segment), verify (digest check +
-    // PutAndRef of the shipped ones), recipe (id + recipe write; cswrite
-    // is these three) and reindex (the assembled segment cut and
-    // fingerprinted for the file's signature, the answer held against the
-    // client's recipe, the fingerprint session committed).
-    // Columns are 0 when a stage did not occur;
+    // (SURVEY.md §5).  work = dio-stage time; every other stage column
+    // is the sum of the request's intervals of one stage (c->stages,
+    // common/trace.h): recv = body receive window, then the
+    // chunked-upload splits inside the work window (fingerprint wall, its
+    // sidecar-lock-wait share, chunk-store writes, binlog append);
+    // req_bytes = request body size (wire accounting — e.g. chunk-aware
+    // replication's savings show up here); then, appended so that readers
+    // by position keep working: cdc = the native chunker's share of fp,
+    // dio_wait = the wait in the dio queue at the head of the work
+    // window, readback = the tmp file read back segment by segment before
+    // each fingerprint call (inside work, outside fp); and last the
+    // negotiated upload's own stages: negotiate (UPLOAD_RECIPE: parse +
+    // PinAndMask), then of an UPLOAD_CHUNKS commit, summed over its
+    // segments: present (RefOne of the chunks the store had, their one
+    // batched read into the segment buffer, the CRC over the assembled
+    // segment), verify (digest check + PutAndRef of the shipped ones),
+    // recipe (id + recipe write; cswrite holds these three) and reindex
+    // (the assembled segment cut and fingerprinted for the file's
+    // signature, the answer held against the client's recipe, the
+    // fingerprint session committed; cdc and fp_lock are then shares of
+    // it).  Columns are 0 when a stage did not occur;
     // tools/access_log_stages.py aggregates them into the bench stage
     // table.
-    int64_t recv_us =
-        c->recv_done_us > 0 ? c->recv_done_us - c->req_start_us : 0;
+    //
+    // After the row, the intervals themselves, for a request that
+    // recorded any: one compact JSON line (StageLineJson; a single token,
+    // which every column parser skips like the slow-request line).
+    // Formatted only here, with the log on, and before the lock.
+    const StageTrace& st = c->stages;
+    const int64_t cost_us = now_us - c->req_start_us;
+    std::string stage_line;
+    if (st.n > 0)
+      stage_line = StageLineJson(st, c->cmd, status, c->req_start_us,
+                                 TraceWallUs() - cost_us, cost_us);
     int64_t work_us =
         c->work_start_us > 0 ? now_us - c->work_start_us : 0;
+    std::lock_guard<RankedMutex> lk(log_mu_);
     fprintf(access_log_,
             "%lld %s %d %d %lld %lld %lld %lld %lld %lld %lld %lld %lld %lld "
             "%lld %lld %lld %lld %lld %lld %lld\n",
             static_cast<long long>(time(nullptr)), c->peer_ip.c_str(), c->cmd,
             status, static_cast<long long>(bytes),
-            static_cast<long long>(now_us - c->req_start_us),
-            static_cast<long long>(recv_us),
+            static_cast<long long>(cost_us),
+            static_cast<long long>(st.Sum(Stage::kRecv)),
             static_cast<long long>(work_us),
-            static_cast<long long>(c->fp_us),
-            static_cast<long long>(c->fp_lock_us),
-            static_cast<long long>(c->cswrite_us),
-            static_cast<long long>(c->binlog_us),
+            static_cast<long long>(st.Sum(Stage::kFingerprint)),
+            static_cast<long long>(st.Sum(Stage::kFpLock)),
+            static_cast<long long>(st.Sum(Stage::kCsWrite)),
+            static_cast<long long>(st.Sum(Stage::kBinlog)),
             static_cast<long long>(c->pkg_len),
-            static_cast<long long>(c->cdc_us),
-            static_cast<long long>(c->dio_wait_us),
-            static_cast<long long>(c->readback_us),
-            static_cast<long long>(c->negotiate_us),
-            static_cast<long long>(c->present_us),
-            static_cast<long long>(c->verify_us),
-            static_cast<long long>(c->recipe_us),
-            static_cast<long long>(c->reindex_us));
+            static_cast<long long>(st.Sum(Stage::kCdc)),
+            static_cast<long long>(st.Sum(Stage::kDioWait)),
+            static_cast<long long>(st.Sum(Stage::kReadback)),
+            static_cast<long long>(st.Sum(Stage::kNegotiate)),
+            static_cast<long long>(st.Sum(Stage::kPresent)),
+            static_cast<long long>(st.Sum(Stage::kVerify)),
+            static_cast<long long>(st.Sum(Stage::kRecipe)),
+            static_cast<long long>(st.Sum(Stage::kReindex)));
+    if (!stage_line.empty()) {
+      fputs(stage_line.c_str(), access_log_);
+      fputc('\n', access_log_);
+    }
   }
   // Spans AFTER the column line: the slow gate's immediate fflush then
   // pushes this request's own access-log record out with the JSON line
@@ -2091,21 +2098,11 @@ void StorageServer::LogAccess(Conn* c, uint8_t status, int64_t bytes) {
   // which the slow request has no parseable column row — observed as a
   // fast-host race in the slow-gate integration test).
   RecordRequestSpans(c, status, now_us, bytes);
-  c->req_start_us = 0;  // one line per request
-  c->recv_done_us = 0;
+  // One line per request.  c->stages is left as it is until the next
+  // request's header resets it: a stage guard may still close after the
+  // response was built.
+  c->req_start_us = 0;
   c->work_start_us = 0;
-  c->dio_wait_us = 0;
-  c->fp_us = 0;
-  c->fp_lock_us = 0;
-  c->cswrite_us = 0;
-  c->binlog_us = 0;
-  c->cdc_us = 0;
-  c->readback_us = 0;
-  c->negotiate_us = 0;
-  c->present_us = 0;
-  c->verify_us = 0;
-  c->recipe_us = 0;
-  c->reindex_us = 0;
   c->commit_read_batches = 0;
   c->commit_read_chunks = 0;
   c->heat_key.clear();
@@ -2156,45 +2153,32 @@ void StorageServer::RecordRequestSpans(Conn* c, uint8_t status,
     trace_->Record(s);
     return s.span_id;
   };
-  // recv = body receive window; the dio work window then decomposes into
-  // queue wait -> tmp read-back -> fingerprint (the native chunker inside
-  // it) -> chunk-store writes -> binlog (sequential in the handler, per
-  // segment; their sums are laid out back-to-back).  dio.queue_wait is
-  // WAITING, not working — the span that makes a saturated dio pool
-  // visible on an fdfs_trace timeline.
-  int64_t recv_us =
-      c->recv_done_us > 0 ? c->recv_done_us - c->req_start_us : 0;
-  child("storage.recv", wall_start, recv_us);
-  int64_t work_wall = wall_start + (c->work_start_us > 0
-                                        ? c->work_start_us - c->req_start_us
-                                        : recv_us);
-  child("dio.queue_wait", work_wall, c->dio_wait_us);
-  child("storage.tmp_readback", work_wall + c->dio_wait_us, c->readback_us);
-  int64_t stage_wall = work_wall + c->dio_wait_us + c->readback_us;
-  uint32_t fp_span = child("storage.fingerprint", stage_wall, c->fp_us);
-  child("storage.cdc", stage_wall, c->cdc_us, fp_span);
-  child("storage.cs_write", stage_wall + c->fp_us, c->cswrite_us);
-  // The negotiated upload's stages (0 elsewhere): negotiate is an
-  // UPLOAD_RECIPE's whole work; verify, present and the re-index are what
-  // an UPLOAD_CHUNKS commit does with each segment in turn (their sums
-  // laid out one after the other, verify and present inside cs_write).
-  // How the present chunks were read hangs under present as an
-  // annotation: chunks served by a preadv / preadv calls.
-  child("storage.negotiate", stage_wall, c->negotiate_us);
-  child("storage.commit.verify", stage_wall, c->verify_us);
-  uint32_t present_span = child("storage.commit.present",
-                                stage_wall + c->verify_us, c->present_us);
-  if (present_span != 0 && c->commit_read_batches > 0) {
-    char ann[sizeof(TraceSpan{}.name)];
-    if (std::snprintf(ann, sizeof(ann), "ingest.commit_reads %lld/%lld",
-                      static_cast<long long>(c->commit_read_chunks),
-                      static_cast<long long>(c->commit_read_batches)) > 0)
-      child(ann, stage_wall + c->verify_us, c->present_us, present_span);
+  // The request's stages as they happened (c->stages): each interval
+  // under its real parent, its wall start the request's plus the
+  // interval's monotonic offset.  dio.queue_wait is WAITING, not working
+  // — the span that makes a saturated dio pool visible on an fdfs_trace
+  // timeline.  How a commit's present chunks were read hangs under each
+  // segment's present span as an annotation: chunks served by a preadv /
+  // preadv calls.
+  const StageTrace& st = c->stages;
+  uint32_t ids[StageTrace::kCapacity];
+  for (int i = 0; i < st.n; ++i) {
+    const StageTrace::Interval& v = st.iv[i];
+    const int64_t start = wall_start + (v.start_us - c->req_start_us);
+    const int64_t dur = v.end_us - v.start_us;
+    ids[i] = child(StageName(v.stage), start, dur,
+                   v.parent >= 0 ? ids[v.parent] : 0);
+    if (v.stage == Stage::kPresent && ids[i] != 0 && v.args[1] > 0) {
+      char ann[sizeof(TraceSpan{}.name)];
+      if (std::snprintf(ann, sizeof(ann), "ingest.commit_reads %lld/%lld",
+                        static_cast<long long>(v.args[0]),
+                        static_cast<long long>(v.args[1])) > 0)
+        child(ann, start, dur, ids[i]);
+    }
   }
-  child("storage.reindex", stage_wall + c->fp_us + c->cswrite_us,
-        c->reindex_us);
-  child("storage.binlog",
-        stage_wall + c->fp_us + c->cswrite_us + c->reindex_us, c->binlog_us);
+  int64_t work_wall =
+      wall_start + (c->work_start_us > 0 ? c->work_start_us - c->req_start_us
+                                         : st.Sum(Stage::kRecv));
   if (c->ingest_chunks_total > 0) {
     // Negotiated-upload annotation: how much of the recipe actually
     // crossed the wire (missing/total), spanning the request's work
@@ -2597,6 +2581,7 @@ void StorageServer::OnHeaderComplete(Conn* c) {
   // negative latencies).  Always stamped: the stats registry's
   // per-opcode latency histograms run even without the access log.
   c->req_start_us = MonoUs();
+  c->stages.Reset();
   c->shed_resp = false;
   if (c->peer_ip.empty()) c->peer_ip = PeerIp(c->fd);
   if (c->pkg_len < 0) {
@@ -3259,7 +3244,8 @@ void StorageServer::OnFixedComplete(Conn* c) {
 }
 
 void StorageServer::OnFileComplete(Conn* c) {
-  c->recv_done_us = MonoUs();  // recv-stage end (access log AND spans)
+  // recv-stage end (access log AND spans)
+  c->stages.Add(Stage::kRecv, c->req_start_us, MonoUs());
   if (c->discarding) {  // rejected request: body drained, send the verdict
     Respond(c, c->pending_status, c->pending_body);
     return;
@@ -3685,7 +3671,9 @@ void StorageServer::HandleSyncQueryChunks(Conn* c) {
 // falls back to a plain UPLOAD_FILE (an older daemon without this
 // opcode answers EINVAL, same client reaction).
 void StorageServer::HandleUploadRecipe(Conn* c) {
-  const int64_t t_in = MonoUs();
+  // The whole handler up to the reply: parse + PinAndMask (a refusal
+  // closes it after the answer was logged, and logs 0).
+  StageScope negotiate(&c->stages, Stage::kNegotiate);
   if (dedup_ == nullptr || chunk_stores_.empty()) {
     if (ctr_ingest_fallbacks_ != nullptr)
       ctr_ingest_fallbacks_->fetch_add(1, std::memory_order_relaxed);
@@ -3776,7 +3764,7 @@ void StorageServer::HandleUploadRecipe(Conn* c) {
     std::lock_guard<RankedMutex> lk(ingest_mu_);
     ingest_sessions_[s->id] = std::move(s);
   }
-  c->negotiate_us = MonoUs() - t_in;
+  negotiate.End();
   Respond(c, 0, body);
 }
 
@@ -4009,7 +3997,9 @@ void StorageServer::UploadChunksComplete(Conn* c) {
       break;
     }
     char* const seg = KeptSegment(seg_len);
-    const int64_t t_seg0 = MonoUs();
+    // This segment's chunk-store share: verify, then present, inside it.
+    StageScope cs_write(&c->stages, Stage::kCsWrite);
+    StageScope verify(&c->stages, Stage::kVerify);
     // Shipped entries first, so that a digest this commit ships is in
     // the store before a later occurrence of it is read from there.
     int64_t at = 0;  // the entry's place in the segment
@@ -4040,8 +4030,10 @@ void StorageServer::UploadChunksComplete(Conn* c) {
       }
       done.chunks.push_back(e);  // ref taken: in the rollback set
     }
-    const int64_t t_seg1 = MonoUs();
-    c->verify_us += t_seg1 - t_seg0;
+    verify.End();
+    StageScope present(&c->stages, Stage::kPresent);
+    const int64_t batches0 = c->commit_read_batches;
+    const int64_t chunks0 = c->commit_read_chunks;
     reads.clear();
     at = 0;
     for (size_t i = first; status == 0 && i < end;
@@ -4082,21 +4074,21 @@ void StorageServer::UploadChunksComplete(Conn* c) {
                     unreadable.c_str());
       status = 5;
     }
-    if (status != 0) {
-      c->present_us += MonoUs() - t_seg1;
-      break;
-    }
+    if (status != 0) break;
     crc = Crc32(seg, static_cast<size_t>(seg_len), crc);
-    const int64_t t_seg2 = MonoUs();
-    c->present_us += t_seg2 - t_seg1;
+    present.SetArgs(c->commit_read_chunks - chunks0,
+                    c->commit_read_batches - batches0);
+    present.End();
+    cs_write.End();
     if (fp_ok) {
+      // The chunker, the lock wait and the RPC nest under it (dedup.cc).
+      StageScope reindex(&c->stages, Stage::kReindex);
       std::vector<ChunkFp> fps;
       fp_ok = fp_plugin->FingerprintChunks(
           fp_session, seg, static_cast<size_t>(seg_len), base, &fps);
       if (fp_ok &&
           !NodeCutsEqualRecipe(fps, recipe, first, end - first, s->id))
         status = 22;
-      c->reindex_us += MonoUs() - t_seg2;
     }
     first = end;
     base += seg_len;
@@ -4112,7 +4104,10 @@ void StorageServer::UploadChunksComplete(Conn* c) {
     ctr_ingest_commit_read_chunks_->fetch_add(c->commit_read_chunks,
                                               std::memory_order_relaxed);
   }
-  const int64_t t_recipe = MonoUs();
+  // The recipe's write is the chunk store's too (the access log's cswrite
+  // column is present + verify + recipe).
+  StageScope cs_write(&c->stages, Stage::kCsWrite);
+  StageScope recipe_write(&c->stages, Stage::kRecipe);
   if (status == 0 && crc != s->crc32)
     FDFS_LOG_WARN("negotiated upload: client declared crc %u, content is %u "
                   "(ID minted from content)", s->crc32, crc);
@@ -4136,18 +4131,15 @@ void StorageServer::UploadChunksComplete(Conn* c) {
     fail(status);
     return;
   }
-  const int64_t t_commit = MonoUs();
-  c->recipe_us = t_commit - t_recipe;
-  // present and verify are the chunk store's share of the commit, and
-  // the recipe's write with them (the access log's cswrite column).
-  c->cswrite_us = c->present_us + c->verify_us + c->recipe_us;
+  recipe_write.End();
+  cs_write.End();
   if (fp_plugin != nullptr) {
+    StageScope reindex(&c->stages, Stage::kReindex);
     if (fp_ok)
       fp_plugin->CommitChunked(
           fp_session, cfg_.group_name + "/" + parts->RemoteFilename());
     else
       fp_plugin->AbortChunked(fp_session);
-    c->reindex_us += MonoUs() - t_commit;
   }
   stats_.dedup_hits += hits;
   stats_.dedup_bytes_saved += saved;
@@ -4165,9 +4157,10 @@ void StorageServer::UploadChunksComplete(Conn* c) {
     ctr_ingest_chunks_shipped_->fetch_add(missing,
                                           std::memory_order_relaxed);
   }
-  int64_t t_bl = MonoUs();
-  binlog_.Append(kBinlogOpCreate, parts->RemoteFilename());
-  c->binlog_us = MonoUs() - t_bl;
+  {
+    StageScope binlog(&c->stages, Stage::kBinlog);
+    binlog_.Append(kBinlogOpCreate, parts->RemoteFilename());
+  }
   NoteTracedMutation(c, parts->RemoteFilename());
   stats_.success_upload++;
   stats_.last_source_update = time(nullptr);
@@ -4772,23 +4765,18 @@ void StorageServer::FinishUpload(Conn* c) {
       // fan-out directory (StoreRecipe creates the chain only for the
       // flat sidecar; the flat-store fallback below makes its own).
       int64_t saved = 0, hits = 0;
-      ChunkStageUs st;
       if (StoreChunkedFromTmp(c->tmp_path, c->store_path_index, c->file_size,
                               local + ".rcp",
                               cfg_.group_name + "/" + parts->RemoteFilename(),
-                              &saved, &hits, &st)) {
+                              &saved, &hits)) {
         unlink(c->tmp_path.c_str());
         c->tmp_path.clear();
         stats_.dedup_hits += hits;
         stats_.dedup_bytes_saved += saved;
-        int64_t t_bl = MonoUs();
-        binlog_.Append(kBinlogOpCreate, parts->RemoteFilename());
-        c->binlog_us = MonoUs() - t_bl;
-        c->fp_us = st.fp;
-        c->fp_lock_us = st.fp_lock;
-        c->cswrite_us = st.cs_write;
-        c->cdc_us = st.cdc;
-        c->readback_us = st.readback;
+        {
+          StageScope binlog(&c->stages, Stage::kBinlog);
+          binlog_.Append(kBinlogOpCreate, parts->RemoteFilename());
+        }
         NoteTracedMutation(c, parts->RemoteFilename());
         stats_.success_upload++;
         stats_.last_source_update = time(nullptr);
@@ -4893,9 +4881,10 @@ void StorageServer::FinishUpload(Conn* c) {
   }
   c->tmp_path.clear();
   if (dedup_ != nullptr && !appender) dedup_->Commit(digest, id);
-  int64_t t_bl = MonoUs();
-  binlog_.Append(kBinlogOpCreate, parts->RemoteFilename());
-  c->binlog_us = MonoUs() - t_bl;
+  {
+    StageScope binlog(&c->stages, Stage::kBinlog);
+    binlog_.Append(kBinlogOpCreate, parts->RemoteFilename());
+  }
   NoteTracedMutation(c, parts->RemoteFilename());
   stats_.success_upload++;
   stats_.last_source_update = time(nullptr);
@@ -4949,10 +4938,9 @@ bool StorageServer::StoreChunkedFromTmp(const std::string& tmp_path, int spi,
                                         const std::string& rcp_path,
                                         const std::string& file_ref,
                                         int64_t* saved_bytes,
-                                        int64_t* chunk_hits,
-                                        ChunkStageUs* stage) {
+                                        int64_t* chunk_hits) {
   return ChunkedStoreWith(dedup_.get(), tmp_path, spi, size, rcp_path,
-                          file_ref, saved_bytes, chunk_hits, stage);
+                          file_ref, saved_bytes, chunk_hits);
 }
 
 bool StorageServer::ChunkedStoreWith(DedupPlugin* plugin,
@@ -4960,9 +4948,12 @@ bool StorageServer::ChunkedStoreWith(DedupPlugin* plugin,
                                      int64_t size, const std::string& rcp_path,
                                      const std::string& file_ref,
                                      int64_t* saved_bytes,
-                                     int64_t* chunk_hits,
-                                     ChunkStageUs* stage) {
+                                     int64_t* chunk_hits) {
   if (spi >= static_cast<int>(chunk_stores_.size())) return false;
+  // The request this thread works on (null on the recovery thread): each
+  // segment's read-back, fingerprint and chunk-store writes are intervals
+  // of it, one per segment and stage.
+  StageTrace* const stages = CurrentStageTrace();
   ChunkStore* cs = chunk_stores_[spi].get();
   int fd = open(tmp_path.c_str(), O_RDONLY);
   if (fd < 0) return false;
@@ -4978,9 +4969,9 @@ bool StorageServer::ChunkedStoreWith(DedupPlugin* plugin,
   while (ok && seg_base < size) {
     int64_t want = std::min<int64_t>(cfg_.dedup_segment_bytes,
                                      size - seg_base);
-    int64_t t_read = MonoUs();
+    StageScope readback(stages, Stage::kReadback);
     const char* seg = ReadTmpSegment(fd, seg_base, want);
-    if (stage != nullptr) stage->readback += MonoUs() - t_read;
+    readback.End();
     if (seg == nullptr) {
       ok = false;
       break;
@@ -4988,21 +4979,16 @@ bool StorageServer::ChunkedStoreWith(DedupPlugin* plugin,
     // Fingerprint this segment (accelerated in sidecar mode: CDC +
     // batched SHA1 run on the TPU); then write only unseen chunks.
     std::vector<ChunkFp> fps;
-    int64_t t0 = MonoUs();
-    TakeDedupLockWaitUs();  // clear: attribute only this call's wait
-    TakeDedupCdcUs();
+    // The chunker, the lock wait and the RPC nest under it (dedup.cc).
+    StageScope fingerprint(stages, Stage::kFingerprint);
     bool fp_ok = plugin->FingerprintChunks(
         session, seg, static_cast<size_t>(want), seg_base, &fps);
-    if (stage != nullptr) {
-      stage->fp += MonoUs() - t0;
-      stage->fp_lock += TakeDedupLockWaitUs();
-      stage->cdc += TakeDedupCdcUs();
-    }
+    fingerprint.End();
     if (!fp_ok) {
       ok = false;  // fingerprinting unavailable: caller stores flat
       break;
     }
-    t0 = MonoUs();
+    StageScope cs_write(stages, Stage::kCsWrite);
     for (const ChunkFp& fp : fps) {
       bool existed = false;
       std::string err;
@@ -5022,12 +5008,14 @@ bool StorageServer::ChunkedStoreWith(DedupPlugin* plugin,
       }
       recipe.chunks.push_back({fp.digest_hex, fp.length});
     }
-    if (stage != nullptr) stage->cs_write += MonoUs() - t0;
     seg_base += want;
   }
   close(fd);
   ReleaseTmpSegment();
   std::string err;
+  // The recipe's write is a chunk-store write like the chunks' (an
+  // upload's recipe_us column stays the negotiated commit's alone).
+  StageScope recipe_write(ok ? stages : nullptr, Stage::kCsWrite);
   if (!ok || !cs->StoreRecipe(rcp_path, recipe, &err)) {
     if (ok) FDFS_LOG_ERROR("recipe write: %s", err.c_str());
     // Roll back references taken so far; untouched chunks stay for
@@ -5036,6 +5024,7 @@ bool StorageServer::ChunkedStoreWith(DedupPlugin* plugin,
     plugin->AbortChunked(session);
     return false;
   }
+  recipe_write.End();
   plugin->CommitChunked(session, file_ref);
   return true;
 }

@@ -217,32 +217,19 @@ class StorageServer {
     NioThread* owner = nullptr;   // the nio loop this conn lives on
     bool async_pending = false;   // a dio worker owns the request right now
     bool dead = false;            // closed while async_pending: zombie
-    // How long THIS request sat in the dio queue before a worker picked
-    // it up (stamped by the worker; inside the work window).  Traced
-    // requests get it as a dio.queue_wait child span so fdfs_trace
-    // timelines separate waiting from working.
-    int64_t dio_wait_us = 0;
-    // access log bookkeeping (per-stage timings, SURVEY.md §5: the
-    // rebuild logs recv/work splits, not just the total)
+    // access log bookkeeping (SURVEY.md §5: the rebuild logs recv/work
+    // splits, not just the total)
     int64_t req_start_us = 0;
-    int64_t recv_done_us = 0;   // body fully received (recv stage end)
     int64_t work_start_us = 0;  // dio-stage begin (fingerprint/write)
-    // chunked-upload stage splits within the work window (0 when the
-    // request did not take that stage)
-    int64_t fp_us = 0;          // fingerprint wall (sidecar RPC / serial)
-    int64_t fp_lock_us = 0;     // share of fp_us spent queued on the
-                                // sidecar RPC mutex (engine serialization)
-    int64_t cswrite_us = 0;     // chunk-store writes
-    int64_t binlog_us = 0;      // binlog append
-    int64_t cdc_us = 0;         // share of fp_us in the native chunker
-    int64_t readback_us = 0;    // tmp-file read-back, before fp_us
-    // negotiated-upload stage splits (UPLOAD_RECIPE: negotiate;
-    // UPLOAD_CHUNKS: the commit's stages; 0 on every other request)
-    int64_t negotiate_us = 0;   // recipe parse + PinAndMask
-    int64_t present_us = 0;     // present chunks: RefOne + batched read; CRC
-    int64_t verify_us = 0;      // shipped chunks: digest check + PutAndRef
-    int64_t recipe_us = 0;      // id mint + recipe write
-    int64_t reindex_us = 0;     // cut + fingerprint RPC + compare + commit
+    // What this request did, stage by stage as it happened (body
+    // receive, dio queue wait, and per segment: tmp read-back,
+    // fingerprint with its chunker / lock wait / RPC, chunk-store
+    // writes, the negotiated commit's verify / present / re-index, the
+    // binlog append).  Written by the one thread that works on the
+    // request at the time; the access log's stage columns and the
+    // ingest histograms are its sums, the span ring and the access log's
+    // "stages" line its intervals (common/trace.h).
+    StageTrace stages;
     // the preadv calls the commit's batched reads took, and their chunks
     int64_t commit_read_batches = 0;
     int64_t commit_read_chunks = 0;
@@ -498,29 +485,19 @@ class StorageServer {
   // store-path's chunk store, and write the recipe at `rcp_path`.
   // *saved_bytes accumulates duplicate-chunk bytes.  False => caller
   // stores the file flat (fingerprinting unavailable or IO error).
-  // Per-upload stage attribution (access-log columns; the bench stage
-  // table): fingerprint wall time (sidecar RPC incl. lock wait in
-  // sidecar mode, serial CDC+SHA1 in cpu mode), the lock-wait share of
-  // it, and chunk-store write time; the native chunker's share of fp,
-  // and the tmp-file read-back that precedes each segment's fingerprint.
-  struct ChunkStageUs {
-    int64_t fp = 0;
-    int64_t fp_lock = 0;
-    int64_t cs_write = 0;
-    int64_t cdc = 0;
-    int64_t readback = 0;
-  };
+  // Each segment's read-back, fingerprint and chunk-store writes are
+  // intervals of the request the calling thread works on
+  // (CurrentStageTrace(); none on the recovery thread).
   bool StoreChunkedFromTmp(const std::string& tmp_path, int spi,
                            int64_t size, const std::string& rcp_path,
                            const std::string& file_ref,
-                           int64_t* saved_bytes, int64_t* chunk_hits,
-                           ChunkStageUs* stage = nullptr);
+                           int64_t* saved_bytes, int64_t* chunk_hits);
   // Same, against an explicit plugin (the recovery thread uses its own
   // instance — the plugins are not thread-safe, the ChunkStore is).
   bool ChunkedStoreWith(DedupPlugin* plugin, const std::string& tmp_path,
                         int spi, int64_t size, const std::string& rcp_path,
                         const std::string& file_ref, int64_t* saved_bytes,
-                        int64_t* chunk_hits, ChunkStageUs* stage = nullptr);
+                        int64_t* chunk_hits);
   // Open the logical content at `local`: a plain fd, or a recipe
   // materialized into an unlinked temp file.  -1 when missing.
   int OpenLogical(const std::string& local, int64_t* size);

@@ -4,6 +4,7 @@
 #include <stdlib.h>
 #include <sys/stat.h>
 #include <sys/wait.h>
+#include <time.h>
 #include <unistd.h>
 
 #include <algorithm>
@@ -1256,9 +1257,149 @@ static void TestThreadRegistryWatchdog() {
   quiet.join();
 }
 
+// -- per-request stage intervals (StageTrace) -----------------------------
+
+static void TestStageTraceNestingAndSums() {
+  StageTrace t;
+  t.Reset();
+  t.Add(Stage::kRecv, 100, 150);
+  {
+    StageScope fp(&t, Stage::kFingerprint);
+    {
+      StageScope cdc(&t, Stage::kCdc);
+    }
+    StageScope rpc(&t, Stage::kFpRpc);
+    rpc.SetArgs(7, 4096);
+    rpc.End();
+    rpc.End();  // idempotent
+  }
+  StageScope cs(&t, Stage::kCsWrite);
+  cs.End();
+  CHECK_EQ(t.n, 5);
+  CHECK(!t.truncated);
+  CHECK_EQ(t.open, -1);
+  CHECK(t.iv[0].stage == Stage::kRecv && t.iv[0].parent == -1);
+  CHECK_EQ(t.Sum(Stage::kRecv), 50);
+  CHECK(t.iv[1].stage == Stage::kFingerprint && t.iv[1].parent == -1);
+  CHECK(t.iv[2].stage == Stage::kCdc && t.iv[2].parent == 1);
+  CHECK(t.iv[3].stage == Stage::kFpRpc && t.iv[3].parent == 1);
+  CHECK(t.iv[3].args[0] == 7 && t.iv[3].args[1] == 4096);
+  CHECK(t.iv[4].stage == Stage::kCsWrite && t.iv[4].parent == -1);
+  // children inside their parent, siblings in order
+  CHECK(t.iv[1].start_us <= t.iv[2].start_us);
+  CHECK(t.iv[2].end_us <= t.iv[3].start_us);
+  CHECK(t.iv[3].end_us <= t.iv[1].end_us);
+  CHECK(t.iv[1].end_us <= t.iv[4].start_us);
+  // every sum is the sum of its stage's intervals
+  for (int s = 0; s < static_cast<int>(Stage::kCount); ++s) {
+    int64_t sum = 0;
+    for (int i = 0; i < t.n; ++i)
+      if (static_cast<int>(t.iv[i].stage) == s)
+        sum += t.iv[i].end_us - t.iv[i].start_us;
+    CHECK_EQ(t.sum_us[s], sum);
+  }
+  t.Reset();
+  CHECK_EQ(t.n, 0);
+  CHECK_EQ(t.Sum(Stage::kRecv), 0);
+}
+
+static void TestStageTraceOverflowKeepsSums() {
+  StageTrace t;
+  t.Reset();
+  StageScope outer(&t, Stage::kFingerprint);
+  for (int i = 0; i < StageTrace::kCapacity + 10; ++i)
+    t.Add(Stage::kCsWrite, 1000 * i, 1000 * i + 7);
+  {
+    StageScope late(&t, Stage::kBinlog);  // does not fit
+    struct timespec ts = {0, 2000000};
+    nanosleep(&ts, nullptr);
+  }
+  outer.End();
+  CHECK_EQ(t.n, StageTrace::kCapacity);
+  CHECK(t.truncated);
+  CHECK_EQ(t.Sum(Stage::kCsWrite), 7 * (StageTrace::kCapacity + 10));
+  CHECK(t.Sum(Stage::kBinlog) >= 2000);
+  CHECK_EQ(t.open, -1);  // the one that fit closed; the late one never opened
+  CHECK(t.iv[0].end_us - t.iv[0].start_us >= 2000);
+  std::string line = StageLineJson(t, 11, 0, t.iv[0].start_us, 5, 9);
+  CHECK(line.find("\"truncated\":1") != std::string::npos);
+}
+
+static void TestStageTraceNullAndThreadLocal() {
+  {
+    StageScope nothing(nullptr, Stage::kCdc);  // no recorder: a no-op
+    nothing.SetArgs(3, 4);
+  }
+  CHECK(CurrentStageTrace() == nullptr);
+  StageTrace t;
+  t.Reset();
+  {
+    StageTraceBinding bind(&t);
+    CHECK(CurrentStageTrace() == &t);
+    std::thread other([] { CHECK(CurrentStageTrace() == nullptr); });
+    other.join();
+    {
+      StageTraceBinding inner(nullptr);
+      StageScope hidden(CurrentStageTrace(), Stage::kCdc);
+    }
+    StageScope seen(CurrentStageTrace(), Stage::kCdc);
+  }
+  CHECK(CurrentStageTrace() == nullptr);
+  CHECK_EQ(t.n, 1);
+}
+
+static void TestStageLineFormat() {
+  StageTrace t;
+  t.Reset();
+  t.Add(Stage::kRecv, 1000, 1812);
+  t.Add(Stage::kDioWait, 1812, 1815);
+  t.iv[t.n++] =
+      StageTrace::Interval{1815, 2815, {0, 0}, Stage::kFingerprint, -1};
+  t.iv[t.n++] = StageTrace::Interval{1900, 2800, {-5, 65536}, Stage::kFpRpc, 2};
+  std::string line = StageLineJson(t, 11, 0, 1000, 1700000000000000LL, 2000);
+  CHECK(line ==
+        "{\"event\":\"stages\",\"cmd\":11,\"status\":0,\"t0_mono_us\":1000,"
+        "\"t0_wall_us\":1700000000000000,\"dur_us\":2000,\"truncated\":0,"
+        "\"spans\":[[\"storage.recv\",0,812,-1],[\"dio.queue_wait\",812,3,-1],"
+        "[\"storage.fingerprint\",815,1000,-1],"
+        "[\"storage.fp_rpc\",900,900,2,{\"session\":-5,"
+        "\"base_offset\":65536}]]}");
+  CHECK(line.find(' ') == std::string::npos);  // one token to column parsers
+  CHECK(line.find('\n') == std::string::npos);
+}
+
+// `common_test --stage-cost`: what one interval costs with no sink on
+// (OPERATIONS.md, "Tracing", the cost paragraph).
+static int RunStageCost() {
+  StageTrace t;
+  const int kRounds = 200000, kPer = 10;
+  int64_t t0 = MonoUs();
+  for (int r = 0; r < kRounds; ++r) {
+    t.Reset();
+    for (int i = 0; i < kPer; ++i) {
+      StageScope s(&t, Stage::kCsWrite);
+    }
+  }
+  int64_t with = MonoUs() - t0;
+  t0 = MonoUs();
+  for (int r = 0; r < kRounds; ++r) {
+    for (int i = 0; i < kPer; ++i) {
+      StageScope s(nullptr, Stage::kCsWrite);
+    }
+  }
+  int64_t without = MonoUs() - t0;
+  std::printf("{\"ns_per_interval\":%.1f,\"ns_per_null_guard\":%.2f,"
+              "\"intervals\":%d,\"recorded\":%d}\n",
+              with * 1000.0 / (kRounds * kPer),
+              without * 1000.0 / (kRounds * kPer), kRounds * kPer, t.n);
+  return 0;
+}
+
 int main(int argc, char** argv) {
   if (argc > 1 && std::strncmp(argv[1], "--lockrank-", 11) == 0)
     return RunLockRankViolation(argv[1]);
+  if (argc > 1 && std::strcmp(argv[1], "--stage-cost") == 0)
+    return RunStageCost();
 
   TestEndian();
   TestBase64();
@@ -1273,6 +1414,10 @@ int main(int argc, char** argv) {
   TestTraceRing();
   TestTraceRingThreaded();
   TestTraceCorrelator();
+  TestStageTraceNestingAndSums();
+  TestStageTraceOverflowKeepsSums();
+  TestStageTraceNullAndThreadLocal();
+  TestStageLineFormat();
   TestEventLog();
   TestEventLogThreaded();
   TestEventLoopLagHook();

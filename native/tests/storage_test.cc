@@ -13,6 +13,7 @@
 #include <vector>
 
 #include "common/bytes.h"
+#include "common/trace.h"
 #include "storage/binlog.h"
 #include "storage/chunkstore.h"
 #include "storage/ecstore.h"
@@ -129,6 +130,33 @@ static void TestCpuDedup() {
   // forget
   d2.Forget("group1/M00/00/00/x.bin");
   CHECK(!d2.Judge("abc", 10).duplicate);
+}
+
+// dedup.cc has no Conn: it reaches the request's recorder through the
+// thread-local the dio handler binds, and does nothing without one.
+static void TestDedupRecordsIntoBoundStageTrace() {
+  std::string dir = TempDir();
+  CpuDedup d(dir + "/dedup_index.dat");
+  std::string data(256 * 1024, '\0');
+  for (size_t i = 0; i < data.size(); ++i)
+    data[i] = static_cast<char>((i * 2654435761u) >> 13);
+  std::vector<ChunkFp> fps;
+  CHECK(d.FingerprintChunks(1, data.data(), data.size(), 0, &fps));  // unbound
+  StageTrace t;
+  t.Reset();
+  {
+    StageTraceBinding bind(&t);
+    StageScope fp(&t, Stage::kFingerprint);
+    fps.clear();
+    CHECK(d.FingerprintChunks(1, data.data(), data.size(), 0, &fps));
+  }
+  CHECK(!fps.empty());
+  CHECK(t.n == 2);
+  CHECK(t.iv[1].stage == Stage::kCdc && t.iv[1].parent == 0);
+  CHECK(t.iv[0].start_us <= t.iv[1].start_us &&
+        t.iv[1].end_us <= t.iv[0].end_us);
+  CHECK(t.Sum(Stage::kCdc) == t.iv[1].end_us - t.iv[1].start_us);
+  CHECK(t.Sum(Stage::kFpLock) == 0);  // the cpu plugin has no connection
 }
 
 static void TestStoreInit() {
@@ -1239,6 +1267,7 @@ int main() {
   TestBinlogWriteReadResume();
   TestBinlogRotation();
   TestCpuDedup();
+  TestDedupRecordsIntoBoundStageTrace();
   TestStoreInit();
   TestTrunkAllocator();
   TestTrunkReserveAndCompaction();

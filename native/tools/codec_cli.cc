@@ -20,6 +20,9 @@
 //   fdfs_codec trace-json      (golden span-ring dump: fixed spans ->
 //                JSON, compared field-for-field against
 //                fastdfs_tpu.trace.decode_dump)
+//   fdfs_codec stage-line      (golden access-log "stages" line: a fixed
+//                two-segment upload's intervals -> StageLineJson,
+//                decoded by fastdfs_tpu.trace.decode_stage_line)
 //   fdfs_codec trace-ctx <hex32>  (parse a 16-byte TRACE_CTX body and
 //                print trace_id/parent/flags — wire-layout golden)
 //   fdfs_codec scrub-status    (golden SCRUB_STATUS blob: fixture value
@@ -334,6 +337,35 @@ int main(int argc, char** argv) {
     slow.SetName("tracker.query_store");
     ring.Record(slow);
     printf("%s\n", ring.Json("storage", 23000).c_str());
+    return 0;
+  }
+  if (cmd == "stage-line") {
+    // Fixed fixture — tests/test_trace.py decodes it with
+    // fastdfs_tpu.trace.decode_stage_line and checks every field.
+    StageTrace t;
+    t.Reset();
+    const int64_t t0 = 5000000;
+    auto put = [&](Stage s, int64_t off, int64_t dur, int parent,
+                   int64_t a0 = 0, int64_t a1 = 0) {
+      t.iv[t.n++] = StageTrace::Interval{
+          t0 + off, t0 + off + dur, {a0, a1}, s, static_cast<int8_t>(parent)};
+    };
+    put(Stage::kRecv, 0, 812, -1);
+    put(Stage::kDioWait, 812, 3, -1);
+    for (int seg = 0; seg < 2; ++seg) {
+      const int64_t base = 815 + seg * 3000;
+      const int fp = t.n + 1;
+      put(Stage::kReadback, base, 400, -1);
+      put(Stage::kFingerprint, base + 400, 2000, -1);
+      put(Stage::kCdc, base + 401, 500, fp);
+      put(Stage::kFpLock, base + 905, 2, fp);
+      put(Stage::kFpRpc, base + 910, 1480, fp, (1234LL << 32) | 7,
+          seg * 67108864LL);
+      put(Stage::kCsWrite, base + 2400, 600, -1);
+    }
+    put(Stage::kBinlog, 6815, 40, -1);
+    printf("%s\n", StageLineJson(t, 11, 0, t0, 1700000000000000LL, 6900)
+                       .c_str());
     return 0;
   }
   if (cmd == "trace-ctx" && argc == 3) {

@@ -38,6 +38,11 @@ def test_access_log_lines(tmp_path_factory):
     log_path = os.path.join(str(base), "logs", "access.log")
     assert os.path.exists(log_path)
     lines = open(log_path).read().strip().splitlines()
+    # a request that recorded stages is followed by its intervals, one
+    # compact JSON token (tests/test_stage_intervals.py reads those)
+    stage_lines = [ln for ln in lines if ln.startswith("{")]
+    assert stage_lines and all(" " not in ln for ln in stage_lines)
+    lines = [ln for ln in lines if not ln.startswith("{")]
     assert len(lines) >= 2  # upload + download
     # "<ts> <ip> <cmd> <status> <bytes> <cost_us> <recv_us> <work_us>
     #  <fp_us> <fp_lock_us> <cswrite_us> <binlog_us> <req_bytes>

@@ -431,7 +431,8 @@ def test_commit_assembles_each_segment_once(tmp_path, mode):
             stats: dict = {}
             fid = sc.upload_buffer_dedup(gen1, ext="bin", stats=stats)
             after = sc.stat()["counters"]
-            spans = {s.name: s for s in decode_dump(sc.trace_dump())}
+            dump = decode_dump(sc.trace_dump())
+            spans = {s.name: s for s in dump}
             assert stats["fallback"] == ""
             assert sc.download_to_buffer(fid) == gen1
         assert _tmp_files(base) == []       # nothing materialised, nothing left
@@ -449,13 +450,16 @@ def test_commit_assembles_each_segment_once(tmp_path, mode):
         assert in_slab > 100
         assert delta("ingest.commit_read_chunks") == in_slab
         assert 0 < delta("ingest.commit_read_batches") < in_slab // 4
-        # and the commit's span says the same of itself
-        present = spans["storage.commit.present"]
-        (reads,) = [s for n, s in spans.items()
-                    if n.startswith("ingest.commit_reads ")]
-        assert reads.parent_id == present.span_id
-        assert reads.name.split()[1] == "%d/%d" % (
-            in_slab, delta("ingest.commit_read_batches"))
+        # and the commit's spans say the same of themselves: one present
+        # span a segment, each with the reads it made
+        present = {s.span_id for s in dump
+                   if s.name == "storage.commit.present"}
+        reads = [s for s in dump if s.name.startswith("ingest.commit_reads ")]
+        assert len(present) == 3 and len(reads) == 3
+        assert {s.parent_id for s in reads} == present
+        assert [sum(int(s.name.split()[1].split("/")[i]) for s in reads)
+                for i in (0, 1)] == [in_slab,
+                                     delta("ingest.commit_read_batches")]
         assert ("storage.reindex" in spans) == (mode == "sidecar")
     finally:
         st.stop()

@@ -186,7 +186,9 @@ def test_one_marker_per_request_carries_its_counts(traced, cmd):
     assert len(markers) == 1
     got = markers[0][4]
     assert got["bytes"] == len(traced["payload"][cmd])
-    assert set(got) == {"bytes", "host_wall_us", "host_cpu_us"}
+    assert set(got) == {"bytes", "host_wall_us", "host_cpu_us", "mono_us"}
+    # the anchor: this host's CLOCK_MONOTONIC, read beside the marker
+    assert 0 < time.monotonic_ns() // 1000 - got["mono_us"] < 3600 * 10 ** 6
     assert traced["replies"][cmd][0] == 0
     assert 0 < got["host_cpu_us"] <= got["host_wall_us"] * 1.05 + 50
     assert len([e for e in traced["events"] if e[0] == MARKER]) == 2

@@ -355,8 +355,11 @@ def test_chunked_upload_shows_readback_and_cdc_inside_fingerprint(tmp_path):
         readback = by_name["storage.tmp_readback"]
         assert cdc.parent_id == fp.span_id and fp.parent_id == root.span_id
         assert readback.parent_id == root.span_id
-        assert cdc.start_us == fp.start_us and 0 < cdc.dur_us <= fp.dur_us
-        assert readback.start_us + readback.dur_us == fp.start_us
+        # intervals as they happened: the chunker starts inside the
+        # fingerprint call, which starts once the segment is read back
+        assert fp.start_us <= cdc.start_us and 0 < cdc.dur_us <= fp.dur_us
+        assert cdc.start_us + cdc.dur_us <= fp.start_us + fp.dur_us
+        assert readback.start_us + readback.dur_us <= fp.start_us
         assert by_name["dio.queue_wait"].start_us <= readback.start_us
     finally:
         storage.stop()      # flushes the access log

@@ -22,9 +22,21 @@ assembled segment) and ``recipe_us`` make up ``cswrite_us``;
 file's signature, the answer held against the client's recipe, and the
 fingerprint session's commit, all before the reply (0 in cpu mode).
 ``cdc_us`` is the native
-chunker's share of ``fp_us``; ``dio_wait_us`` (the wait in the dio queue)
-and ``readback_us`` (the tmp file read back before each fingerprint call)
-lie inside ``work_us``.  This tool answers the question the raw ingest rate
+chunker's share of ``fp_us`` and ``fp_lock_us`` the wait for a sidecar
+connection inside it (on an ``upload_chunks`` row, which has no ``fp_us``,
+both are shares of ``reindex_us``); ``dio_wait_us`` (the wait in the dio
+queue) and ``readback_us`` (the tmp file read back before each fingerprint
+call) lie inside ``work_us``.  Every stage column is the sum of the
+request's intervals of that stage; the intervals themselves follow the row
+as one compact-JSON line,
+
+    {"event":"stages","cmd":...,"status":...,"t0_mono_us":...,
+     "t0_wall_us":...,"dur_us":...,"truncated":0,
+     "spans":[[name,start_offset_us,dur_us,parent_index(,{args})],...]}
+
+which ``aggregate`` skips like the slow-request line and ``--timeline N``
+draws for the N slowest requests (``fastdfs_tpu.trace.logged_requests`` +
+``render_timeline``; OPERATIONS.md, "Tracing").  This tool answers the question the raw ingest rate
 can't: WHERE does an upload's time go — network receive, fingerprinting
 (and how much of that is queueing on the sidecar's serialized engine),
 chunk-store writes, or the binlog — the attribution SURVEY.md §3.1 marks
@@ -37,11 +49,12 @@ additionally interleaves one compact-JSON line per slow request:
      "span_id":...,"start_us":...,"dur_us":...,"status":...,"peer":...,
      "bytes":...}
 
-``aggregate`` skips those (a compact JSON line is a single token);
+``aggregate`` skips both kinds (a compact JSON line is a single token);
 ``slow_requests`` ingests them, and ``--slow`` renders them with the
 ``cli.py trace --trace-id`` command that drills into each one.
 
 Usage:  python tools/access_log_stages.py <access.log> [--json] [--slow]
+                                          [--timeline N]
 Import: ``aggregate(path) -> dict``; ``slow_requests(path) -> list[dict]``.
 """
 
@@ -142,7 +155,8 @@ def aggregate(path: str) -> dict:
             "stage_share": {},
         }
         if total_cost > 0:
-            # fp_lock and cdc are subsets of fp; work contains dio_wait +
+            # fp_lock and cdc are subsets of fp (of reindex on a commit,
+            # which has no fp); work contains dio_wait +
             # readback + fp + cswrite + binlog + negotiate + reindex;
             # cswrite contains present + verify + recipe (of a negotiated
             # commit it is their sum, so cs_write below is recipe_us).
@@ -157,18 +171,19 @@ def aggregate(path: str) -> dict:
             rb = d["readback_us"]
             neg, ri = d["negotiate_us"], d["reindex_us"]
             present, verify = d["present_us"], d["verify_us"]
+            in_reindex = 0 if fp else cdc + lock
             other_work = max(
                 d["work_us"] - fp - cs - bl - wait - rb - neg - ri, 0)
             pre = max(total_cost - d["recv_us"] - d["work_us"], 0)
             for name, v in [("recv", recv), ("dio_wait", wait),
                             ("tmp_readback", rb), ("fp_cdc", cdc),
-                            ("fp_rpc", fp - lock - cdc),
+                            ("fp_rpc", max(fp - lock - cdc, 0)),
                             ("fp_lock_wait", lock),
                             ("negotiate", neg),
                             ("commit_present", present),
                             ("commit_verify", verify),
                             ("cs_write", cs - present - verify),
-                            ("reindex", ri),
+                            ("reindex", max(ri - in_reindex, 0)),
                             ("binlog", bl), ("work_other", other_work),
                             ("dispatch_other", pre)]:
                 row["stage_share"][name] = round(v / total_cost, 4)
@@ -182,7 +197,23 @@ def main() -> int:
     ap.add_argument("--json", action="store_true", help="raw JSON output")
     ap.add_argument("--slow", action="store_true",
                     help="show the structured slow-request lines instead")
+    ap.add_argument("--timeline", type=int, metavar="N", default=0,
+                    help="draw the N slowest requests that logged stages")
     args = ap.parse_args()
+    if args.timeline:
+        import os
+        sys.path.insert(0, os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))))
+        from fastdfs_tpu import trace as T
+        spans = T.logged_requests(args.log, cmd_names=CMD_NAMES)
+        roots = sorted((s for s in spans if s.parent_id == 0),
+                       key=lambda s: -s.dur_us)[:args.timeline]
+        for root in roots:
+            print(T.render_timeline(spans, root.trace_id))
+        if not roots:
+            print("no stage lines (use_access_log on a daemon that "
+                  "writes them?)")
+        return 0
     if args.slow:
         slow = slow_requests(args.log)
         if args.json:
