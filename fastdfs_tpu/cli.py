@@ -1228,6 +1228,18 @@ def cmd_sidecar_trace(args: list[str]) -> int:
           + ", tiles_by_rows: "
           + (", ".join(f"{n} x {rows}" for rows, n in sorted(
               tiles.items(), key=lambda t: int(t[0])) if n) or "none"))
+    # the SHA-1 launches: blocks walked one after another (a tile under
+    # 128 rows as far as its longest chunk) of the blocks of the tiles'
+    # widths, and the rows that held a chunk of the lanes launched
+    launched = {k: after.get(k, 0) - before.get(k, 0) for k in (
+        "sha1_grid_steps", "sha1_width_steps", "rows_placed",
+        "lanes_launched")}
+    print(f"sha1 launches: sha1_grid_steps {launched['sha1_grid_steps']} of "
+          f"sha1_width_steps {launched['sha1_width_steps']}"
+          + (f" ({launched['sha1_grid_steps'] / launched['sha1_width_steps']:.3f}"
+             " of the widths walked)" if launched["sha1_width_steps"] else "")
+          + f", {launched['rows_placed']} rows on "
+          f"{launched['lanes_launched']} lanes")
     print(f"{'span':<28}{'n':>8}{'ms':>12}{'ms/MB':>10}")
     for name in sorted(after["span_us"]):
         n = after["span_n"][name] - before["span_n"].get(name, 0)

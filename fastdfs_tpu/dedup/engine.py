@@ -16,7 +16,9 @@ one byte bound (``_TILE_MAX_BYTES``: at chunk widths of megabytes a tile
 holds a few rows), chosen by what the request's buckets hold
 (``tile_plan``): the shapes are a fixed set that follows from the
 configured widths, all compiled in ``warmup()``, and a sparse bucket does
-not ship a full tile of zeros.
+not ship a full tile of zeros.  Where rows are megabytes a launch costs
+what its longest chunk costs, and the plan groups chunks by length so
+that a request's long chunks walk together.
 The file-level MinHash signature is the element-wise min over its chunks'
 signatures — exact for the union of their shingle sets (min of mins), so
 near-dup detection works at file granularity without rehashing the file.
@@ -148,6 +150,21 @@ _TILE_FIXED_BYTES = 4 << 20
 _TILE_MAX_BYTES = 64 << 20
 
 
+# What one 64-byte SHA-1 block costs a launch that walks it, in tile
+# bytes that cost as much: 0.72 us on the v5e whatever the lanes carry
+# (`%_sha1_rows_pallas.1` over the blocks its launches walked, PERF.md
+# section 5, bottleneck 1), at ``_TILE_FIXED_BYTES``' 0.39 ms per MB:
+# 0.72 us / 0.39 ns.  A byte walked costs 29 bytes shipped: at a width
+# of kilobytes a launch walks under a millisecond and the fixed cost
+# holds it; at megabytes the walk is what a plan pays.
+_WALK_BLOCK_BYTES = 1846
+
+# Rows from which a tile takes the lane-major SHA-1 kernel
+# (ops/pallas_sha1.py:LANE); under it the row-major one, whose launch
+# ends at its longest chunk.
+_LANE_ROWS = 128
+
+
 def _row_ladder(row_tile: int, blen: int) -> tuple[int, ...]:
     """Row counts a tile of width ``blen`` may have, largest first.  The
     full tile: ``row_tile`` rows, or as many as ``_TILE_MAX_BYTES`` holds
@@ -201,6 +218,48 @@ def _cheapest_tiles(n: int, blen: int, widths: list[int], row_tile: int
     return min(options)
 
 
+def _serial_width(row_tile: int, blen: int) -> bool:
+    """True where ``_TILE_MAX_BYTES`` cuts the full tile of width
+    ``blen`` under the lane-major kernel's rows: every tile of that
+    width is a few rows of megabytes on the row-major kernel (at
+    ``row_tile`` 256: 1 MiB and wider)."""
+    return _TILE_MAX_BYTES // blen < min(row_tile, _LANE_ROWS)
+
+
+def _walk_plan(lengths: list[int], widths: list[int], row_tile: int
+               ) -> list[tuple[int, int, list[int]]]:
+    """``tile_plan`` for a request that reaches the serial widths: its
+    chunks in order of length, a run of them to a tile, so a tile holds
+    chunks of like length and the long ones walk together.  A tile costs
+    its bytes, the fixed cost and the blocks its launch walks
+    (``launch_geometry``: under ``_LANE_ROWS`` rows its longest chunk's).
+    best[j]: the cheapest (cost, start, rows, width) whose last tile ends
+    the ``j`` shortest chunks; a tile of a rung takes as many chunks as
+    it has rows (one chunk fewer in it never makes the rest cheaper)."""
+    from fastdfs_tpu.ops.pallas_sha1 import launch_geometry
+
+    order = sorted(range(len(lengths)), key=lengths.__getitem__)
+    best: list[tuple[int, int, int, int]] = [(0, 0, 0, 0)]
+    for j in range(1, len(order) + 1):
+        longest = lengths[order[j - 1]]
+        options = []
+        for width in [w for w in widths if w >= longest] or [longest]:
+            for rows in _row_ladder(row_tile, width):
+                start = max(0, j - rows)
+                walked = launch_geometry(rows, width, longest)[1]
+                options.append((
+                    best[start][0] + _TILE_FIXED_BYTES + rows * width
+                    + walked * _WALK_BLOCK_BYTES, start, rows, width))
+        best.append(min(options))
+    plan = []
+    j = len(order)
+    while j:
+        _, start, rows, width = best[j]
+        plan.append((rows, width, order[start:j]))
+        j = start
+    return plan[::-1]
+
+
 def tile_plan(lengths, min_size: int, max_size: int, row_tile: int
               ) -> list[tuple[int, int, list[int]]]:
     """The tiles one request ships, as ``(rows, blen, chunk indices)``.
@@ -212,15 +271,36 @@ def tile_plan(lengths, min_size: int, max_size: int, row_tile: int
     their length and both kernels mask by ``lens``; a word's MinHash
     segment does not depend on the tile's width), so neighbouring
     buckets share tiles of the wider width where that is cheaper by
-    ``_tiles_cost``: shipped bytes plus a fixed cost a tile.  Every
-    chunk is placed once and row 0 of every tile is a real chunk.  Pure
-    arithmetic, no JAX.
+    ``_tiles_cost``: shipped bytes plus a fixed cost a tile.
+
+    A launch also costs the device the SHA-1 blocks it walks one after
+    another, and under 128 rows that is its longest chunk
+    (``launch_geometry``; ``_WALK_BLOCK_BYTES`` a block, from the v5e's
+    0.72 us).  Where a full tile has 128 rows and more a launch walks
+    kilobytes, under a millisecond, which the fixed cost as measured
+    already holds: those requests are planned by bytes alone, exactly as
+    PR 30 tuned them on the chip.  A request with a chunk in a bucket
+    whose full tile ``_TILE_MAX_BYTES`` cut under 128 rows
+    (``_serial_width``: an observable of the widths, 1 MiB and wider at
+    ``row_tile`` 256) is planned by ``_walk_plan`` with the walk priced
+    in: its chunks by length, a run of them to a tile, so the longest
+    chunks of narrower buckets fill the idle rows of a wider tile and a
+    request's long chunks walk together once, instead of each bucket
+    opening a launch as wide as its width.
+
+    Every chunk is placed once and row 0 of every tile is a real chunk;
+    every ``(rows, blen)`` is one of ``plan_shapes``.  Pure arithmetic,
+    no JAX call.
     """
+    lengths = list(lengths)
+    widths = _widths(min_size, max_size)
+    if lengths and _serial_width(
+            row_tile, _bucket_len(max(lengths), min_size, max_size)):
+        return _walk_plan(lengths, widths, row_tile)
     by_bucket: dict[int, list[int]] = {}
     for i, ln in enumerate(lengths):
         by_bucket.setdefault(_bucket_len(ln, min_size, max_size), []).append(i)
     blens = sorted(by_bucket)
-    widths = _widths(min_size, max_size)
     # best[j]: the cheapest (cost, groups) for the j narrowest buckets; a
     # group (i, j, width, row counts) ships buckets i..j-1 together.
     best: list[tuple[int, list]] = [(0, [])]
@@ -324,11 +404,14 @@ class DedupEngine:
         self.tiles_by_rows: dict[int, int] = {}
         # Every tile's SHA-1 launch, summed (the arguments of its
         # fdfs.engine.dispatch span): rows that held a chunk, the lanes
-        # the kernel's layout gives the tile, and the 64-byte blocks it
-        # walks one after another (ops/pallas_sha1.py:launch_geometry;
-        # the host path launches nothing and counts the same arithmetic).
+        # the kernel's layout gives the tile, the 64-byte blocks it walks
+        # one after another (under 128 rows: as far as its longest chunk)
+        # and the blocks of its width, which it walked before the launch
+        # ended early: walked / width is the share of that walk left
+        # (ops/pallas_sha1.py:launch_geometry; the host path launches
+        # nothing and counts the same arithmetic).
         self.launched = {"rows_placed": 0, "lanes_launched": 0,
-                         "sha1_grid_steps": 0}
+                         "sha1_grid_steps": 0, "sha1_width_steps": 0}
         self._placed_lock = threading.Lock()
 
     def _count_placed(self, result, row_bytes: int) -> None:
@@ -490,14 +573,17 @@ class DedupEngine:
                     off, ln = spans[i]
                     batch_buf[row, :ln] = arr[off:off + ln]
                     lens[row] = ln
-            lanes, blocks = launch_geometry(rows, blen)
+            lanes, width_blocks = launch_geometry(rows, blen)
+            _, blocks = launch_geometry(rows, blen, int(lens.max()))
             with span("fdfs.engine.dispatch", acc, rows=len(group),
-                      lanes=lanes, blen=blen, blocks=blocks):
+                      lanes=lanes, blen=blen, blocks=blocks,
+                      width_blocks=width_blocks):
                 d, s = self._fingerprint_batch(batch_buf, lens)
             with self._placed_lock:
                 self.launched["rows_placed"] += len(group)
                 self.launched["lanes_launched"] += lanes
                 self.launched["sha1_grid_steps"] += blocks
+                self.launched["sha1_width_steps"] += width_blocks
             slot_last[(size, slot)] = (d, s)
             by_rows.setdefault(rows, []).append(len(outs_d))
             outs_d.append(d)

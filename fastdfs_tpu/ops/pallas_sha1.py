@@ -23,7 +23,9 @@ takes a second layout, ``_sha1_rows_kernel``: lanes padded to 128 in HBM
 would multiply such a tile by 128 / rows, so its words stay row-major
 (chunk on the sublane axis, 128 words = 8 blocks a grid step) and the
 kernel transposes each (rows, 128) block in VMEM.  The rounds are the
-same code (``_compress``).
+same code (``_compress``).  Its launch ends at its longest chunk: rows
+of megabytes are few, a step costs the same whatever its lanes carry,
+and a tile's width is a pow2 above what its chunks need.
 
 Bit-exactness vs hashlib and vs the XLA reference is enforced by
 tests/test_pallas_kernels.py (interpret mode on CPU; the real kernel
@@ -152,11 +154,21 @@ def _sha1_rows_kernel(words_ref, nblocks_ref, state_ref, rows_ref, wt_ref):
 def _sha1_rows_pallas(words, nblocks, interpret: bool = False):
     """words: (rows, groups * 128) uint32, row-major as the packing pass
     leaves them, rows a multiple of 8 and at most 128; nblocks: (1, 128)
-    int32, chunk on the lane axis → state (5, 1, 128) uint32."""
+    int32, chunk on the lane axis → state (5, 1, 128) uint32.
+
+    The launch ends at its longest chunk: the grid's bound is the groups
+    that chunk has, a value computed here on the device and not a static
+    argument, so the program is one a shape whatever the lengths.  Every
+    step it leaves out had all lanes masked off.  (The other form, the
+    full grid with a scalar-prefetch operand, the index map clamped to
+    the last live group and the body under ``pl.when``, reads the same
+    bits and 0.05-0.09 us a skipped step on the v5e: PERF.md section 6,
+    PR 38.)"""
     rows, n_words = words.shape
+    live = -(-jnp.max(nblocks) // _GROUP)   # >= 1: an empty row has a block
     return pl.pallas_call(
         _sha1_rows_kernel,
-        grid=(n_words // LANE,),
+        grid=(live,),
         in_specs=[pl.BlockSpec((rows, LANE), lambda g: (0, g)),
                   pl.BlockSpec((1, LANE), lambda g: (0, 0))],
         out_specs=pl.BlockSpec((5, 1, LANE), lambda g: (0, 0, 0)),
@@ -173,13 +185,20 @@ def default_sub(rows: int) -> int:
     return max(1, min(DEFAULT_SUB, rows // LANE))
 
 
-def launch_geometry(rows: int, max_len: int) -> tuple[int, int]:
+def launch_geometry(rows: int, max_len: int, longest: int | None = None
+                    ) -> tuple[int, int]:
     """``(lanes, blocks)`` of one ``sha1_batch_pallas`` call at
     ``default_sub(rows)``: the lanes its rounds run over (the rows after
     the kernel's padding) and the 64-byte blocks it walks one after
-    another."""
+    another.  Under 128 rows the walk ends at the ``longest`` row's last
+    block, in whole groups of ``_GROUP``; the lane-major kernel walks the
+    width ``max_len`` whatever the rows hold, and so does either with no
+    ``longest`` given (what the walk was before it ended early: the
+    engine keeps both sums)."""
     max_blocks = (max_len + 8) // 64 + 1
     if rows < LANE:
+        if longest is not None:
+            max_blocks = (longest + 8) // 64 + 1
         return LANE, -(-max_blocks // _GROUP) * _GROUP
     tile = default_sub(rows) * LANE
     return -(-rows // tile) * tile, -(-rows // tile) * max_blocks

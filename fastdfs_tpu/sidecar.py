@@ -57,10 +57,12 @@ Opcodes
   and memory_peak_bytes, the most the fullest device has held), the
   chunk widths in force (``widths``) and the SHA-1 launches summed over
   every tile: ``rows_placed`` (rows that held a chunk),
-  ``lanes_launched`` (the rows after the kernel's padding) and
-  ``sha1_grid_steps`` (64-byte blocks walked one after another) — a
-  reader learns from it whether the chip did the work, which the
-  daemon's fail-open path would otherwise hide.
+  ``lanes_launched`` (the rows after the kernel's padding),
+  ``sha1_grid_steps`` (64-byte blocks walked one after another: a tile
+  under 128 rows ends at its longest chunk) and ``sha1_width_steps``
+  (the blocks of the tiles' widths: walked / width is the share of the
+  widths' walk that is left) — a reader learns from it whether the chip
+  did the work, which the daemon's fail-open path would otherwise hide.
   ``trace start <dir>`` / ``trace stop`` start and stop a JAX profiler
   trace of this process (the one that holds the chip) into ``<dir>``:
   device operations and the ``fdfs.*`` spans on one clock.  Both are

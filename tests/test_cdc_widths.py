@@ -14,6 +14,7 @@ program), hashlib, and the NumPy MinHash of ``test_dedup_engine.py``.
 """
 
 import hashlib
+import json
 import os
 import subprocess
 import sys
@@ -37,6 +38,13 @@ K, M = 1 << 10, 1 << 20
 NARROW = (4 * K, 13, 64 * K)
 WIDE = (16 * K, 15, 256 * K)
 RESTIC = (512 * K, 20, 8 * M)
+
+# What tile_plan of PR 38's parent made of seeded requests, as literal
+# plans (the file says how it was generated): the shipped widths' must stay
+# as they are, the restic widths' are what the walk-aware plan is held
+# against.
+with open(os.path.join(REPO, "tests", "goldens", "tile_plans_parent.json")) as f:
+    PARENT_PLANS = json.load(f)
 
 
 def _conf_text(widths) -> str:
@@ -192,6 +200,28 @@ def test_restic_widths_plan_tiles_under_the_byte_bound():
                for rows, blen in RESTIC_SHAPES)
 
 
+def _walked(plan, lens, early_stop=True) -> int:
+    """Σ SHA-1 blocks the plan's launches walk: a tile under 128 rows as
+    far as its longest chunk, or (the parent's kernel) every tile its
+    width."""
+    from fastdfs_tpu.ops.pallas_sha1 import launch_geometry
+    return sum(launch_geometry(
+        rows, blen, max(lens[i] for i in group) if early_stop else None)[1]
+        for rows, blen, group in plan)
+
+
+def _assert_long_chunks_walk_together(plan, lens, cfg):
+    """A request that reaches a width whose full tile the byte bound cut
+    under 128 rows is planned by length: each tile a run of the chunks in
+    order of length, so no chunk is longer than any chunk of a later
+    tile."""
+    if not engine_mod._serial_width(cfg.row_tile, engine_mod._bucket_len(
+            max(lens), cfg.min_size, cfg.max_size)):
+        return
+    for (_, _, a), (_, _, b) in zip(plan, plan[1:]):
+        assert max(lens[i] for i in a) <= min(lens[i] for i in b)
+
+
 @pytest.mark.parametrize(
     "widths", [(2 * K, 13, 64 * K), NARROW, WIDE, RESTIC, (M, 22, 8 * M)],
     ids=["shipped", "narrow", "wide", "restic", "borg_like"])
@@ -216,6 +246,72 @@ def test_tile_plan_never_emits_a_tile_over_the_byte_bound(widths):
             assert (rows, blen) in shapes
             assert 1 <= len(group) <= rows
             assert all(lens[i] <= blen for i in group)
+        _assert_long_chunks_walk_together(plan, lens, cfg)
+
+
+def test_serial_widths_are_those_whose_full_tile_the_byte_bound_cut():
+    """The observable the plan switches on: at row_tile 256 a full tile
+    has 128 rows and more up to 512 KiB, so no shipped width is one."""
+    assert not any(engine_mod._serial_width(256, blen)
+                   for _, blen in SHIPPED_SHAPES)
+    assert [blen for blen in (512 * K, M, 2 * M, 4 * M, 8 * M)
+            if engine_mod._serial_width(256, blen)] == [M, 2 * M, 4 * M, 8 * M]
+    # a small row_tile is not the byte bound's cut
+    assert not engine_mod._serial_width(64, 64 * K)
+
+
+@pytest.mark.parametrize("name", sorted(PARENT_PLANS["shipped"]["requests"]))
+def test_shipped_widths_plan_each_request_as_the_parent_did(name):
+    """PR 30 tuned these plans on the chip and three cells run them: the
+    walk's price must not move one tile of them."""
+    request = PARENT_PLANS["shipped"]["requests"][name]
+    cfg = DedupConfig()
+    assert [cfg.min_size, cfg.avg_bits, cfg.max_size] == (
+        PARENT_PLANS["shipped"]["widths"])
+    plan = tile_plan(request["lengths"], cfg.min_size, cfg.max_size,
+                     cfg.row_tile)
+    assert [[rows, blen, group] for rows, blen, group in plan] == (
+        request["plan"])
+
+
+@pytest.mark.parametrize("name", sorted(PARENT_PLANS["restic"]["requests"]))
+def test_restic_widths_plan_by_length_and_never_walk_more(name):
+    request = PARENT_PLANS["restic"]["requests"][name]
+    lens, parent = request["lengths"], request["plan"]
+    cfg = DedupConfig(min_size=RESTIC[0], avg_bits=RESTIC[1],
+                      max_size=RESTIC[2])
+    plan = tile_plan(lens, cfg.min_size, cfg.max_size, cfg.row_tile)
+    assert sorted(i for _, _, g in plan for i in g) == list(range(len(lens)))
+    for rows, blen, group in plan:
+        assert (rows, blen) in RESTIC_SHAPES
+        assert 1 <= len(group) <= rows          # row 0 is a real chunk
+        assert all(lens[i] <= blen for i in group)
+    _assert_long_chunks_walk_together(plan, lens, cfg)
+    if max(lens) <= 512 * K:    # no serial width reached: the plan before
+        assert [[rows, blen, group] for rows, blen, group in plan] == parent
+    assert _walked(plan, lens) <= _walked(parent, lens)
+    assert len(plan) <= len(parent)
+
+
+def test_restic_widths_walk_under_six_tenths_of_the_parents_blocks():
+    """Over segments of the cell's series (40, 56, 64, 8, 24 MiB; lengths
+    512 KiB + geometric(2^20) capped at 8 MiB): against the parent's plan
+    under the parent's kernel, which walked every tile's width."""
+    cfg = DedupConfig(min_size=RESTIC[0], avg_bits=RESTIC[1],
+                      max_size=RESTIC[2])
+    walked = width = was = shipped = shipped_was = 0
+    for request in PARENT_PLANS["restic"]["requests"].values():
+        lens = request["lengths"]
+        plan = tile_plan(lens, cfg.min_size, cfg.max_size, cfg.row_tile)
+        walked += _walked(plan, lens)
+        width += _walked(plan, lens, early_stop=False)
+        was += _walked(request["plan"], lens, early_stop=False)
+        shipped += sum(rows * blen for rows, blen, _ in plan)
+        shipped_was += sum(rows * blen for rows, blen, _ in request["plan"])
+    assert walked <= 0.60 * was
+    assert shipped <= 1.2 * shipped_was
+    # either half alone is not enough: the plan's tiles at their widths
+    assert width > 0.60 * was
 
 
 def test_engine_refuses_widths_no_tile_can_hold():
@@ -241,26 +337,39 @@ def test_warmup_compiles_exactly_the_shapes_of_the_widths(monkeypatch):
     assert seen == RESTIC_SHAPES
 
 
-def test_dispatch_counts_rows_lanes_and_blocks():
+@pytest.mark.parametrize("tile_bound", [None, 2 * M],
+                         ids=["plan_by_bytes", "plan_by_walk"])
+def test_dispatch_counts_rows_lanes_and_blocks(monkeypatch, tile_bound):
     """The sums `stats` reports, and the span's arguments, for one request
     at the wide widths: every chunk counted once, 128 lanes a tile under
-    128 rows, the blocks of each tile's width."""
+    128 rows, the blocks each launch walks (under 128 rows as far as its
+    longest chunk) and beside them the blocks of each tile's width.  Under
+    a tile bound of 2 MiB the same widths are serial ones (8 rows of
+    256 KiB fill a tile) and the request takes the walk-aware plan."""
     from fastdfs_tpu.ops.pallas_sha1 import launch_geometry
+    if tile_bound:
+        monkeypatch.setattr(engine_mod, "_TILE_MAX_BYTES", tile_bound)
     cfg = DedupConfig(min_size=WIDE[0], avg_bits=WIDE[1], max_size=WIDE[2],
                       use_pallas=False)
+    assert engine_mod._serial_width(cfg.row_tile, WIDE[2]) == bool(tile_bound)
     eng = DedupEngine(cfg)
     data = _seeded(2 * M, 38)
-    spans, _, _ = eng.fingerprint(data)
-    plan = tile_plan([ln for _, ln in spans], cfg.min_size, cfg.max_size,
-                     cfg.row_tile)
+    spans, digests, _ = eng.fingerprint(data)
+    raw = digests.astype(">u4").tobytes()
+    for i, (off, ln) in enumerate(spans):
+        assert raw[i * 20:(i + 1) * 20] == hashlib.sha1(
+            data[off:off + ln]).digest(), i
+    lens = [ln for _, ln in spans]
+    plan = tile_plan(lens, cfg.min_size, cfg.max_size, cfg.row_tile)
+    _assert_long_chunks_walk_together(plan, lens, cfg)
     geo = [launch_geometry(rows, blen) for rows, blen, _ in plan]
     assert eng.launched == {
         "rows_placed": len(spans),
         "lanes_launched": sum(lanes for lanes, _ in geo),
-        "sha1_grid_steps": sum(blocks for _, blocks in geo)}
+        "sha1_grid_steps": _walked(plan, lens),
+        "sha1_width_steps": sum(blocks for _, blocks in geo)}
+    assert eng.launched["sha1_grid_steps"] < eng.launched["sha1_width_steps"]
 
-
-# -- a daemon and a sidecar that disagree ---------------------------------------
 
 def test_sidecar_answers_widths_and_refuses_foreign_cuts(tmp_path, capsys):
     import struct

@@ -18,7 +18,7 @@ import pytest
 from fastdfs_tpu.ops.minhash import EMPTY, minhash_batch, survivor_segmin
 from fastdfs_tpu.ops.pallas_minhash import (minhash_batch_pallas,
                                             survivor_segmin_pallas)
-from fastdfs_tpu.ops.pallas_sha1 import sha1_batch_pallas
+from fastdfs_tpu.ops.pallas_sha1 import launch_geometry, sha1_batch_pallas
 from fastdfs_tpu.ops.sha1 import sha1_batch, sha1_hex
 
 
@@ -43,6 +43,50 @@ def test_sha1_pallas_matches_hashlib(n, L):
     for i in range(n):
         expect = hashlib.sha1(data[i, :lens[i]].tobytes()).hexdigest()
         assert sha1_hex(out[i]) == expect, i
+
+
+# The row-major kernel (a tile under 128 rows) ends its launch at its
+# longest row: (tile width, row lengths).  A width of 32,704 bytes is 512
+# blocks, 64 grid steps of 8; 503 bytes pad to 512, exactly one step, and
+# 504 to one block more.
+ROW_MAJOR_CASES = {
+    "one_row_of_3_blocks_in_64_groups": (32704, [150]),
+    "longest_ends_on_a_group_edge": (32704, [503, 200, 64]),
+    "longest_one_byte_over_a_group_edge": (32704, [504, 503, 1]),
+    "empty_trailing_rows": (32704, [1000, 77, 0, 0, 0]),
+    "every_row_empty_but_row_0": (32704, [300] + [0] * 7),
+    "nine_rows_longest_last": (32704, [5, 0, 64, 55, 56, 119, 120, 1, 2000]),
+    "longest_at_the_full_width": (4096, [4096, 17, 4095]),
+}
+
+
+@pytest.mark.parametrize("as_words", [False, True], ids=["bytes", "words"])
+@pytest.mark.parametrize("case", sorted(ROW_MAJOR_CASES))
+def test_sha1_row_major_launch_ends_at_its_longest_row(case, as_words):
+    width, lens = ROW_MAJOR_CASES[case]
+    rng = np.random.RandomState(len(case))
+    data = np.zeros((len(lens), width), np.uint8)
+    for i, ln in enumerate(lens):
+        data[i, :ln] = rng.randint(0, 256, ln)
+    arg = data.view(np.uint32) if as_words else data
+    out = np.asarray(sha1_batch_pallas(arg, np.array(lens, np.int32), width,
+                                       interpret=True))
+    for i, ln in enumerate(lens):
+        assert sha1_hex(out[i]) == hashlib.sha1(
+            data[i, :ln].tobytes()).hexdigest(), i
+
+
+def test_launch_geometry_walks_to_the_longest_row_under_128_rows():
+    M = 1 << 20
+    # the width's blocks, as before, where no longest row is given
+    assert launch_geometry(8, 8 * M) == (128, 131080)
+    # whole groups of 8 blocks: 503 bytes are one group, 504 two
+    assert launch_geometry(8, 32704, 503) == (128, 8)
+    assert launch_geometry(8, 32704, 504) == (128, 16)
+    assert launch_geometry(8, 8 * M, 4299161) == (128, 67176)
+    assert launch_geometry(32, 65536, 65536) == launch_geometry(32, 65536)
+    # the lane-major kernel walks its width whatever the rows hold
+    assert launch_geometry(256, 65536, 100) == (256, 1025)
 
 
 def test_sha1_pallas_matches_xla_reference():

@@ -161,10 +161,13 @@ def test_every_span_of_the_table_lies_inside_its_request(traced, cmd):
     # a dispatch carries its tile's SHA-1 launch (the benchmark's
     # sha1_lane_fill and sha1_serial_steps_per_MB sum these)
     launches = [e[4] for e in inside if e[0] == "fdfs.engine.dispatch"]
-    assert all(set(a) == {"rows", "lanes", "blen", "blocks"}
+    assert all(set(a) == {"rows", "lanes", "blen", "blocks", "width_blocks"}
                for a in launches)
+    # blocks: what the launch walked (under 128 rows as far as its longest
+    # chunk, in groups of 8), of width_blocks, the blocks of its width
     assert all(0 < a["rows"] <= a["lanes"] and a["lanes"] % 128 == 0
-               and a["blocks"] > a["blen"] // 64 for a in launches)
+               and 0 < a["blocks"] <= a["width_blocks"]
+               and a["width_blocks"] > a["blen"] // 64 for a in launches)
     if cmd == FP_CUTS:      # every one of the 600 chunks on one row
         assert sum(a["rows"] for a in launches) == 600
     assert all(set(e[4]) <= {"cmd", "bytes"} for e in inside
@@ -305,3 +308,8 @@ def test_cli_sidecar_trace_prints_the_deltas(traced):
     assert calls == bodies and "(1.00 a body)" in recv
     # the host path (--platform cpu) places no tile on a device
     assert "tiles_by_rows: none" in proc.stdout
+    # ... and counts its launches' arithmetic all the same
+    (sha1,) = [ln for ln in proc.stdout.splitlines()
+               if ln.startswith("sha1 launches:")]
+    walked, width = int(sha1.split()[3]), int(sha1.split()[6])
+    assert 0 < walked <= width and "of the widths walked)" in sha1

@@ -100,6 +100,42 @@ def test_sha1_pallas_compiles_for_v5e(one_chip, rows, blen):
     assert compiled.memory_analysis().temp_size_in_bytes <= 2.5 * rows * blen
 
 
+# The row-major shapes at both ends of both sets of widths (in TILES) and
+# the two the walk-aware plan ships most at the restic widths: the launch
+# ends at its longest row by a grid bound that is a run-time value, which
+# interpret mode accepts whatever Mosaic would say.
+_ROW_MAJOR = sorted({s for s in (_SMALL[0], _SMALL[-1], _WIDE[1], _WIDE[-1],
+                                 (32, 2 << 20), (16, 4 << 20))})
+
+
+@pytest.mark.parametrize("rows,blen", _ROW_MAJOR)
+def test_sha1_row_major_launch_bound_is_a_run_time_value(one_chip, rows,
+                                                         blen):
+    """One program a shape: the longest row is not a static argument, so
+    the lowering takes shapes alone, and Mosaic takes a kernel whose grid
+    is bounded by a value computed on the device."""
+    assert (rows, blen) in plan_shapes(CFG) + _WIDE and rows < 128
+    data, lens = _batch(rows, blen, one_chip, words=True)
+    jaxpr = jax.make_jaxpr(lambda d, n: sha1_batch_pallas(
+        d, n, max_len=blen, sub=default_sub(rows)))(data, lens)
+    (grid,) = _pallas_grids(jaxpr.jaxpr)
+    assert len(grid) == 1 and not isinstance(grid[0], int)
+    text = sha1_batch_pallas.lower(data, lens, max_len=blen,
+                                   sub=default_sub(rows)).compile().as_text()
+    assert "_sha1_rows_pallas" in text and "tpu_custom_call" in text
+
+
+def _pallas_grids(jaxpr):
+    """The grid of every pallas_call under ``jaxpr``."""
+    grids = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            grids.append(eqn.params["grid_mapping"].grid)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            grids += _pallas_grids(sub)
+    return grids
+
+
 @pytest.mark.parametrize("rows,blen", TILES)
 def test_minhash_pallas_compiles_for_v5e(one_chip, rows, blen):
     data, lens = _batch(rows, blen, one_chip, words=True)
