@@ -1170,8 +1170,9 @@ def cmd_group(c: FdfsClient, args: list[str]) -> int:
 def cmd_sidecar_trace(args: list[str]) -> int:
     """sidecar-trace <socket> --seconds N --out DIR: a JAX profiler trace
     of a running dedup sidecar (device operations and the ``fdfs.*`` spans
-    on one clock), and the spans' wall time and count and the receive
-    counters over those seconds from its ``stats`` reply.  Takes the
+    on one clock), and the spans' wall time and count, the receive
+    counters and the near-dup index's counters over those seconds from
+    its ``stats`` reply.  Takes the
     sidecar's socket, no tracker."""
     import time
 
@@ -1240,6 +1241,21 @@ def cmd_sidecar_trace(args: list[str]) -> int:
              " of the widths walked)" if launched["sha1_width_steps"] else "")
           + f", {launched['rows_placed']} rows on "
           f"{launched['lanes_launched']} lanes")
+    # the near-dup index on the device: what it holds, and the passes
+    # that answered these seconds' near_dups queries
+    near = {k: after.get(k, 0) - before.get(k, 0) for k in (
+        "near_queries", "near_scans", "near_scan_us", "near_inserts",
+        "near_removed")}
+    print(f"near-dup index: near_rows {after.get('near_rows', 0)} "
+          f"(near_base_rows {after.get('near_base_rows', 0)}) in "
+          f"near_resident_bytes {after.get('near_resident_bytes', 0)}; "
+          f"near_queries {near['near_queries']} in near_scans "
+          f"{near['near_scans']}"
+          + (f" ({near['near_queries'] / near['near_scans']:.2f} a pass, "
+             f"{near['near_scan_us'] / near['near_scans'] / 1e3:.2f} ms a "
+             "pass)" if near["near_scans"] else "")
+          + f", near_inserts {near['near_inserts']}, near_removed "
+          f"{near['near_removed']}")
     print(f"{'span':<28}{'n':>8}{'ms':>12}{'ms/MB':>10}")
     for name in sorted(after["span_us"]):
         n = after["span_n"][name] - before["span_n"].get(name, 0)

@@ -8,7 +8,7 @@ loop in the reference's ``storage/storage_dio.c:dio_write_file()``):
           ──SHA1 batch + MinHash batch (one jit per tile shape)──►
           digests + signatures
           ──exact index──► per-chunk write/skip verdicts
-          ──LSH index──► file-level near-duplicate candidates
+          ──near index (on the device)──► file-level near-duplicates
 
 Chunks are padded to power-of-two length buckets and shipped in tiles
 whose row count comes from a short ladder under ``row_tile`` and under
@@ -33,7 +33,8 @@ from dataclasses import dataclass, field
 
 import numpy as np
 
-from fastdfs_tpu.dedup.index import ExactDigestIndex, MinHashLSHIndex
+from fastdfs_tpu.dedup.index import ExactDigestIndex
+from fastdfs_tpu.dedup.near_index import DeviceNearIndex
 from fastdfs_tpu.dedup.spans import new_acc, span
 from fastdfs_tpu.ops import gear_cdc
 from fastdfs_tpu.ops.minhash import DEFAULT_PERMS, DEFAULT_SHINGLE, minhash_batch
@@ -79,6 +80,9 @@ class DedupConfig:
     # otherwise 1); 1 = single-device paths.  Every tile's row
     # count (plan_shapes) must divide by it.
     fan_out: int | None = None
+    # (rows, seed): the near-dup index starts with this many seeded rows
+    # on the device (near_index.py; the sidecar's --near-base).
+    near_base: tuple[int, int] | None = None
 
 
 @dataclass
@@ -352,8 +356,9 @@ class DedupEngine:
     """Stateful dedup engine: chunk, fingerprint, and judge byte streams.
 
     One engine per storage process.  Compute (CDC/SHA1/MinHash) runs on the
-    accelerator; index mutation stays on the host.  The verdicts gate disk
-    writes in the storage daemon (write unique chunks, reference dups).
+    accelerator; the exact index is the host's, the near-dup index the
+    device's (``near_index.py``).  The verdicts gate disk writes in the
+    storage daemon (write unique chunks, reference dups).
     """
 
     def __init__(self, config: DedupConfig | None = None) -> None:
@@ -362,12 +367,18 @@ class DedupEngine:
                                           gear_cdc.CDC_POLICY_SKIPMIN):
             raise ValueError(f"unknown cdc_policy {self.config.cdc_policy}")
         self.exact = ExactDigestIndex()
-        self.near = MinHashLSHIndex(self.config.num_perms, self.config.lsh_bands)
         use_pallas = self.config.use_pallas
         if use_pallas is None:
-            # The survivor kernel is specialized to the default shingle
-            # width; other widths take the (bit-identical) XLA reference.
-            use_pallas = _tpu_available() and self.config.shingle == 5
+            use_pallas = _tpu_available()
+        # The near-dup index's arrays are made by its first use (warmup,
+        # a commit), so an engine that is replaced by a loaded one
+        # (``load``) never held them.
+        self.near = DeviceNearIndex(self.config.num_perms,
+                                    self.config.lsh_bands,
+                                    self.config.near_base, use_pallas)
+        # The survivor kernel is specialized to the default shingle
+        # width; other widths take the (bit-identical) XLA reference.
+        use_pallas = use_pallas and self.config.shingle == 5
         fan = self.config.fan_out
         if fan is None:
             # Auto fan-out only where it pays: a multi-chip TPU host.  On
@@ -621,6 +632,7 @@ class DedupEngine:
             lens = np.ones(rows, dtype=np.int32)
             d, s = self._fingerprint_batch(batch, lens)
             np.asarray(d), np.asarray(s)
+        self.near.warmup()
 
     # -- stateful ingest ---------------------------------------------------
 
@@ -674,5 +686,6 @@ class DedupEngine:
              config: DedupConfig | None = None) -> "DedupEngine":
         eng = cls(config)
         eng.exact = ExactDigestIndex.load(exact_path)
-        eng.near = MinHashLSHIndex.load(near_path)
+        eng.near = DeviceNearIndex.load(near_path, eng.config.near_base,
+                                        eng.near.use_pallas)
         return eng

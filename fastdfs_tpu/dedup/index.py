@@ -287,6 +287,8 @@ class ExactDigestIndex:
 
 class MinHashLSHIndex:
     """Near-duplicate index: LSH band buckets over MinHash signatures.
+    The small-scale host reference of ``near_index.DeviceNearIndex``,
+    which is the one the engine serves from: same rows, same answers.
 
     ``num_perms = bands * rows``.  A query hashes each signature band;
     items sharing any band bucket become candidates, then the true
@@ -361,14 +363,18 @@ class MinHashLSHIndex:
             cand.update(self._buckets[b].get(key, ()))
         if not cand:
             return []
-        ids = np.fromiter(cand, dtype=np.int64)
+        # Live candidates in the order they were added, so that the stable
+        # sort leaves ties older row first: the rule the device index
+        # (near_index.py) ranks by too.
+        ids = np.array(sorted(i for i in cand if self._refs[i] is not None),
+                       dtype=np.int64)
+        if not len(ids):
+            return []
         sigs = self.signatures
         scores = (sigs[ids] == sig[None, :]).mean(axis=1, dtype=np.float32)
-        order = np.argsort(-scores)[:top_k]
+        order = np.argsort(-scores, kind="stable")[:top_k]
         return [(self._refs[int(ids[i])], float(scores[i]))
-                for i in order
-                if scores[i] >= min_similarity
-                and self._refs[int(ids[i])] is not None]
+                for i in order if scores[i] >= min_similarity]
 
     def remove(self, ref: Any) -> int:
         """Tombstone every item carrying ``ref`` (deleted file); queries

@@ -37,6 +37,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
+from fastdfs_tpu.dedup.near_index import band_scores
 from fastdfs_tpu.ops.gear_cdc import GEAR_TABLE, WINDOW
 from fastdfs_tpu.ops.minhash import (EMPTY, _perm_constants, minhash_batch,
                                      survivor_segmin)
@@ -118,9 +119,11 @@ def make_ingest_step(mesh: Mesh, num_perms: int = 64, avg_bits: int = 13,
 
         # ---- stage 4: dp-sharded index query + global pmax --------------
         # index_sigs local: (M_loc, P); score all N queries vs my shard.
-        eq = sigs[:, None, :] == index_sigs[None, :, :]          # (N, M_loc, P)
-        scores = eq.mean(axis=2, dtype=jnp.float32)
-        local_best = jnp.max(scores, axis=1, initial=0.0)        # 0.0 if M_loc==0
+        # The served index's scoring function (lane-major: a shard's rows
+        # as columns); the agreeing lanes over P is the similarity.
+        counts, _ = band_scores(index_sigs.T, sigs, bands=1)     # (N, M_loc)
+        local_best = jnp.max(counts.astype(jnp.float32) / num_perms,
+                             axis=1, initial=0.0)                # 0.0 if M_loc==0
         best = jax.lax.pmax(local_best, "dp")                    # (N,)
         return cand, digests, sigs, best
 

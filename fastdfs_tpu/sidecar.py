@@ -71,9 +71,15 @@ Opcodes
   socket is trusted: whoever may connect may already ``forget`` a file,
   and may have a profile written wherever this process may write.
 * ``DEDUP_NEARDUPS`` (123): body = file id text.  Response: ranked text
-  lines ``<file_id> <score>`` from the MinHash/LSH index (the operator
-  query surface behind the daemon's ``NEAR_DUPS`` command); status 61
-  when the file carries no signature.
+  lines ``<file_id> <score>`` from the near-dup index on the device
+  (``dedup/near_index.py``; behind the daemon's ``NEAR_DUPS`` command):
+  score descending, ties older file first, exact over every row
+  acknowledged before the query was sent.  The query runs outside
+  ``_lock`` and joins the index's next pass, which answers every query
+  waiting when it starts.  Status 61 when the file carries no signature.
+  ``stats`` reports the index as ``near_queries``, ``near_scans``,
+  ``near_scan_us``, ``near_inserts``, ``near_removed`` (counters) and
+  ``near_rows``, ``near_base_rows``, ``near_resident_bytes`` (gauges).
 * ``DEDUP_VERIFY`` (136): batched chunk-integrity verify for the storage
   scrubber (``native/storage/scrub.cc``).  Body = 8B count + per chunk
   (8B length + 20B expected raw SHA1) + payloads concatenated; response
@@ -87,9 +93,11 @@ Opcodes
   daemon path: chunking stays on the CPU (AVX2, identical cut points),
   the accelerator round-trip only carries the hash work.
 
-State: whole-file digest map + the DedupEngine's exact/LSH indexes;
-snapshotted to ``<state_dir>/sidecar_*.json`` on SIGTERM and every
-``--snapshot-interval`` seconds.
+State: whole-file digest map + the DedupEngine's exact and near-dup
+indexes; snapshotted to ``<state_dir>/sidecar_*`` on SIGTERM and every
+``--snapshot-interval`` seconds.  The near-dup snapshot holds the rows
+this process indexed and the spec of its ``--near-base``, never the base:
+nothing is copied off the device.
 
 Run: ``python -m fastdfs_tpu.sidecar --socket /path/dedup.sock``.
 """
@@ -178,6 +186,14 @@ def parse_widths(text: str) -> tuple[int, int, int]:
     if len(parts) != 3:
         raise ValueError(f"widths {text!r}: want <min>:<avg_bits>:<max>")
     return parse_bytes(parts[0]), int(parts[1]), parse_bytes(parts[2])
+
+
+def parse_near_base(text: str) -> tuple[int, int]:
+    """``<rows>:<seed>`` of ``--near-base`` → (rows, seed)."""
+    rows, _, seed = text.partition(":")
+    if not (rows.isdigit() and seed.isdigit()):
+        raise ValueError(f"near base {text!r}: want <rows>:<seed>")
+    return int(rows), int(seed)
 
 
 def _cuts_cover(ends: np.ndarray, n: int) -> bool:
@@ -480,7 +496,8 @@ class DedupSidecar:
             fid = self.files.get(sha1_hex)
         return 0, fid.encode() if fid else b""
 
-    def _commit(self, body: bytes) -> tuple[int, bytes]:
+    def _commit(self, body: bytes, acc: dict | None = None
+                ) -> tuple[int, bytes]:
         text = body.decode("utf-8", "replace")
         parts = text.split()
         if not parts:
@@ -496,31 +513,25 @@ class DedupSidecar:
                   "fingerprinted for it (set storage.conf dedup_cdc_widths "
                   "and --cdc-widths alike)", flush=True)
             return 22, why.encode()
-        with self._lock:
-            if parts[0] == "commitfile" and len(parts) == 3:
-                self.files.setdefault(parts[1], parts[2])
-                self.by_file[parts[2]] = parts[1]
-                return 0, b""
-            if parts[0] == "commitchunks" and len(parts) == 3:
+        # The near-dup index has a lock of its own (a write is a dispatch
+        # to the device): its row is written, and a tombstone set, after
+        # _lock is let go and before the daemon is answered, so every
+        # query sent after the acknowledgement sees it.
+        if parts[0] == "commitchunks" and len(parts) == 3:
+            with self._lock:
                 sess = self._sessions.pop(_parse_session(parts[1]), None)
                 if sess is not None:
-                    file_id = parts[2]
                     for dig, off in sess.digests:
-                        self.engine.exact.insert(dig, [file_id, off])
-                    if sess.sig is not None:
-                        self.engine.near.add(sess.sig, file_id)
-                return 0, b""
-            if parts[0] == "stats" and len(parts) == 1:
-                return 0, json.dumps({**self.stats,
-                                      **self.device_info()}).encode()
-            if parts[0] == "abort" and len(parts) == 2:
-                self._sessions.pop(_parse_session(parts[1]), None)
-                return 0, b""
-            if parts[0] == "forget" and len(parts) == 2:
+                        self.engine.exact.insert(dig, [parts[2], off])
+            if sess is not None and sess.sig is not None:
+                self._near_update(self.engine.near.add, sess.sig, parts[2],
+                                  acc=acc)
+            return 0, b""
+        if parts[0] == "forget" and len(parts) == 2:
+            with self._lock:
                 sha1 = self.by_file.pop(parts[1], None)
                 if sha1 is not None and self.files.get(sha1) == parts[1]:
                     del self.files[sha1]
-                self.engine.near.remove(parts[1])
                 # Exact attributions for the deleted file leave the index
                 # too (they would otherwise accumulate in RAM + snapshots
                 # forever).  The daemon's ChunkStore owns true chunk
@@ -530,8 +541,33 @@ class DedupSidecar:
                 # vectorized pass over the index's carrier column — no
                 # per-file digest-list side table in RAM.
                 self.engine.exact.remove_by_carrier(parts[1])
+            self._near_update(self.engine.near.remove, parts[1])
+            return 0, b""
+        with self._lock:
+            if parts[0] == "commitfile" and len(parts) == 3:
+                self.files.setdefault(parts[1], parts[2])
+                self.by_file[parts[2]] = parts[1]
+                return 0, b""
+            if parts[0] == "stats" and len(parts) == 1:
+                return 0, json.dumps({**self.stats,
+                                      **self.engine.near.stats(),
+                                      **self.device_info()}).encode()
+            if parts[0] == "abort" and len(parts) == 2:
+                self._sessions.pop(_parse_session(parts[1]), None)
                 return 0, b""
         return 22, b""
+
+    @staticmethod
+    def _near_update(update, *args, **kw) -> None:
+        """``near.add`` / ``near.remove`` for a commit or a forget.  The
+        file is stored and exactly deduplicated whatever happens here (a
+        device too full to grow the index on): it is only not found by
+        ``near_dups``, and the log says so."""
+        try:
+            update(*args, **kw)
+        except Exception as e:  # noqa: BLE001 — the commit still answers
+            print(f"dedup sidecar: near-dup index not updated for "
+                  f"{args[-1]} ({type(e).__name__}: {e})", flush=True)
 
     @staticmethod
     def _trace(args: list[str]) -> tuple[int, bytes]:
@@ -620,24 +656,25 @@ class DedupSidecar:
         raw = digest_bytes(sha1_batch(batch, lens))
         return [raw[i * 20:(i + 1) * 20] for i in range(len(chunks))]
 
-    def _neardups(self, body: bytes) -> tuple[int, bytes]:
+    def _neardups(self, body: bytes, acc: dict | None = None
+                  ) -> tuple[int, bytes]:
         """Ranked near-dup report for a stored file id (the production
-        query surface for the LSH index; without it the index is
+        query surface for the near-dup index; without it the index is
         write-only).  Status 61 (ENODATA) when the file is unknown to the
-        near index — flat, whole-file-deduped, or never committed."""
+        near index — flat, whole-file-deduped, or never committed.  Never
+        under ``_lock``: the query waits for the index's next pass."""
         file_id = body.decode("utf-8", "replace").strip()
         if not file_id:
             return 22, b""
-        with self._lock:
-            sig = self.engine.near.signature_of(file_id)
-            if sig is None:
-                return 61, b""
-            cfg = self.engine.config
-            pairs = self.engine.near.query(
-                sig, top_k=cfg.near_dup_top_k * 2 + 1,
-                min_similarity=cfg.near_dup_threshold)
+        sig = self.engine.near.signature_of(file_id)
+        if sig is None:
+            return 61, b""
+        cfg = self.engine.config
+        pairs = self.engine.near.query(
+            sig, top_k=cfg.near_dup_top_k * 2 + 1,
+            min_similarity=cfg.near_dup_threshold, acc=acc)
         lines = [f"{ref} {score:.4f}" for ref, score in pairs
-                 if ref != file_id][:self.engine.config.near_dup_top_k * 2]
+                 if ref != file_id][:cfg.near_dup_top_k * 2]
         return 0, "\n".join(lines).encode()
 
     def _reap_stale_sessions(self) -> None:
@@ -670,9 +707,9 @@ class DedupSidecar:
             if cmd == StorageCmd.DEDUP_QUERY:
                 return self._query(body)
             if cmd == StorageCmd.DEDUP_COMMIT:
-                return self._commit(body)
+                return self._commit(body, acc)
             if cmd == StorageCmd.DEDUP_NEARDUPS:
-                return self._neardups(body)
+                return self._neardups(body, acc)
             if cmd == StorageCmd.DEDUP_VERIFY:
                 # the scrubber's batch: background work on the same chip
                 with span("fdfs.sidecar.verify", acc):
@@ -845,6 +882,14 @@ def main(argv: list[str] | None = None) -> int:
                     help="shard each fingerprint batch's rows over this "
                          "many local devices (default: auto — all local "
                          "devices on a multi-chip TPU host, else 1)")
+    ap.add_argument("--near-base", type=parse_near_base, default=None,
+                    metavar="ROWS:SEED",
+                    help="start the near-dup index with ROWS seeded "
+                         "signatures made on the device (refs base/<row>): "
+                         "the node at the size it will hold, before it "
+                         "holds it (OPERATIONS.md, Device memory).  A "
+                         "snapshot written over another base is refused at "
+                         "load.")
     args = ap.parse_args(argv)
 
     import jax
@@ -873,16 +918,19 @@ def main(argv: list[str] | None = None) -> int:
     min_size, avg_bits, max_size = args.cdc_widths
     config = DedupConfig(min_size=min_size, avg_bits=avg_bits,
                          max_size=max_size, cdc_policy=args.cdc_policy,
-                         fan_out=args.fan_out)
+                         fan_out=args.fan_out, near_base=args.near_base)
     sidecar = DedupSidecar(args.socket, state_dir=args.state_dir,
                            config=config)
     signal.signal(signal.SIGTERM, lambda *_: sidecar.stop())
     signal.signal(signal.SIGINT, lambda *_: sidecar.stop())
     t0 = time.monotonic()
     sidecar.engine.warmup()  # compile all shapes BEFORE accepting traffic
+    near = sidecar.engine.near.stats()
     print(f"dedup sidecar warmed in {time.monotonic() - t0:.1f}s at chunk "
-          f"widths {sidecar._widths_text()}, listening on {args.socket}",
-          flush=True)
+          f"widths {sidecar._widths_text()}, near-dup index of "
+          f"{near['near_rows']} rows ({near['near_base_rows']} base) in "
+          f"{near['near_resident_bytes'] / 1e6:.0f} MB on the device, "
+          f"listening on {args.socket}", flush=True)
     sidecar.serve_forever(snapshot_interval=args.snapshot_interval)
     return 0
 

@@ -212,6 +212,25 @@ def test_lsh_remove_tombstones_all_items_of_ref():
     assert (idx.signature_of("dup-file") == sigs[5]).all()
 
 
+def test_lsh_query_orders_ties_older_row_first_and_ranks_live_rows_only():
+    """The rule both near indexes answer by: score descending, ties in the
+    order the rows were added; a tombstone takes no place among the top."""
+    rng = np.random.RandomState(10)
+    idx = MinHashLSHIndex(64, 16)
+    root = rng.randint(1, 2**32, 64).astype(np.uint32)
+    half = root.copy()
+    half[32:] = rng.randint(1, 2**32, 32).astype(np.uint32)
+    for ref, sig in (("h9", half), ("full-b", root), ("h2", half),
+                     ("gone", root), ("h5", half), ("full-a", root)):
+        idx.add(sig, ref)
+    idx.remove("gone")
+    want = [("full-b", 1.0), ("full-a", 1.0), ("h9", 0.5), ("h2", 0.5),
+            ("h5", 0.5)]
+    for _ in range(3):          # not a set's iteration order: every time
+        assert idx.query(root, top_k=10, min_similarity=0.5) == want
+    assert idx.query(root, top_k=3, min_similarity=0.5) == want[:3]
+
+
 def test_lsh_remove_roundtrips_through_snapshot(tmp_path):
     rng = np.random.RandomState(10)
     idx = MinHashLSHIndex(64, 16)

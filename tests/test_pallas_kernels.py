@@ -150,3 +150,33 @@ def test_engine_batch_dispatch_paths_agree():
     assert np.array_equal(d_ref, d2)
     assert np.array_equal(s_ref, s2)
 
+
+
+@pytest.mark.parametrize("n_q", [1, 8])
+def test_near_scan_pallas_nominates_the_blocks_its_xla_twin_does(n_q):
+    from fastdfs_tpu.ops.pallas_near_scan import (BLOCK, LANES,
+                                                  near_scan_pallas,
+                                                  near_scan_xla)
+    rng = np.random.RandomState(21)
+    blocks = 3
+    sigs = rng.randint(0, 2**32, (64, blocks * BLOCK), dtype=np.uint64
+                       ).astype(np.uint32)
+    queries = rng.randint(0, 2**32, (n_q, 64), dtype=np.uint64
+                          ).astype(np.uint32)
+    # plant: a whole band of query 0 in block 0 (first row) and block 2
+    # (last row), three lanes of a band (no candidate) in block 1, and one
+    # band per later query at a row of its own
+    sigs[20:24, 0] = queries[0, 20:24]
+    sigs[0:4, 3 * BLOCK - 1] = queries[0, 0:4]
+    sigs[8:11, BLOCK + 77] = queries[0, 8:11]
+    for q in range(1, n_q):
+        sigs[4 * q:4 * q + 4, (q % blocks) * BLOCK + 1000 * q] = \
+            queries[q, 4 * q:4 * q + 4]
+    sigs_t = sigs.reshape(64, -1, LANES)
+    want = np.asarray(near_scan_xla(sigs_t, queries))
+    got = np.asarray(near_scan_pallas(sigs_t, queries, interpret=True))
+    assert got.shape == (n_q, blocks) and got.dtype == np.bool_
+    assert np.array_equal(got, want)
+    assert want[0].tolist() == [True, False, True]
+    for q in range(1, n_q):
+        assert want[q].tolist() == [b == q % blocks for b in range(blocks)]

@@ -296,6 +296,11 @@ def test_cli_sidecar_trace_prints_the_deltas(traced):
     assert float(rows["fdfs.engine.fingerprint"][2]) > 0
     assert "MB fingerprinted" in proc.stdout
     assert "device memory peak" in proc.stdout
+    # the near-dup index's gauges and counters (nothing asked it here)
+    (near,) = [ln for ln in proc.stdout.splitlines()
+               if ln.startswith("near-dup index:")]
+    assert "near_rows" in near and "near_resident_bytes" in near
+    assert "near_queries 0 in near_scans 0" in near
     # ... all of them a re-index here, told apart from uploads
     (reidx,) = [ln for ln in proc.stdout.splitlines()
                 if ln.startswith("of them re-index:")]

@@ -145,6 +145,53 @@ def test_minhash_pallas_compiles_for_v5e(one_chip, rows, blen):
     assert compiled.memory_analysis().temp_size_in_bytes <= 2.5 * rows * blen
 
 
+@pytest.mark.parametrize("n_q", [1, 8])
+def test_near_scan_pallas_compiles_for_v5e_at_a_nodes_size(one_chip, n_q):
+    """The scan over 30M rows and their eighth to spare (8.64 GB): the
+    kernel is there and the pass needs no temporary beside the matrix
+    (XLA's own fusion of the same comparison needs 5.7 GB at one query
+    and more than the chip holds at eight: PERF.md section 6, PR 39)."""
+    from fastdfs_tpu.dedup.near_index import DeviceNearIndex, _programs
+    from fastdfs_tpu.ops.pallas_near_scan import LANES
+
+    capacity = DeviceNearIndex._capacity_for(30_000_000 + 30_000_000 // 8)
+    sigs = jax.ShapeDtypeStruct((64, capacity // LANES, LANES), jnp.uint32,
+                                sharding=one_chip)
+    queries = jax.ShapeDtypeStruct((n_q, 64), jnp.uint32, sharding=one_chip)
+    compiled = _programs(16, True)["scan"].lower(sigs, queries).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes >= capacity * 256
+    assert mem.temp_size_in_bytes <= 256 << 20
+
+
+def test_near_rank_and_insert_compile_for_v5e_at_a_nodes_size(one_chip):
+    """The rank program gathers its blocks by slices (a gather copied the
+    matrix: 2.2 GB of temporaries), and an insert writes in place."""
+    from fastdfs_tpu.dedup.near_index import (RANK_BLOCKS, DeviceNearIndex,
+                                              _programs)
+    from fastdfs_tpu.ops.pallas_near_scan import LANES
+
+    capacity = DeviceNearIndex._capacity_for(30_000_000 + 30_000_000 // 8)
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    sigs = arg((64, capacity // LANES, LANES), jnp.uint32)
+    live = arg((capacity // LANES, LANES), jnp.bool_)
+    prog = _programs(16, True)
+    rank = prog["rank"].lower(sigs, live, arg((8, 64), jnp.uint32),
+                              arg((8,), jnp.int32),
+                              arg((RANK_BLOCKS,), jnp.int32),
+                              arg((), jnp.int32)).compile()
+    assert rank.memory_analysis().temp_size_in_bytes <= 256 << 20
+    insert = prog["insert"].lower(sigs, live, arg((64,), jnp.uint32),
+                                  arg((), jnp.int32),
+                                  arg((), jnp.bool_)).compile()
+    mem = insert.memory_analysis()
+    assert mem.alias_size_in_bytes >= capacity * 256     # donated, in place
+    assert mem.temp_size_in_bytes <= 1 << 20
+
+
 def test_packed_concat_compiles_for_v5e(one_chip):
     rows = [ROWS] + [r for r, _ in _SMALL[-2:]]  # one segment's mixed tiles
     digests = [jax.ShapeDtypeStruct((r, 5), jnp.uint32, sharding=one_chip)
