@@ -21,14 +21,21 @@ with the query; its score is the count of agreeing lanes over
 ordered by score descending, ties older row first, and the best ``top_k``
 are returned.  Exact: every row is read by every pass.
 
-A pass is two programs.  ``fdfs_near_scan`` reads the whole matrix once
-and reduces it to one bit a query a block of ``BLOCK`` rows: does the
-block hold a candidate (``ops/pallas_near_scan.py``: a Pallas kernel on
-the TPU, the same function in ``jax.numpy`` elsewhere).  Candidates are
-rare, so ``fdfs_near_rank`` then gathers only the nominated blocks,
-``RANK_BLOCKS`` at a time, drops what is not live or under the threshold,
-scores with ``band_scores`` and ranks; the host merges the chunks' lists.
-One pass answers every query that was waiting when it started (``query``
+A pass is one program, ``fdfs_near_scan``: one dispatch, one fetch.  It
+reads the whole matrix once and reduces it to one bit a query a block of
+``BLOCK`` rows: does the block hold a candidate (``ops/pallas_near_scan.py``:
+a Pallas kernel on the TPU, the same function in ``jax.numpy`` elsewhere).
+Candidates are rare, so the same program then picks the first
+``RANK_BLOCKS`` nominated blocks (ascending), gathers only those, drops
+what is not live or under the threshold, scores with ``band_scores`` and
+ranks; what comes back is one array of the keys, the blocks and the count
+of nominated blocks, so the chip never waits for the host between the
+scan and the rank.  Where more blocks were nominated than that (rare: a
+query's family spread over the index), the host fetches the scan's
+bitmap and ranks the rest with ``fdfs_near_rank`` alone, ``RANK_BLOCKS``
+at a time: the pass *spills* (``stats`` ``near_rank_spills``, the
+``fdfs.near.scan`` span's ``spilled``).  The host merges the lists.  One
+pass answers every query that was waiting when it started (``query``
 joins a batch; the scanner thread is started by the first query, so a
 process that is never asked compiles and runs none of this).
 
@@ -132,15 +139,14 @@ def _programs(bands: int, use_pallas: bool):
 
     from fastdfs_tpu.ops import pallas_near_scan
 
-    def fdfs_near_scan(sigs_t, queries):
-        scan = (pallas_near_scan.near_scan_pallas if use_pallas
-                else pallas_near_scan.near_scan_xla)
-        return scan(sigs_t, queries, bands=bands)
-
-    def fdfs_near_rank(sigs_t, live, queries, least, blocks, limit):
-        # blocks: ascending block numbers, -1 for none; rows from `limit`
-        # on were written after the scan began and are not this pass's
+    def fdfs_near_rank(sigs_t, live, asked, blocks):
+        # asked: DeviceNearIndex._asked's; blocks: ascending block
+        # numbers, -1 for none.  Rows from the limit on were written after
+        # the pass was dispatched and are not its own.
         perms = sigs_t.shape[0]
+        queries = asked[:, :perms]
+        least = asked[:, perms].astype(jnp.int32)
+        limit = asked[0, perms + 1].astype(jnp.int32)
         at = jnp.maximum(blocks, 0)
         n = blocks.shape[0] * BLOCK
         # one dynamic slice a block (a gather would copy the matrix)
@@ -160,6 +166,20 @@ def _programs(bands: int, use_pallas: bool):
         key = jnp.where(hit, counts * n + (n - 1 - jnp.arange(n))[None, :],
                         -1)
         return jax.lax.top_k(key, MAX_TOP_K)[0]
+
+    def fdfs_near_scan(sigs_t, live, asked):
+        # the whole pass: the scan, its first RANK_BLOCKS nominated blocks
+        # found on the device, and their rank; one array comes back
+        scan = (pallas_near_scan.near_scan_pallas if use_pallas
+                else pallas_near_scan.near_scan_xla)
+        blocks = scan(sigs_t, asked[:, :sigs_t.shape[0]], bands=bands)
+        nominated = blocks.any(axis=0)
+        (first,) = jnp.nonzero(nominated, size=RANK_BLOCKS, fill_value=-1)
+        first = first.astype(jnp.int32)
+        keys = fdfs_near_rank(sigs_t, live, asked, first)
+        return jnp.concatenate(
+            [keys.reshape(-1), first,
+             nominated.sum(dtype=jnp.int32)[None]]), blocks
 
     def fdfs_near_insert(sigs_t, live, col, row, alive):
         at = (row // LANES, row % LANES)
@@ -248,8 +268,8 @@ class DeviceNearIndex:
         # dispatch only, never for its wait.
         self._lock = threading.Lock()
         self.counters = {"near_queries": 0, "near_scans": 0,
-                         "near_scan_us": 0, "near_inserts": 0,
-                         "near_removed": 0}
+                         "near_scan_us": 0, "near_rank_spills": 0,
+                         "near_inserts": 0, "near_removed": 0}
         self._queue: list[_Waiting] = []
         self._wake = threading.Condition()
         self._scanner: threading.Thread | None = None
@@ -313,9 +333,12 @@ class DeviceNearIndex:
         if self.base_rows:
             probe = base_rows(self.base_seed, 0, 1, self.num_perms)[0]
             for q in QUERY_LADDER:
-                self._pass([_Waiting(probe, 1, self.num_perms + 1,
-                                     new_acc()) for _ in range(q)],
-                           counted=False)
+                batch = [_Waiting(probe, 1, self.num_perms + 1, new_acc())
+                         for _ in range(q)]
+                self._pass(batch, counted=False)
+                # and the spill's rank program, which this pass needs not
+                self._rank(self._asked(batch, 0),
+                           np.full(RANK_BLOCKS, -1, np.int32))
 
     # -- MinHashLSHIndex's surface -----------------------------------------------
 
@@ -441,45 +464,66 @@ class DeviceNearIndex:
             for w in batch:
                 w.done.set()
 
+    def _asked(self, batch: list[_Waiting], held: int) -> np.ndarray:
+        """What a pass's programs take of ``batch``, in one array (one
+        transfer): ``(width, P + 2)`` uint32 at the batch's
+        ``QUERY_LADDER`` width, a query's lanes, then its least count,
+        then ``held`` (the rows the pass may return lie under it).  A
+        padding query asks for more lanes than there are: no hit."""
+        width = next(q for q in QUERY_LADDER if q >= len(batch))
+        perms = self.num_perms
+        asked = np.empty((width, perms + 2), np.uint32)
+        asked[:, :perms] = batch[0].sig
+        asked[:, perms] = perms + 1
+        for i, w in enumerate(batch):
+            asked[i, :perms] = w.sig
+            asked[i, perms] = w.least
+        asked[:, perms + 1] = held
+        return asked
+
+    def _rank(self, asked: np.ndarray, chunk: np.ndarray):
+        """The rank program alone, over the blocks of ``chunk``."""
+        with self._lock:
+            # a write since the pass donated the arrays it read; the ones
+            # in force hold every row the pass saw
+            return self._programs["rank"](self._sigs_t, self._live, asked,
+                                          chunk)
+
     def _pass(self, batch: list[_Waiting], counted: bool = True) -> None:
         """One pass for ``batch``: fills in every ``result``."""
-        import jax
-
-        prog = self._programs
-        width = next(q for q in QUERY_LADDER if q >= len(batch))
-        queries = np.stack([w.sig for w in batch]
-                           + [batch[0].sig] * (width - len(batch)))
-        # a padding query asks for more lanes than there are: no hit
-        least = np.array([w.least for w in batch]
-                         + [self.num_perms + 1] * (width - len(batch)),
-                         np.int32)
-        acc = batch[0].acc
         t0 = time.perf_counter_ns()
-        with self._lock:
-            self._room_for(0)
-            held = np.int32(self.base_rows + len(self._refs))
-        with span("fdfs.near.scan", acc, queries=len(batch), rows=int(held)):
+        with span("fdfs.near.scan", batch[0].acc,
+                  queries=len(batch)) as scan:
             with self._lock:
-                blocks = prog["scan"](self._sigs_t, queries)
-            hit_blocks = np.flatnonzero(np.asarray(blocks).any(axis=0))
-            keys = []
-            span_rows = RANK_BLOCKS * BLOCK
-            for lo in range(0, len(hit_blocks), RANK_BLOCKS):
-                chunk = np.full(RANK_BLOCKS, -1, np.int32)
-                part = hit_blocks[lo:lo + RANK_BLOCKS]
-                chunk[:len(part)] = part
-                with self._lock:
-                    # a write since the scan donated the arrays it read;
-                    # the ones in force hold every row the scan saw
-                    got = prog["rank"](self._sigs_t, self._live, queries,
-                                       least, chunk, held)
-                keys.append((chunk, np.asarray(jax.device_get(got))))
+                # the program reads every row acknowledged before this
+                self._room_for(0)
+                held = self.base_rows + len(self._refs)
+                asked = self._asked(batch, held)
+                out, blocks = self._programs["scan"](self._sigs_t,
+                                                     self._live, asked)
+            out = np.asarray(out)
+            n_keys = len(asked) * MAX_TOP_K
+            first = out[n_keys:n_keys + RANK_BLOCKS]
+            keys = [(first, out[:n_keys].reshape(-1, MAX_TOP_K))]
+            spilled = out[-1] > RANK_BLOCKS
+            if spilled:
+                # more nominated blocks than the program ranked: the rest
+                # by the rank program, RANK_BLOCKS at a time
+                hit_blocks = np.flatnonzero(np.asarray(blocks).any(axis=0))
+                for lo in range(RANK_BLOCKS, len(hit_blocks), RANK_BLOCKS):
+                    chunk = np.full(RANK_BLOCKS, -1, np.int32)
+                    part = hit_blocks[lo:lo + RANK_BLOCKS]
+                    chunk[:len(part)] = part
+                    keys.append((chunk, np.asarray(self._rank(asked, chunk))))
+            scan.note(rows=held, spilled=int(spilled))
         with self._lock:
             if counted:
                 self.counters["near_scans"] += 1
                 self.counters["near_queries"] += len(batch)
+                self.counters["near_rank_spills"] += int(spilled)
                 self.counters["near_scan_us"] += (
                     time.perf_counter_ns() - t0) // 1000
+        span_rows = RANK_BLOCKS * BLOCK
         for i, w in enumerate(batch):
             with span("fdfs.near.rank", w.acc):
                 found = []                    # (-count, row)

@@ -1,7 +1,7 @@
 """Spans inside the sidecar and the engine, on the device trace's clock.
 
-``span(name, acc, cpu=False, **ids)`` is the one helper.  It has three
-sinks:
+``span(name, acc, cpu=False, **ids)`` is the one helper (``note(**ids)``
+adds arguments known only inside it).  It has three sinks:
 
 * a ``jax.profiler.TraceAnnotation(name, **ids)``, which lands on the
   host plane of the same ``.xplane.pb`` as the device's "XLA Ops" line, so
@@ -70,6 +70,11 @@ class span:  # noqa: N801 — reads as a statement: ``with span(...)``
         if self.cpu:    # taken inside the wall interval: wall >= CPU
             self.c0 = time.thread_time_ns()
         return self
+
+    def note(self, **ids) -> None:
+        """More arguments, known only inside the span (while traced)."""
+        if self.ann is not None:
+            self.ann.set_metadata(**ids)
 
     def __exit__(self, *exc) -> bool:
         acc = self.acc

@@ -78,7 +78,8 @@ Opcodes
   ``_lock`` and joins the index's next pass, which answers every query
   waiting when it starts.  Status 61 when the file carries no signature.
   ``stats`` reports the index as ``near_queries``, ``near_scans``,
-  ``near_scan_us``, ``near_inserts``, ``near_removed`` (counters) and
+  ``near_scan_us``, ``near_rank_spills``, ``near_inserts``,
+  ``near_removed`` (counters) and
   ``near_rows``, ``near_base_rows``, ``near_resident_bytes`` (gauges).
 * ``DEDUP_VERIFY`` (136): batched chunk-integrity verify for the storage
   scrubber (``native/storage/scrub.cc``).  Body = 8B count + per chunk

@@ -2,7 +2,8 @@
 CPU backend at small size: held to ``MinHashLSHIndex``, the host reference
 that stays in the repo, on random and on planted rows, before and after a
 growth of its capacity; ties, tombstones, the seeded base, snapshots that
-never hold the base, and one pass for many waiting queries.
+never hold the base, one pass for many waiting queries, and a pass whose
+nominated blocks outnumber what its one program ranks.
 """
 
 from __future__ import annotations
@@ -17,7 +18,9 @@ import pytest
 
 from fastdfs_tpu.dedup import near_index
 from fastdfs_tpu.dedup.index import MinHashLSHIndex
-from fastdfs_tpu.dedup.near_index import DeviceNearIndex, base_rows, min_count
+from fastdfs_tpu.dedup.near_index import (DeviceNearIndex, _Waiting,
+                                          base_rows, min_count)
+from fastdfs_tpu.dedup.spans import new_acc
 from fastdfs_tpu.ops.minhash import EMPTY
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -278,3 +281,101 @@ def test_min_count_is_the_least_count_at_or_over_the_threshold():
     for thr in (0.1, 0.25, 0.33, 0.5, 0.75, 0.99):
         c = min_count(thr, 64)
         assert c / 64 >= thr > (c - 1) / 64
+
+
+# -- more nominated blocks than the pass program ranks ---------------------------
+
+BLOCK = near_index.BLOCK
+
+
+def _spread_families(tmp_path, families: int = 6):
+    """Both indexes over the same 65,436 rows (the four blocks of a fresh
+    index's capacity), loaded into the device's from the host's snapshot:
+    strangers, and families with member j in block j (members 0 and 3
+    the same signature under two refs: a tie across blocks; 1 and 2 keep
+    the first band, so every family nominates all four blocks)."""
+    rng = np.random.default_rng(18)
+    sigs = rng.integers(0, 1 << 32, (4 * BLOCK - 100, P), dtype=np.uint32)
+    refs = [f"r{i}" for i in range(len(sigs))]
+    roots = []
+    for f in range(families):
+        root = _sig(rng)
+        roots.append(root)
+        near = [root.copy(), root.copy()]
+        near[0][8:20] ^= 1
+        near[1][8:36] = _sig(rng)[8:36]
+        for j, sig in enumerate((root, *near, root)):
+            row = j * BLOCK + 300 * f + 17 * j
+            sigs[row], refs[row] = sig, f"f{f}/{j}"
+    host = MinHashLSHIndex(P, 16)
+    for sig, ref in zip(sigs, refs):
+        host.add(sig, ref)
+    path = str(tmp_path / "spread.npz")
+    host.save(path)
+    return host, DeviceNearIndex.load(path), roots
+
+
+@pytest.fixture
+def rank_blocks(monkeypatch):
+    """Sets ``RANK_BLOCKS`` for the programs made after it (the cache of
+    ``_programs`` is cleared before and after)."""
+    def set_to(n: int) -> None:
+        monkeypatch.setattr(near_index, "RANK_BLOCKS", n)
+        near_index._programs.cache_clear()
+    yield set_to
+    near_index._programs.cache_clear()
+
+
+def _counting(dev: DeviceNearIndex) -> dict:
+    """Counts the calls of the index's pass and rank programs."""
+    calls = {"scan": 0, "rank": 0}
+
+    def wrap(name, program):
+        def counted(*args):
+            calls[name] += 1
+            return program(*args)
+        return counted
+    dev._programs = {**dev._programs,
+                     **{k: wrap(k, dev._programs[k]) for k in calls}}
+    return calls
+
+
+@pytest.mark.parametrize("ranked", [1, 3])
+def test_a_pass_that_nominates_more_blocks_than_it_ranks_spills_exactly(
+        tmp_path, rank_blocks, ranked):
+    rank_blocks(ranked)          # the pass program ranks 1 or 3 of the 4
+    host, dev, roots = _spread_families(tmp_path)
+    calls = _counting(dev)
+    for top_k, thr in ((11, 0.5), (3, 0.25), (1, 0.9)):
+        for root in roots[:3]:
+            assert dev.query(root, top_k, thr) == host.query(root, top_k, thr)
+    want = [("f0/0", 1.0), ("f0/3", 1.0)]        # the tie: older row first
+    assert dev.query(roots[0], 2, 0.9) == want
+    assert dev.stats()["near_rank_spills"] == dev.stats()["near_scans"] == 10
+    # spilled blocks beyond the first RANK_BLOCKS: 4 - ranked, by the
+    # rank program RANK_BLOCKS at a time
+    assert calls == {"scan": 10, "rank": 10 * -(-(4 - ranked) // ranked)}
+    # one pass for every family at once: one spill
+    batch = [_Waiting(r, 11, min_count(0.5, P), new_acc()) for r in roots]
+    dev._pass(batch)
+    assert [w.result for w in batch] == [host.query(r, 11, 0.5)
+                                         for r in roots]
+    assert dev.stats()["near_rank_spills"] == 11
+    # a query whose blocks the program ranks alone does not spill
+    assert dev.query(_sig(np.random.default_rng(19)), 11, 0.5) == []
+    assert dev.stats()["near_rank_spills"] == 11
+
+
+def test_a_pass_that_nominates_no_more_than_it_ranks_is_one_program(tmp_path):
+    host, dev, roots = _spread_families(tmp_path)    # 4 blocks of 8
+    calls = _counting(dev)
+    for root in roots:
+        assert dev.query(root, 11, 0.5) == host.query(root, 11, 0.5)
+    batch = [_Waiting(r, 11, min_count(0.25, P), new_acc()) for r in roots]
+    dev._pass(batch)
+    assert [w.result for w in batch] == [host.query(r, 11, 0.25)
+                                         for r in roots]
+    assert calls == {"scan": len(roots) + 1, "rank": 0}
+    stats = dev.stats()
+    assert stats["near_rank_spills"] == 0
+    assert stats["near_scans"] == len(roots) + 1

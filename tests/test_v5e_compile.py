@@ -145,44 +145,53 @@ def test_minhash_pallas_compiles_for_v5e(one_chip, rows, blen):
     assert compiled.memory_analysis().temp_size_in_bytes <= 2.5 * rows * blen
 
 
-@pytest.mark.parametrize("n_q", [1, 8])
-def test_near_scan_pallas_compiles_for_v5e_at_a_nodes_size(one_chip, n_q):
-    """The scan over 30M rows and their eighth to spare (8.64 GB): the
-    kernel is there and the pass needs no temporary beside the matrix
-    (XLA's own fusion of the same comparison needs 5.7 GB at one query
-    and more than the chip holds at eight: PERF.md section 6, PR 39)."""
-    from fastdfs_tpu.dedup.near_index import DeviceNearIndex, _programs
-    from fastdfs_tpu.ops.pallas_near_scan import LANES
-
-    capacity = DeviceNearIndex._capacity_for(30_000_000 + 30_000_000 // 8)
-    sigs = jax.ShapeDtypeStruct((64, capacity // LANES, LANES), jnp.uint32,
-                                sharding=one_chip)
-    queries = jax.ShapeDtypeStruct((n_q, 64), jnp.uint32, sharding=one_chip)
-    compiled = _programs(16, True)["scan"].lower(sigs, queries).compile()
-    assert "tpu_custom_call" in compiled.as_text()
-    mem = compiled.memory_analysis()
-    assert mem.argument_size_in_bytes >= capacity * 256
-    assert mem.temp_size_in_bytes <= 256 << 20
-
-
-def test_near_rank_and_insert_compile_for_v5e_at_a_nodes_size(one_chip):
-    """The rank program gathers its blocks by slices (a gather copied the
-    matrix: 2.2 GB of temporaries), and an insert writes in place."""
-    from fastdfs_tpu.dedup.near_index import (RANK_BLOCKS, DeviceNearIndex,
-                                              _programs)
+def _near_arrays(one_chip, n_q):
+    """The near-dup index's device arrays at 30M rows and their eighth to
+    spare (8.64 GB), and a pass's queries with their least counts and row
+    limit (``DeviceNearIndex._asked``)."""
+    from fastdfs_tpu.dedup.near_index import DeviceNearIndex
     from fastdfs_tpu.ops.pallas_near_scan import LANES
 
     capacity = DeviceNearIndex._capacity_for(30_000_000 + 30_000_000 // 8)
 
     def arg(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
-    sigs = arg((64, capacity // LANES, LANES), jnp.uint32)
-    live = arg((capacity // LANES, LANES), jnp.bool_)
+    return capacity, (arg((64, capacity // LANES, LANES), jnp.uint32),
+                      arg((capacity // LANES, LANES), jnp.bool_),
+                      arg((n_q, 64 + 2), jnp.uint32))
+
+
+@pytest.mark.parametrize("n_q", [1, 8])
+def test_near_pass_compiles_for_v5e_at_a_nodes_size(one_chip, n_q):
+    """A pass is one program: the scan, its nominated blocks picked and
+    ranked on the device.  The kernel is there and the pass needs no
+    temporary beside the matrix, so neither the compaction nor the rank's
+    slices copy it (XLA's own fusion of the scan's comparison needs 5.7 GB
+    at one query and more than the chip holds at eight, a gather of the
+    blocks 2.2 GB: PERF.md section 6, PR 39)."""
+    from fastdfs_tpu.dedup.near_index import _programs
+
+    capacity, args = _near_arrays(one_chip, n_q)
+    compiled = _programs(16, True)["scan"].lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes >= capacity * 256
+    assert mem.temp_size_in_bytes <= 256 << 20
+
+
+def test_near_spill_rank_and_insert_compile_for_v5e_at_a_nodes_size(one_chip):
+    """The rank program alone (a pass's blocks beyond the first
+    RANK_BLOCKS) gathers its blocks by slices (a gather copied the
+    matrix: 2.2 GB of temporaries), and an insert writes in place."""
+    from fastdfs_tpu.dedup.near_index import RANK_BLOCKS, _programs
+
+    capacity, (sigs, live, asked) = _near_arrays(one_chip, 8)
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
     prog = _programs(16, True)
-    rank = prog["rank"].lower(sigs, live, arg((8, 64), jnp.uint32),
-                              arg((8,), jnp.int32),
-                              arg((RANK_BLOCKS,), jnp.int32),
-                              arg((), jnp.int32)).compile()
+    rank = prog["rank"].lower(sigs, live, asked,
+                              arg((RANK_BLOCKS,), jnp.int32)).compile()
     assert rank.memory_analysis().temp_size_in_bytes <= 256 << 20
     insert = prog["insert"].lower(sigs, live, arg((64,), jnp.uint32),
                                   arg((), jnp.int32),
