@@ -1171,8 +1171,8 @@ def cmd_sidecar_trace(args: list[str]) -> int:
     """sidecar-trace <socket> --seconds N --out DIR: a JAX profiler trace
     of a running dedup sidecar (device operations and the ``fdfs.*`` spans
     on one clock), and the spans' wall time and count, the receive
-    counters, the near-dup index's and the erasure coding's counters over
-    those seconds from its ``stats`` reply.  Takes the
+    counters, the exact index's, the near-dup index's and the erasure
+    coding's counters over those seconds from its ``stats`` reply.  Takes the
     sidecar's socket, no tracker."""
     import time
 
@@ -1256,6 +1256,14 @@ def cmd_sidecar_trace(args: list[str]) -> int:
              "pass)" if near["near_scans"] else "")
           + f", near_inserts {near['near_inserts']}, near_removed "
           f"{near['near_removed']}")
+    # the exact index: a batch is one commit's digests
+    ex = {k: after.get(k, 0) - before.get(k, 0) for k in (
+        "exact_insert_batches", "exact_inserted", "exact_merges")}
+    print(f"exact index: exact_insert_batches {ex['exact_insert_batches']}, "
+          f"exact_inserted {ex['exact_inserted']}"
+          + (f" ({ex['exact_inserted'] / ex['exact_insert_batches']:.1f} a "
+             "batch)" if ex["exact_insert_batches"] else "")
+          + f", exact_merges {ex['exact_merges']}")
     # the erasure coding on write: stripes whose parity the chip computed
     ec = {k: after.get(k, 0) - before.get(k, 0) for k in (
         "ec_encode_requests", "ec_encode_bytes", "ec_encode_us")}

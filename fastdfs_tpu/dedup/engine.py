@@ -658,22 +658,23 @@ class DedupEngine:
             return report
 
         raw = digest_bytes(digests)
+        digs = [raw[i * 20:(i + 1) * 20] for i in range(len(spans))]
         # Repeats *within* this stream must judge as duplicates even on a
         # dry run, so track first-seen digests locally too.
         seen_here: dict[bytes, list] = {}
-        for i, (off, ln) in enumerate(spans):
-            dig = raw[i * 20:(i + 1) * 20]
-            existing = self.exact.lookup(dig)
+        for (off, ln), dig, existing in zip(spans, digs,
+                                            self.exact.lookup_batch(digs)):
             if existing is None:
                 existing = seen_here.get(dig)
             if existing is None:
                 seen_here[dig] = [file_ref, off]
-                if update_index:
-                    self.exact.insert(dig, [file_ref, off])
                 report.chunks.append(ChunkRecord(off, ln, dig, duplicate=False))
             else:
                 report.chunks.append(ChunkRecord(off, ln, dig, duplicate=True,
                                                  dup_of=existing))
+        if update_index:
+            self.exact.insert_batch(raw, file_ref,
+                                    [off for off, _ in spans])
 
         # File-level signature: min over chunk signatures == MinHash of the
         # union of their shingle sets.

@@ -16,6 +16,7 @@ from typing import Any, Sequence
 
 import numpy as np
 
+from fastdfs_tpu.dedup.spans import new_acc, span
 from fastdfs_tpu.ops.minhash import EMPTY
 
 # Bumped whenever the signature spec changes (v2 = the survivor sketch,
@@ -33,6 +34,89 @@ _OFF_BARE = -(1 << 62)
 # v1 = flat digest bytes + per-entry json refs).  load() reads both.
 _EXACT_SPEC = 2
 
+# The delta is folded into the base once it holds max(this, base / 4) rows.
+_MERGE_ROWS = 65536
+
+# A batch under this many digests is probed and kept one digest at a time
+# (a dict probe, a scalar search a run): numpy lets the interpreter go in
+# a search or sort of a few dozen keys or more, and under the sidecar's
+# lock each such hand-off waits out the other busy threads' slices.
+_ONE_BY_ONE = 256
+
+
+def _prefix_keys(dig: np.ndarray) -> np.ndarray:
+    """The first 8 bytes of each ``S20`` digest as one big-endian
+    ``uint64``: the order of the ``S20`` column, so that a probe compares
+    integers and only a shared prefix needs the whole digest."""
+    raw = np.ascontiguousarray(dig).view(np.uint8).reshape(-1, 20)
+    return raw[:, :8].copy().view(">u8").ravel().astype(np.uint64)
+
+
+class _Run:
+    """Rows sorted by digest: the ``S20`` digests, their prefix keys (the
+    same order), carrier ids, offsets and tombstones.  No digest is in a
+    run twice."""
+
+    __slots__ = ("dig", "key", "cid", "off", "dead")
+
+    def __init__(self, dig: np.ndarray, cid: np.ndarray, off: np.ndarray,
+                 key: np.ndarray | None = None) -> None:
+        self.dig = dig
+        self.key = _prefix_keys(dig) if key is None else key
+        self.cid = cid
+        self.off = off
+        self.dead = np.zeros(len(dig), dtype=bool)
+
+    def __len__(self) -> int:
+        return len(self.dig)
+
+    def holds(self, digest: bytes, k: np.uint64) -> bool:
+        """Whether one digest (``k``: its prefix key) is live here, by a
+        scalar search that keeps the interpreter."""
+        n = len(self.dig)
+        i = int(self.key.searchsorted(k))
+        while i < n and self.key[i] == k:
+            if self.dig[i:i + 1].tobytes() == digest:   # all 20 bytes
+                return not self.dead[i]
+            i += 1
+        return False
+
+    def find(self, q: np.ndarray, qkey: np.ndarray) -> np.ndarray:
+        """The row of each query digest's live entry here, or -1.  ``q``
+        is an ``S20`` array: only S20-to-S20 comparison gets NUL-padding
+        semantics, so the ~1/256 SHA1 digests ending in 0x00 still match."""
+        n = len(self.dig)
+        if n == 0:
+            return np.full(len(q), -1, dtype=np.intp)
+        row = np.searchsorted(self.key, qkey)
+        np.minimum(row, n - 1, out=row)
+        hit = self.dig[row] == q
+        # a prefix this run holds more than once: the digests place it
+        tie = ~hit & (self.key[row] == qkey)
+        if tie.any():
+            j = np.flatnonzero(tie)
+            r = np.minimum(np.searchsorted(self.dig, q[j]), n - 1)
+            row[j] = r
+            hit[j] = self.dig[r] == q[j]
+        hit &= ~self.dead[row]
+        return np.where(hit, row, -1)
+
+    @classmethod
+    def merged(cls, runs: Sequence["_Run"]) -> "_Run":
+        """One run of the live rows of ``runs`` (a digest is live in one
+        of them at most)."""
+        cols = [[], [], [], []]
+        for r in runs:
+            live = ~r.dead if r.dead.any() else slice(None)
+            for col, a in zip(cols, (r.dig, r.key, r.cid, r.off)):
+                col.append(a[live])
+        dig, key, cid, off = (np.concatenate(c) for c in cols)
+        order = np.argsort(key, kind="stable")
+        sk = key[order]
+        if (sk[1:] == sk[:-1]).any():   # a shared prefix: sort the digests
+            order = np.argsort(dig, kind="stable")
+        return cls(dig[order], cid[order], off[order], key[order])
+
 
 class ExactDigestIndex:
     """digest bytes → ``[carrier, offset]`` ref (chunk locator / file id),
@@ -40,31 +124,44 @@ class ExactDigestIndex:
 
     A plain ``dict[bytes, list]`` costs ~200 B/entry — config 5's nominal
     scale (~62M chunks) would need >12 GB of pure bookkeeping.  Instead:
-    an LSM-flavored layout with a sorted ``S20`` digest column plus
-    parallel ``int32`` carrier-id / ``int64`` offset columns (the BASE),
-    and a small dict DELTA for recent inserts, merged into the base when
-    it grows past a quarter of it.  ~36 B/entry steady-state, batch
-    lookups vectorize through ``np.searchsorted``, and snapshots are raw
-    column dumps (SHA1 digests are incompressible — no zlib pass).
+    an LSM-flavored layout of sorted runs (``_Run``: an ``S20`` digest
+    column, its first 8 bytes as ``uint64`` keys, parallel ``int32``
+    carrier-id / ``int64`` offset columns).  The BASE is one run; the
+    DELTA is a few small ones, one appended per inserted batch, the last
+    two merged while they are within twice each other's size (so at most
+    ~log2 of them), plus a dict of the digests that batches under
+    ``_ONE_BY_ONE`` added, and all of it is folded into the base when it
+    reaches a quarter of it.  ~41 B/entry steady-state, a large batch of
+    inserts or lookups is a few ``np.searchsorted`` over integer keys with
+    no Python per digest, and snapshots are raw column dumps (SHA1
+    digests are incompressible — no zlib pass).
 
     Carrier objects (file ids) are interned in a side table, so the per
     entry cost is independent of file-id length.  Removals tombstone
-    base rows (compacted at the next merge) and delete delta entries.
+    rows, compacted at the next merge.
     """
 
     def __init__(self) -> None:
-        self._base_dig = np.empty(0, dtype="S20")
-        self._base_carrier = np.empty(0, dtype=np.int32)
-        self._base_off = np.empty(0, dtype=np.int64)
-        self._base_dead = np.empty(0, dtype=bool)
-        self._dead = 0                                  # tombstoned rows
-        self._delta: dict[bytes, tuple[int, int]] = {}  # dig -> (cid, off)
+        self._base = _Run(np.empty(0, dtype="S20"),
+                          np.empty(0, dtype=np.int32),
+                          np.empty(0, dtype=np.int64))
+        self._delta: list[_Run] = []    # newest last
+        self._small: dict[bytes, tuple[int, int]] = {}  # dig -> (cid, off)
+        self._delta_rows = 0            # rows in the delta, tombstones too
         self._carriers: list[Any] = []
         self._carrier_ids: dict[Any, int] = {}
         self._len = 0
+        self.insert_batches = self.inserted = self.merges = 0
 
     def __len__(self) -> int:
         return self._len
+
+    def stats(self) -> dict:
+        """Counters for the sidecar's ``stats`` reply: inserted batches
+        (one a commit), the digests they added, delta → base merges."""
+        return {"exact_insert_batches": self.insert_batches,
+                "exact_inserted": self.inserted,
+                "exact_merges": self.merges}
 
     # -- internals ---------------------------------------------------------
 
@@ -87,44 +184,45 @@ class ExactDigestIndex:
         c = self._carriers[cid]
         return c if off == _OFF_BARE else [c, off]
 
-    def _base_row(self, digest: bytes) -> int:
-        """Row index of a LIVE base entry, or -1."""
-        n = len(self._base_dig)
-        if n == 0:
-            return -1
-        # The probe must be an S20 ARRAY scalar, not np.bytes_: only
-        # S20-to-S20 comparison gets NUL-padding semantics, so the ~1/256
-        # SHA1 digests ending in 0x00 still match their stored row.
-        q = np.array(digest, dtype="S20")
-        i = int(np.searchsorted(self._base_dig, q))
-        if i < n and self._base_dig[i] == q and not self._base_dead[i]:
-            return i
-        return -1
+    def _runs(self) -> list[_Run]:
+        return [self._base, *self._delta]
 
-    def _merge(self) -> None:
+    def _find(self, q: np.ndarray, qkey: np.ndarray | None = None
+              ) -> tuple[list[_Run], np.ndarray, np.ndarray]:
+        """For each ``S20`` query digest (``qkey``: its prefix keys, if
+        known): which of the runs holds it live (-1: none; at most one
+        does) and its row there."""
+        runs = self._runs()
+        if qkey is None:
+            qkey = _prefix_keys(q)
+        where = np.full(len(q), -1, dtype=np.intp)
+        row = np.full(len(q), -1, dtype=np.intp)
+        for i, run in enumerate(runs):
+            r = run.find(q, qkey)
+            hit = r >= 0
+            where[hit] = i
+            row[hit] = r[hit]
+        return runs, where, row
+
+    def _merge(self, acc: dict | None = None) -> None:
         """Fold the delta into the base (and compact tombstones)."""
-        alive = ~self._base_dead if self._dead else slice(None)
-        parts_d = [self._base_dig[alive]]
-        parts_c = [self._base_carrier[alive]]
-        parts_o = [self._base_off[alive]]
-        if self._delta:
-            nd = len(self._delta)
-            parts_d.append(np.fromiter(self._delta.keys(), dtype="S20",
-                                       count=nd))
-            vals = self._delta.values()
-            parts_c.append(np.fromiter((v[0] for v in vals), dtype=np.int32,
-                                       count=nd))
-            parts_o.append(np.fromiter((v[1] for v in self._delta.values()),
-                                       dtype=np.int64, count=nd))
-        dig = np.concatenate(parts_d)
-        order = np.argsort(dig, kind="stable")
-        self._base_dig = dig[order]
-        self._base_carrier = np.concatenate(parts_c)[order]
-        self._base_off = np.concatenate(parts_o)[order]
-        self._base_dead = np.zeros(len(dig), dtype=bool)
-        self._dead = 0
-        self._delta = {}
-        self._compact_carriers()
+        with span("fdfs.exact.merge", new_acc() if acc is None else acc) as s:
+            runs = self._runs()
+            if self._small:     # unsorted: merged() sorts what it is given
+                n, vals = len(self._small), self._small.values()
+                runs.append(_Run(
+                    np.fromiter(self._small.keys(), dtype="S20", count=n),
+                    np.fromiter((v[0] for v in vals), dtype=np.int32,
+                                count=n),
+                    np.fromiter((v[1] for v in vals), dtype=np.int64,
+                                count=n)))
+            self._base = _Run.merged(runs)
+            self._delta = []
+            self._small = {}
+            self._delta_rows = 0
+            self._compact_carriers()
+            s.note(rows=len(self._base))
+        self.merges += 1
 
     def _compact_carriers(self) -> None:
         """Drop forgotten (None-slotted) carriers and remap the base
@@ -134,11 +232,12 @@ class ExactDigestIndex:
         need remapping too)."""
         if not any(c is None for c in self._carriers):
             return
-        used = np.unique(self._base_carrier) if len(self._base_carrier) \
+        base = self._base
+        used = np.unique(base.cid) if len(base) \
             else np.empty(0, dtype=np.int32)
         remap = np.full(len(self._carriers), -1, dtype=np.int32)
         remap[used] = np.arange(len(used), dtype=np.int32)
-        self._base_carrier = remap[self._base_carrier]
+        base.cid = remap[base.cid]
         self._carriers = [self._carriers[int(c)] for c in used]
         self._carrier_ids = {}
         for i, c in enumerate(self._carriers):
@@ -147,99 +246,145 @@ class ExactDigestIndex:
             except TypeError:
                 pass  # unhashable carrier (load() tolerates them too)
 
-    def _maybe_merge(self) -> None:
-        if len(self._delta) >= max(65536, len(self._base_dig) // 4):
-            self._merge()
+    def _settle(self, acc: dict | None) -> None:
+        """After a run is appended: merge the last two runs while they are
+        within twice each other's size, and the delta into the base once
+        it holds a quarter of it."""
+        d = self._delta
+        while len(d) > 1 and len(d[-2]) <= 2 * len(d[-1]):
+            d[-2:] = [_Run.merged(d[-2:])]
+        self._delta_rows = sum(map(len, d)) + len(self._small)
+        if self._delta_rows >= max(_MERGE_ROWS, len(self._base) // 4):
+            self._merge(acc)
 
     # -- API ---------------------------------------------------------------
 
     def lookup(self, digest: bytes):
-        v = self._delta.get(digest)
-        if v is not None:
-            return self._compose(v[0], v[1])
-        i = self._base_row(digest)
-        if i < 0:
-            return None
-        return self._compose(int(self._base_carrier[i]),
-                             int(self._base_off[i]))
+        return self.lookup_batch([digest])[0]
 
     def lookup_batch(self, digests: Sequence[bytes]) -> list[Any]:
-        """One vectorized searchsorted over the base for the whole batch
-        (the TPU engine judges chunks hundreds at a time)."""
+        """One vectorized probe of every run for the whole batch (the TPU
+        engine judges chunks hundreds at a time)."""
         out: list[Any] = [None] * len(digests)
         if not digests:
             return out
-        n = len(self._base_dig)
-        if n:
-            keys = np.array(list(digests), dtype="S20")
-            idx = np.searchsorted(self._base_dig, keys)
-            np.clip(idx, 0, n - 1, out=idx)
-            hit = (self._base_dig[idx] == keys) & ~self._base_dead[idx]
-            for j in np.nonzero(hit)[0]:
-                i = int(idx[j])
-                out[j] = self._compose(int(self._base_carrier[i]),
-                                       int(self._base_off[i]))
-        if self._delta:
+        runs, where, row = self._find(np.array(list(digests), dtype="S20"))
+        for j in np.flatnonzero(where >= 0):
+            run, i = runs[where[j]], row[j]
+            out[j] = self._compose(int(run.cid[i]), int(run.off[i]))
+        if self._small:
             for j, d in enumerate(digests):
-                v = self._delta.get(d)
+                v = self._small.get(d)
                 if v is not None:
                     out[j] = self._compose(v[0], v[1])
         return out
 
     def insert(self, digest: bytes, ref: Any) -> bool:
         """Insert if absent; returns True when this digest was new."""
-        if digest in self._delta or self._base_row(digest) >= 0:
-            return False
         carrier, off = self._decompose(ref)
-        self._delta[digest] = (self._cid(carrier), off)
-        self._len += 1
-        self._maybe_merge()
-        return True
+        return self.insert_batch(digest, carrier, [off]) == 1
+
+    def insert_batch(self, digests, carrier: Any, offsets,
+                     acc: dict | None = None) -> int:
+        """``insert(digests[i], [carrier, offsets[i]])`` for every i in
+        order, as one probe (under ``_ONE_BY_ONE`` digests, one at a time):
+        ``digests`` is 20·n raw bytes (any buffer), ``offsets`` n
+        integers.  A digest already live keeps its ref and,
+        within the batch, the first occurrence wins.  Returns how many
+        were new.  A merge it sets off is a ``fdfs.exact.merge`` span in
+        ``acc``."""
+        q = np.frombuffer(digests, dtype="S20")
+        self.insert_batches += 1
+        if len(q) < _ONE_BY_ONE:
+            return self._insert_one_by_one(bytes(digests), carrier, offsets,
+                                           acc)
+        # each digest once, at its first occurrence, in key order
+        qkey, first = np.unique(_prefix_keys(q), return_index=True)
+        if len(first) < len(q):     # a shared prefix: the digests decide
+            _, first = np.unique(q, return_index=True)
+            qkey = _prefix_keys(q[first])
+        q = q[first]
+        _, where, _ = self._find(q, qkey)
+        new = np.flatnonzero(where < 0)
+        if self._small:
+            raw = q.tobytes()
+            new = new[[raw[i * 20:(i + 1) * 20] not in self._small
+                       for i in new]]
+        if len(new):
+            run = _Run(q[new], np.full(len(new), self._cid(carrier),
+                                       dtype=np.int32),
+                       np.asarray(offsets, dtype=np.int64)[first[new]],
+                       qkey[new])
+            self._delta.append(run)
+            self._len += len(new)
+            self.inserted += len(new)
+            self._settle(acc)
+        return len(new)
+
+    def _insert_one_by_one(self, raw: bytes, carrier: Any, offsets,
+                           acc: dict | None) -> int:
+        """``insert_batch`` for a batch under ``_ONE_BY_ONE``: a dict probe
+        and a scalar search of each run a digest, the new ones into
+        ``_small``."""
+        runs, small, cid, new = self._runs(), self._small, None, 0
+        for i, off in enumerate(offsets):
+            d = raw[i * 20:(i + 1) * 20]
+            if d in small:
+                continue
+            k = np.uint64(int.from_bytes(d[:8], "big"))
+            if any(run.holds(d, k) for run in runs):
+                continue
+            if cid is None:
+                cid = self._cid(carrier)
+            small[d] = (cid, int(off))
+            new += 1
+        if new:
+            self._len += new
+            self.inserted += new
+            self._settle(acc)
+        return new
 
     def remove(self, digest: bytes) -> bool:
-        if self._delta.pop(digest, None) is not None:
+        if self._small.pop(digest, None) is not None:
             self._len -= 1
             return True
-        i = self._base_row(digest)
-        if i < 0:
+        runs, where, row = self._find(np.array([digest], dtype="S20"))
+        if where[0] < 0:
             return False
-        self._base_dead[i] = True
-        self._dead += 1
+        runs[where[0]].dead[row[0]] = True
         self._len -= 1
         return True
 
     def items(self):
-        """Live (digest, ref) pairs — delta first, then base.  Base
-        digests are re-padded to the full 20 bytes: numpy ``S20`` scalars
-        strip trailing NULs on extraction, which would silently shorten
-        ~1/256 SHA1 digests for byte-equality consumers."""
-        for d, (cid, off) in self._delta.items():
+        """Live (digest, ref) pairs — delta first, then base.  Digests are
+        sliced from the column's raw bytes: numpy ``S20`` scalars strip
+        trailing NULs on extraction, which would silently shorten ~1/256
+        SHA1 digests for byte-equality consumers."""
+        for d, (cid, off) in self._small.items():
             yield d, self._compose(cid, off)
-        for i in range(len(self._base_dig)):
-            if not self._base_dead[i]:
-                yield bytes(self._base_dig[i]).ljust(20, b"\0"), self._compose(
-                    int(self._base_carrier[i]), int(self._base_off[i]))
+        for run in [*self._delta, self._base]:
+            raw = run.dig.tobytes()
+            for i in np.flatnonzero(~run.dead):
+                yield raw[i * 20:(i + 1) * 20], self._compose(
+                    int(run.cid[i]), int(run.off[i]))
 
     def remove_by_carrier(self, carrier: Any) -> int:
         """Tombstone every live entry attributed to ``carrier`` (a deleted
-        file id) — one vectorized mask over the base carrier column plus a
-        delta scan, so `forget` needs no per-file side table of digest
-        lists (which would reintroduce the per-entry object overhead this
-        columnar layout exists to avoid).  Returns the number removed."""
+        file id) — one vectorized mask over each run's carrier column, so
+        `forget` needs no per-file side table of digest lists (which
+        would reintroduce the per-entry object overhead this columnar
+        layout exists to avoid).  Returns the number removed."""
         cid = self._carrier_ids.get(carrier)
         if cid is None:
             return 0
-        dead_delta = [d for d, v in self._delta.items() if v[0] == cid]
-        for d in dead_delta:
-            del self._delta[d]
-        n = len(dead_delta)
-        if len(self._base_dig):
-            hit = (self._base_carrier == cid) & ~self._base_dead
-            k = int(hit.sum())
-            if k:
-                self._base_dead[hit] = True
-                self._dead += k
-                n += k
+        dead_small = [d for d, v in self._small.items() if v[0] == cid]
+        for d in dead_small:
+            del self._small[d]
+        n = len(dead_small)
+        for run in self._runs():
+            hit = (run.cid == cid) & ~run.dead
+            run.dead |= hit
+            n += int(hit.sum())
         self._len -= n
         # Release the interned id now (the string itself at the next
         # merge): churned file ids must not accumulate in the carrier
@@ -254,8 +399,8 @@ class ExactDigestIndex:
         self._merge()  # snapshot = one sorted columnar base
         _atomic_savez(
             path, compress=False,  # SHA1 columns are incompressible
-            digests=self._base_dig.view(np.uint8),
-            carrier_idx=self._base_carrier, offsets=self._base_off,
+            digests=self._base.dig.view(np.uint8),
+            carrier_idx=self._base.cid, offsets=self._base.off,
             carriers=np.array([json.dumps(c) for c in self._carriers],
                               dtype=object),
             exact_spec=_EXACT_SPEC)
@@ -270,10 +415,9 @@ class ExactDigestIndex:
             for i in range(len(refs)):
                 idx.insert(raw[i * 20:(i + 1) * 20], json.loads(str(refs[i])))
             return idx
-        idx._base_dig = np.ascontiguousarray(data["digests"]).view("S20")
-        idx._base_carrier = np.asarray(data["carrier_idx"], dtype=np.int32)
-        idx._base_off = np.asarray(data["offsets"], dtype=np.int64)
-        idx._base_dead = np.zeros(len(idx._base_dig), dtype=bool)
+        idx._base = _Run(np.ascontiguousarray(data["digests"]).view("S20"),
+                         np.asarray(data["carrier_idx"], dtype=np.int32),
+                         np.asarray(data["offsets"], dtype=np.int64))
         idx._carriers = [json.loads(str(c)) for c in data["carriers"]]
         idx._carrier_ids = {}
         for i, c in enumerate(idx._carriers):
@@ -281,7 +425,7 @@ class ExactDigestIndex:
                 idx._carrier_ids[c] = i
             except TypeError:  # unhashable carrier (e.g. json list)
                 pass
-        idx._len = len(idx._base_dig)
+        idx._len = len(idx._base)
         return idx
 
 

@@ -41,7 +41,13 @@ Opcodes
   ``_lock`` so that concurrent uploads overlap on the device;
   ``lock_wait_us``, the wait for ``_lock`` AFTER that call, to append
   the reply and the session's bookkeeping (it cannot show queueing for
-  the engine: nothing queues there); ``span_us`` / ``span_n``, wall time
+  the engine: nothing queues there; what it waits behind is mostly the
+  other holders, the commits: span ``fdfs.sidecar.commit``, which puts
+  a session's digests into the exact index in one batch, and
+  ``fdfs.exact.merge`` when that batch folds the index's delta into its
+  base); ``exact_insert_batches``, ``exact_inserted``,
+  ``exact_merges``, the exact index's counters (batches are commits,
+  inserted the digests they added); ``span_us`` / ``span_n``, wall time
   and count of every ``fdfs.*`` span by name (``dedup/spans.py``; the
   table is in OPERATIONS.md, "Tracing"; ``engine_us`` and
   ``lock_wait_us`` are two of its entries under their old names);
@@ -153,15 +159,21 @@ _SHIPPED_WIDTHS = (DedupConfig.min_size, DedupConfig.avg_bits,
                    DedupConfig.max_size)
 
 
+# A fingerprint reply's record of one chunk: offset, length, raw SHA1.
+_CHUNK_REC = np.dtype([("off", ">i8"), ("len", ">i8"), ("dig", "V20")])
+
+
 class _Session:
     """Pending per-upload state: accumulated file signature + the digest
-    attributions to insert (with the real file id) at commit time."""
+    attributions to insert (with the real file id) at commit time, one
+    entry per fingerprint request: its raw digests (20 B a chunk) and the
+    chunks' absolute offsets."""
 
     __slots__ = ("sig", "digests", "touched")
 
     def __init__(self) -> None:
         self.sig: np.ndarray | None = None
-        self.digests: list[tuple[bytes, int]] = []  # (raw digest, offset)
+        self.digests: list[tuple[bytes, np.ndarray]] = []
         self.touched = time.monotonic()
 
 
@@ -454,19 +466,18 @@ class DedupSidecar:
             with span("fdfs.sidecar.reply", acc, True):
                 sess = self._sessions.setdefault(session_id, _Session())
                 sess.touched = time.monotonic()
-                raw = np.asarray(digests, dtype=">u4").tobytes()
-                out = [_I64.pack(len(spans))]
-                for i, (off, ln) in enumerate(spans):
-                    out.append(_I64.pack(base_offset + off))
-                    out.append(_I64.pack(ln))
+                rec = np.empty(len(spans), dtype=_CHUNK_REC)
+                if len(spans):
+                    raw = np.asarray(digests, dtype=">u4").tobytes()
+                    ol = np.asarray(spans, dtype=np.int64)
+                    rec["off"] = ol[:, 0] + base_offset
+                    rec["len"] = ol[:, 1]
+                    rec["dig"] = np.frombuffer(raw, dtype="V20")
                     # Digest attribution (which file first carried a
                     # chunk, for near-dup reporting) stays buffered in the
                     # session until commit binds the real file id — the
                     # index never sees provisional entries.
-                    dig = raw[i * 20:(i + 1) * 20]
-                    out.append(dig)
-                    sess.digests.append((dig, base_offset + off))
-                if len(spans):
+                    sess.digests.append((raw, rec["off"].astype(np.int64)))
                     sig = np.asarray(sigs).min(axis=0)
                     sess.sig = (sig if sess.sig is None
                                 else np.minimum(sess.sig, sig))
@@ -490,7 +501,7 @@ class DedupSidecar:
         mark("fdfs.sidecar.request_done", bytes=len(data),
              host_wall_us=acc["host_wall_ns"] // 1000,
              host_cpu_us=acc["host_cpu_ns"] // 1000)
-        return 0, b"".join(out)
+        return 0, _I64.pack(len(spans)) + rec.tobytes()
 
     def _fold(self, acc: dict) -> None:
         """The spans ``acc`` has closed so far into ``stats``, and out of
@@ -536,11 +547,18 @@ class DedupSidecar:
         # _lock is let go and before the daemon is answered, so every
         # query sent after the acknowledgement sees it.
         if parts[0] == "commitchunks" and len(parts) == 3:
-            with self._lock:
+            if acc is None:
+                acc = new_acc()
+            with self._lock, span("fdfs.sidecar.commit", acc) as s:
                 sess = self._sessions.pop(_parse_session(parts[1]), None)
-                if sess is not None:
-                    for dig, off in sess.digests:
-                        self.engine.exact.insert(dig, [parts[2], off])
+                if sess is not None and sess.digests:
+                    # the session's digests as one batch
+                    new = self.engine.exact.insert_batch(
+                        b"".join(raw for raw, _ in sess.digests), parts[2],
+                        np.concatenate([off for _, off in sess.digests]),
+                        acc=acc)
+                    s.note(digests=sum(len(off) for _, off in sess.digests),
+                           new=new)
             if sess is not None and sess.sig is not None:
                 self._near_update(self.engine.near.add, sess.sig, parts[2],
                                   acc=acc)
@@ -568,6 +586,7 @@ class DedupSidecar:
                 return 0, b""
             if parts[0] == "stats" and len(parts) == 1:
                 return 0, json.dumps({**self.stats,
+                                      **self.engine.exact.stats(),
                                       **self.engine.near.stats(),
                                       **self.device_info()}).encode()
             if parts[0] == "abort" and len(parts) == 2:

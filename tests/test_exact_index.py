@@ -33,8 +33,8 @@ def test_merge_triggers_at_real_threshold_and_preserves_lookups():
     for i, d in enumerate(digs):
         assert idx.insert(d, [f"f{i % 97}", i])
     # the merge must actually have happened (delta folded into the base)
-    assert len(idx._base_dig) >= 65536
-    assert len(idx._delta) < 65536
+    assert len(idx._base) >= 65536
+    assert idx._delta_rows < 65536
     assert len(idx) == n
     # spot-check lookups across both sides of the merge boundary
     for i in (0, 1, 65535, 65536, n - 1, n // 2):
@@ -55,11 +55,10 @@ def test_merge_compacts_tombstones():
     idx._merge()  # all in base
     for d in digs[::3]:
         assert idx.remove(d)
-    assert idx._dead == len(digs[::3])
+    assert int(idx._base.dead.sum()) == len(digs[::3])
     idx._merge()
-    assert idx._dead == 0
-    assert not idx._base_dead.any()
-    assert len(idx._base_dig) == 1000 - len(digs[::3])
+    assert not idx._base.dead.any()
+    assert len(idx._base) == 1000 - len(digs[::3])
     for i, d in enumerate(digs):
         if i % 3 == 0:
             assert idx.lookup(d) is None
@@ -85,6 +84,143 @@ def test_removed_digest_can_be_reinserted_with_new_ref():
     idx._merge()
     assert idx.lookup(digs[50]) == ["new", 7]
     assert len(idx) == 100
+
+
+# ---------------------------------------------------------------------------
+# insert_batch: one probe a commit, the answers of insert() digest by digest
+# ---------------------------------------------------------------------------
+
+class _FirstWriterWins:
+    """The referee: a dict with ``insert``'s rule (a live digest keeps its
+    ref) and ``remove``'s."""
+
+    def __init__(self) -> None:
+        self.refs: dict[bytes, list] = {}
+
+    def insert_batch(self, digs, carrier, offsets) -> int:
+        new = 0
+        for d, off in zip(digs, offsets):
+            if d not in self.refs:
+                self.refs[d] = [carrier, int(off)]
+                new += 1
+        return new
+
+
+def _random_digests(rng, n: int) -> list[bytes]:
+    return [rng.bytes(20) for _ in range(n)]
+
+
+def _nul_and_prefix_digests(rng, n: int) -> list[bytes]:
+    """Digests ending in 0x00 (the S20 NUL rule) and digests that share
+    their first 8 bytes (the integer key ties)."""
+    out = []
+    for i in range(n):
+        d = bytearray(rng.bytes(20))
+        if i % 3 == 0:
+            d[-(1 + i % 12):] = bytes(1 + i % 12)
+        if i % 3 == 1:
+            d[:8] = b"\x07" * 8
+        out.append(bytes(d))
+    return out
+
+
+def _scenario(case: str, rng) -> list[tuple]:
+    """Operations: ("batch", digests, carrier, offsets), ("remove",
+    digest), ("merge",)."""
+    def batch(digs, carrier):
+        return ("batch", digs, carrier, list(rng.integers(0, 1 << 40,
+                                                          len(digs))))
+    if case == "random":
+        pool = _random_digests(rng, 6000)
+        return [batch([pool[j] for j in rng.integers(0, len(pool), k)],
+                      f"f{i}") for i, k in enumerate((1, 1280, 3000, 17,
+                                                      2500))]
+    if case == "duplicates_within":
+        d = _random_digests(rng, 300)
+        return [batch(d + d[::-1] + d[:50], "a"), batch(d[100:] * 3, "b")]
+    if case == "base_and_delta":
+        d = _random_digests(rng, 4000)
+        return [batch(d[:2000], "base"), ("merge",), batch(d[2000:2500], "delta"),
+                batch(d[1500:2100] + d[2400:3000] + d[:10], "mixed")]
+    if case == "tombstones":
+        d = _random_digests(rng, 1000)
+        return ([batch(d[:800], "old"), ("merge",)]
+                + [("remove", x) for x in d[::7]]
+                + [batch(d[900:950], "delta")]
+                + [("remove", x) for x in d[900:950:5]]
+                + [batch(d, "new")])
+    if case == "nul_tails_and_shared_prefixes":
+        d = _nul_and_prefix_digests(rng, 900)
+        return [batch(d[:400], "a"), ("merge",), batch(d[300:700], "b"),
+                ("remove", d[0]), ("remove", d[301]), batch(d, "c")]
+    if case == "small_batches_over_runs":
+        # batches under _ONE_BY_ONE, one digest at a time, over runs that
+        # hold shared prefixes, NUL tails and tombstones
+        d = _nul_and_prefix_digests(rng, 3000)
+        ops = [batch(d[:1000], "base"), ("merge",), batch(d[1000:1600], "run")]
+        ops += [("remove", x) for x in d[::11]]
+        for i, k in enumerate((1, 17, 200, 255, 3, 90)):
+            pick = [d[j] for j in rng.integers(0, 2400, k)]
+            ops.append(batch(pick + pick[:k // 3] + d[2400 + 100 * i:
+                                                     2400 + 100 * i + 5],
+                             f"s{i}"))
+        return ops + [("remove", d[2401]), batch(d[2300:2700], "big")]
+    if case == "empty":
+        d = _random_digests(rng, 50)
+        return [batch([], "none"), batch(d, "a"), batch([], "none")]
+    if case == "sets_off_a_merge":
+        d = _random_digests(rng, 70_000)
+        return [batch(d[:1000], "a"), batch(d[500:], "b")]
+    raise AssertionError(case)
+
+
+INSERT_CASES = ["random", "duplicates_within", "base_and_delta",
+                "tombstones", "nul_tails_and_shared_prefixes", "empty",
+                "sets_off_a_merge", "snapshot_v2", "small_batches_over_runs"]
+
+
+@pytest.mark.parametrize("case", INSERT_CASES)
+def test_insert_batch_equals_sequential_inserts(case, tmp_path):
+    rng = np.random.default_rng(INSERT_CASES.index(case))
+    ops = _scenario("tombstones" if case == "snapshot_v2" else case, rng)
+    batched, ref = ExactDigestIndex(), _FirstWriterWins()
+    # insert() one digest at a time beside it (a merge's worth of them is
+    # left to the referee alone: 65,536 single inserts take seconds)
+    one_by_one = None if case == "sets_off_a_merge" else ExactDigestIndex()
+    for op in ops:
+        if op[0] == "batch":
+            _, digs, carrier, offs = op
+            got = batched.insert_batch(b"".join(digs), carrier,
+                                       np.array(offs, dtype=np.int64))
+            assert got == ref.insert_batch(digs, carrier, offs)
+            if one_by_one is not None:
+                assert got == sum(one_by_one.insert(d, [carrier, int(o)])
+                                  for d, o in zip(digs, offs))
+        elif op[0] == "remove":
+            assert batched.remove(op[1]) == (ref.refs.pop(op[1], None)
+                                             is not None)
+            if one_by_one is not None:
+                one_by_one.remove(op[1])
+        else:
+            batched._merge()
+    if case == "sets_off_a_merge":
+        assert batched.merges == 1 and len(batched._base) >= 65536
+    if case == "snapshot_v2":
+        batched.save(str(tmp_path / "exact"))
+        batched = ExactDigestIndex.load(str(tmp_path / "exact"))
+    assert batched.insert_batches == (0 if case == "snapshot_v2" else
+                                      sum(op[0] == "batch" for op in ops))
+    for idx in (batched, one_by_one):
+        if idx is None:
+            continue
+        assert dict(idx.items()) == ref.refs
+        assert len(idx) == len(ref.refs)
+        seen = [d for op in ops if op[0] != "merge"
+                for d in (op[1] if op[0] == "batch" else [op[1]])]
+        probe = seen[::3] + _random_digests(rng, 20)
+        want = [ref.refs.get(d) for d in probe]
+        assert idx.lookup_batch(probe) == want
+        assert [idx.lookup(d) for d in probe[:200]] == want[:200]
 
 
 # ---------------------------------------------------------------------------

@@ -251,6 +251,59 @@ def test_trace_requests_bounce_and_untraced_replies_are_the_same(traced):
     assert traced["untraced_stall_us"] == 0
 
 
+def test_a_commit_and_the_merge_it_sets_off_are_spans_and_counters(
+        tmp_path):
+    """A commit's hold of the bookkeeping lock is ``fdfs.sidecar.commit``;
+    the exact index counts its batch and digests, and a batch that fills
+    the delta folds it into the base inside a ``fdfs.exact.merge``."""
+    from fastdfs_tpu.sidecar import DedupSidecar
+
+    sc = DedupSidecar(str(tmp_path / "s.sock"))
+    ready = threading.Event()
+    server = threading.Thread(target=sc.serve_forever, args=(ready,),
+                              daemon=True)
+    server.start()
+    try:
+        assert ready.wait(60)
+        rng = np.random.default_rng(29)
+
+        def upload(session: int, fid: str) -> int:
+            """One segment of 200 chunks of 1 KB, then the commit: the
+            stats once both are folded."""
+            data = rng.integers(0, 256, 200 * 1024, dtype=np.uint8).tobytes()
+            ends = [1024 * (i + 1) for i in range(200)]
+            body = (struct.pack(">qqq", session, 0, 200)
+                    + struct.pack(">200q", *ends) + data)
+            sends = read_stats(sc.socket_path)["span_n"].get(
+                "fdfs.sidecar.send", 0)
+            assert rpc(sc.socket_path, FP_CUTS, body, 600.0)[0] == 0
+            assert rpc(sc.socket_path, StorageCmd.DEDUP_COMMIT,
+                       f"commitchunks {session} {fid}".encode()) == (0, b"")
+            return folded(sc.socket_path, sends + 3)
+
+        first = upload(11, "group1/M00/00/00/one.bin")
+        assert (first["exact_insert_batches"], first["exact_inserted"],
+                first["exact_merges"]) == (1, 200, 0)
+        assert first["span_n"]["fdfs.sidecar.commit"] == 1
+        assert first["span_us"]["fdfs.sidecar.commit"] >= 0
+        assert "fdfs.exact.merge" not in first["span_n"]
+        # fill the delta to one row short of the merge, then commit
+        exact = sc.engine.exact
+        exact.insert_batch(rng.bytes(20 * (65536 - 201)), "filler",
+                           np.arange(65536 - 201))
+        assert exact._delta_rows == 65535 and exact.merges == 0
+        second = upload(12, "group1/M00/00/00/two.bin")
+        assert (second["exact_insert_batches"], second["exact_inserted"],
+                second["exact_merges"]) == (3, 65735, 1)
+        assert second["span_n"]["fdfs.sidecar.commit"] == 2
+        assert second["span_n"]["fdfs.exact.merge"] == 1
+        assert len(exact._base) == 65735 and exact._delta_rows == 0
+    finally:
+        sc.stop()
+        server.join(30)
+    assert not server.is_alive()
+
+
 def test_helper_costs_a_flag_test_when_no_trace_runs():
     acc = spans_mod.new_acc()
     with spans_mod.span("fdfs.test.a", acc, cmd=1) as s:
@@ -301,6 +354,11 @@ def test_cli_sidecar_trace_prints_the_deltas(traced):
                if ln.startswith("near-dup index:")]
     assert "near_rows" in near and "near_resident_bytes" in near
     assert "near_queries 0 in near_scans 0" in near
+    # the exact index's counters (nothing committed here)
+    (exact,) = [ln for ln in proc.stdout.splitlines()
+                if ln.startswith("exact index:")]
+    assert "exact_insert_batches 0, exact_inserted 0" in exact
+    assert "exact_merges 0" in exact
     # the erasure coding's counters (no stripe encoded here)
     (ec,) = [ln for ln in proc.stdout.splitlines()
              if ln.startswith("erasure coding:")]

@@ -55,14 +55,19 @@ def main() -> int:
     idx = ExactDigestIndex()
     rss0 = rss_mb()
 
-    # -- inserts (every digest new; carriers cycle over 1000 file ids) ----
+    # -- inserts as the sidecar's commits make them: one batch a file of
+    # 1,280 digests (10 MiB at 8 KB chunks), every digest new, carriers
+    # cycling over 1000 file ids ------------------------------------------
     t0 = time.perf_counter()
     max_pause = 0.0
-    batch = 100_000
+    batch, commit = 100_000, 1280
     for start in range(0, n, batch):
         t_b = time.perf_counter()
-        for i in range(start, min(start + batch, n)):
-            idx.insert(bytes(keys[i]), [f"f{i % 1000}", i])
+        end = min(start + batch, n)
+        for c in range(start, end, commit):
+            e = min(c + commit, end)
+            idx.insert_batch(digs[c:e].tobytes(), f"f{(c // commit) % 1000}",
+                             np.arange(c, e))
         max_pause = max(max_pause, time.perf_counter() - t_b)
     insert_s = time.perf_counter() - t0
     rss_after_insert = rss_mb()
@@ -119,7 +124,8 @@ def main() -> int:
         "snapshot_mb": round(size_mb, 1),
         "snapshot_save_seconds": round(save_s, 2),
         "snapshot_load_seconds": round(load_s, 2),
-        "note": "synthetic uniform 20B digests; carriers interned over "
+        "note": "synthetic uniform 20B digests inserted as commits of "
+                "1,280 (one insert_batch a file); carriers interned over "
                 "1000 file ids; rss delta includes the generator-side "
                 "probe lists",
     }
