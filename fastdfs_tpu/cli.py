@@ -1231,16 +1231,23 @@ def cmd_sidecar_trace(args: list[str]) -> int:
               tiles.items(), key=lambda t: int(t[0])) if n) or "none"))
     # the SHA-1 launches: blocks walked one after another (a tile under
     # 128 rows as far as its longest chunk) of the blocks of the tiles'
-    # widths, and the rows that held a chunk of the lanes launched
+    # widths, and the rows that held a chunk of the lanes launched; the
+    # pack of those tiles: rows copied by a call that let the interpreter
+    # go, and the bytes copied and zeroed
     launched = {k: after.get(k, 0) - before.get(k, 0) for k in (
         "sha1_grid_steps", "sha1_width_steps", "rows_placed",
-        "lanes_launched")}
+        "lanes_launched", "pack_rows", "pack_rows_released",
+        "pack_copied_bytes", "pack_zeroed_bytes")}
     print(f"sha1 launches: sha1_grid_steps {launched['sha1_grid_steps']} of "
           f"sha1_width_steps {launched['sha1_width_steps']}"
           + (f" ({launched['sha1_grid_steps'] / launched['sha1_width_steps']:.3f}"
              " of the widths walked)" if launched["sha1_width_steps"] else "")
           + f", {launched['rows_placed']} rows on "
-          f"{launched['lanes_launched']} lanes")
+          f"{launched['lanes_launched']} lanes; pack_rows "
+          f"{launched['pack_rows']} (pack_rows_released "
+          f"{launched['pack_rows_released']}), pack_copied_bytes "
+          f"{launched['pack_copied_bytes'] / 1e6:.1f} MB, pack_zeroed_bytes "
+          f"{launched['pack_zeroed_bytes'] / 1e6:.1f} MB")
     # the near-dup index on the device: what it holds, and the passes
     # that answered these seconds' near_dups queries
     near = {k: after.get(k, 0) - before.get(k, 0) for k in (

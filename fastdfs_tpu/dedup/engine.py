@@ -328,6 +328,67 @@ def tile_plan(lengths, min_size: int, max_size: int, row_tile: int
     return plan
 
 
+@functools.lru_cache(maxsize=64)
+def _zeros(n: int) -> memoryview:
+    """``n`` read-only zero bytes, shared by every thread: one a tile
+    width (the widths are a fixed set)."""
+    return memoryview(bytes(n))
+
+
+# Tiles from this width on are packed by numpy calls, which let the
+# interpreter go while they copy; narrower ones by a memmove a row that
+# keeps it.  A hand-off may cost a switch interval (5 ms) to win the
+# interpreter back while other threads are in Python, so a tile of
+# narrow rows copied by numpy costs its rows times the threads waiting;
+# a memmove holds the others out for its copy, so wide rows copied that
+# way lose the copies that would have overlapped.  A wide tile is
+# mostly padding (64 MiB cut at restic's 512K/1M/8M: 101 MB of 168 MB
+# shipped), so it is zeroed past its shortest chunk in one call before
+# its rows are copied: one hand-off a row and one a tile.  Measured on
+# an 8-core host (tools/bench_pack_convoy.py,
+# bench_artifacts/pack_convoy.json), a request's pack in ms/MB: 10 MiB
+# cut 2K/8K/64K, 20 threads and 2 more spinning in Python, 307 by numpy
+# a row and 13.4 by memmove; 64 MiB cut at restic's widths, 4 threads,
+# 0.43 this way against 2.54 by memmove every row, and 6.9 against 3.6
+# with 2 more threads spinning.  Rows this wide are at most 4 a MB.
+_RELEASE_ROW_BYTES = 256 << 10
+
+
+def _pack_tile(buf: np.ndarray, src: memoryview, spans, group, rows: int,
+               blen: int) -> tuple[np.ndarray, int]:
+    """Pack the chunks ``group`` (indices into ``spans``, ``(offset,
+    length)`` in the byte view ``src``) into the flat tile ``buf``
+    (``rows * blen`` bytes of any contents), in the layout both kernels
+    read: row ``r`` holds chunk ``group[r]`` and zeros to ``blen``, the
+    rows after the last chunk only zeros.  Returns the tile's ``lens``
+    (int32, one a row) and the rows copied by a call that let the
+    interpreter go (all of a tile ``_RELEASE_ROW_BYTES`` wide or wider,
+    none of a narrower one).  A narrow row's tail is zeroed from one
+    shared zero buffer; the empty rows by one call."""
+    lens = [spans[i][1] for i in group]
+    n = len(group)
+    at = 0
+    if blen < _RELEASE_ROW_BYTES:
+        released = 0
+        dst = memoryview(buf)
+        zeros = _zeros(blen)
+        for i in group:
+            off, ln = spans[i]
+            dst[at:at + ln] = src[off:off + ln]
+            dst[at + ln:at + blen] = zeros[:blen - ln]
+            at += blen
+    else:
+        released = n
+        buf.reshape(rows, blen)[:n, min(lens):] = 0
+        for i in group:
+            off, ln = spans[i]
+            buf[at:at + ln] = np.frombuffer(src[off:off + ln], np.uint8)
+            at += blen
+    if n < rows:
+        buf[n * blen:] = 0
+    return np.array(lens + [0] * (rows - n), dtype=np.int32), released
+
+
 def plan_shapes(cfg: DedupConfig) -> list[tuple[int, int]]:
     """Every ``(rows, blen)`` that ``tile_plan`` can emit at this
     geometry: what ``DedupEngine.warmup`` compiles."""
@@ -421,8 +482,14 @@ class DedupEngine:
         # ended early: walked / width is the share of that walk left
         # (ops/pallas_sha1.py:launch_geometry; the host path launches
         # nothing and counts the same arithmetic).
+        # And the pack's work (_pack_tile): rows packed, of them those
+        # copied by a call that let the interpreter go, chunk bytes
+        # copied, and bytes zeroed (the rows' tails and the rows after a
+        # tile's last chunk).
         self.launched = {"rows_placed": 0, "lanes_launched": 0,
-                         "sha1_grid_steps": 0, "sha1_width_steps": 0}
+                         "sha1_grid_steps": 0, "sha1_width_steps": 0,
+                         "pack_rows": 0, "pack_rows_released": 0,
+                         "pack_copied_bytes": 0, "pack_zeroed_bytes": 0}
         self._placed_lock = threading.Lock()
 
     def _count_placed(self, result, row_bytes: int) -> None:
@@ -527,7 +594,7 @@ class DedupEngine:
 
         digests = np.zeros((len(spans), 5), dtype=np.uint32)
         sigs = np.zeros((len(spans), cfg.num_perms), dtype=np.uint32)
-        arr = np.frombuffer(data, dtype=np.uint8)
+        src = memoryview(np.frombuffer(data, dtype=np.uint8))
 
         # A fixed set of (rows, blen) shapes, all compiled in warmup().
         # Transfer discipline (every device<->host transfer pays a fixed
@@ -575,15 +642,14 @@ class DedupEngine:
             if prev is not None:
                 with span("fdfs.engine.slot_wait", acc):
                     jax.block_until_ready(prev)
-            with span("fdfs.engine.pack", acc, True):
-                batch_buf = gear_cdc.staging_buffer(
-                    size, slot=slot).reshape(rows, blen)
-                batch_buf[:] = 0
-                lens = np.zeros(rows, dtype=np.int32)
-                for row, i in enumerate(group):
-                    off, ln = spans[i]
-                    batch_buf[row, :ln] = arr[off:off + ln]
-                    lens[row] = ln
+            with span("fdfs.engine.pack", acc, True,
+                      rows=len(group)) as packing:
+                staging = gear_cdc.staging_buffer(size, slot=slot)
+                lens, released = _pack_tile(staging, src, spans, group,
+                                            rows, blen)
+                copied = int(lens.sum())
+                packing.note(zeroed=size - copied)
+            batch_buf = staging.reshape(rows, blen)
             lanes, width_blocks = launch_geometry(rows, blen)
             _, blocks = launch_geometry(rows, blen, int(lens.max()))
             with span("fdfs.engine.dispatch", acc, rows=len(group),
@@ -592,6 +658,10 @@ class DedupEngine:
                 d, s = self._fingerprint_batch(batch_buf, lens)
             with self._placed_lock:
                 self.launched["rows_placed"] += len(group)
+                self.launched["pack_rows"] += len(group)
+                self.launched["pack_rows_released"] += released
+                self.launched["pack_copied_bytes"] += copied
+                self.launched["pack_zeroed_bytes"] += size - copied
                 self.launched["lanes_launched"] += lanes
                 self.launched["sha1_grid_steps"] += blocks
                 self.launched["sha1_width_steps"] += width_blocks

@@ -68,7 +68,10 @@ Opcodes
   under 128 rows ends at its longest chunk) and ``sha1_width_steps``
   (the blocks of the tiles' widths: walked / width is the share of the
   widths' walk that is left) — a reader learns from it whether the chip
-  did the work, which the daemon's fail-open path would otherwise hide.
+  did the work, which the daemon's fail-open path would otherwise hide;
+  and the tiles' pack: ``pack_rows``, ``pack_rows_released`` (rows
+  copied by a call that lets the interpreter go), ``pack_copied_bytes``
+  and ``pack_zeroed_bytes``.
   ``trace start <dir>`` / ``trace stop`` start and stop a JAX profiler
   trace of this process (the one that holds the chip) into ``<dir>``:
   device operations and the ``fdfs.*`` spans on one clock.  Both are

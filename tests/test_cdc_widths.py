@@ -367,7 +367,14 @@ def test_dispatch_counts_rows_lanes_and_blocks(monkeypatch, tile_bound):
         "rows_placed": len(spans),
         "lanes_launched": sum(lanes for lanes, _ in geo),
         "sha1_grid_steps": _walked(plan, lens),
-        "sha1_width_steps": sum(blocks for _, blocks in geo)}
+        "sha1_width_steps": sum(blocks for _, blocks in geo),
+        "pack_rows": len(spans),
+        "pack_rows_released": sum(
+            len(group) for _, blen, group in plan
+            if blen >= engine_mod._RELEASE_ROW_BYTES),
+        "pack_copied_bytes": len(data),
+        "pack_zeroed_bytes": sum(rows * blen for rows, blen, _ in plan)
+        - len(data)}
     assert eng.launched["sha1_grid_steps"] < eng.launched["sha1_width_steps"]
 
 

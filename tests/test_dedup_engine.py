@@ -417,3 +417,86 @@ def test_warmup_dispatches_exactly_the_shapes_the_plan_can_emit(monkeypatch):
         emitted |= {(rows, blen) for rows, blen, _ in tile_plan(
             lens, SHIPPED.min_size, SHIPPED.max_size, SHIPPED.row_tile)}
     assert emitted == set(seen)
+
+
+# (rows, blen, chunk lengths of the body, the chunks the tile holds in
+# row order).  "narrower_bucket": a full 1024-wide tile whose rows are
+# mostly under half its width; "fewer_chunks": 3 chunks on 64 rows;
+# "ends_at_body_end": the body's last chunk in the tile, beside one as
+# wide as the tile; "serial_width": restic's widths, a tile of 8 rows of
+# 4 MiB (a serial width: few rows of megabytes), packed by numpy, with a
+# chunk of 100 KiB beside three of megabytes and four empty rows.
+PACK_CASES = {
+    "narrower_bucket": (8, 1024, [700, 100, 300, 65, 511, 900, 3, 128],
+                        [1, 0, 3, 5, 6, 7, 2, 4]),
+    "fewer_chunks": (64, 1024, [513, 1024, 640, 77, 999, 1000, 800, 700],
+                     [2, 5, 7]),
+    "ends_at_body_end": (8, 4096, [4096, 1000, 2500], [2, 0]),
+    "serial_width": (8, 4 << 20, [600 << 10, 4 << 20, 1536 << 10, 100 << 10],
+                     [3, 0, 2, 1]),
+}
+
+
+@pytest.mark.parametrize("case", sorted(PACK_CASES))
+def test_pack_tile_writes_the_layout_the_kernels_read(case):
+    """Into a slot that held other bytes: each row its chunk, then zeros
+    to the width; the rows after the last chunk all zeros."""
+    rows, blen, lens, group = PACK_CASES[case]
+    data = _rand(np.random.RandomState(len(case)), sum(lens))
+    spans = list(zip(np.cumsum([0] + lens[:-1]).tolist(), lens))
+    want = np.zeros((rows, blen), np.uint8)
+    for row, i in enumerate(group):
+        off, ln = spans[i]
+        want[row, :ln] = np.frombuffer(data, np.uint8, ln, off)
+    buf = np.full(rows * blen, 0xAB, np.uint8)
+    src = memoryview(np.frombuffer(data, np.uint8))
+    got, released = engine_mod._pack_tile(buf, src, spans, group, rows, blen)
+    assert buf.tobytes() == want.tobytes()
+    assert got.dtype == np.int32
+    assert got.tolist() == [lens[i] for i in group] + [0] * (rows - len(group))
+    # a tile this wide has its rows copied by calls that let the
+    # interpreter go, a narrower one none
+    if case == "serial_width":
+        assert engine_mod._serial_width(256, blen)
+        assert blen >= engine_mod._RELEASE_ROW_BYTES and released == 4
+    else:
+        assert blen < engine_mod._RELEASE_ROW_BYTES and released == 0
+
+
+def test_requests_in_a_row_reuse_dirty_slots_and_stay_exact(monkeypatch):
+    """Two requests on one thread: the second's tiles land in the staging
+    slots the first left full of its bytes, with shorter chunks and empty
+    rows; every tile dispatched is zero past its rows' lengths (the
+    kernels' contract: the host path here hashes each row's chunk alone,
+    the device's SHA-1 reads the padding), and the digests are hashlib's
+    and the signatures minhash_batch's over clean rows."""
+    cfg = DedupConfig(min_size=64, avg_bits=8, max_size=1024, row_tile=16,
+                      use_pallas=False)
+    eng = DedupEngine(cfg)
+    real = DedupEngine._fingerprint_batch
+
+    def zero_past_lens(self, batch, lens):
+        past = np.arange(batch.shape[1]) >= np.asarray(lens)[:, None]
+        assert not batch[past].any()
+        return real(self, batch, lens)
+    monkeypatch.setattr(DedupEngine, "_fingerprint_batch", zero_past_lens)
+    rng = np.random.RandomState(43)
+    first = [1024] * 40 + [128] * 20
+    second = [int(x) for x in rng.randint(513, 700, size=37)] + [65, 100]
+    for lens in (first, second):
+        data = _rand(rng, sum(lens))
+        spans, digests, sigs = eng.fingerprint(
+            data, cuts=np.cumsum(lens).tolist())
+        raw = digests.astype(">u4").tobytes()
+        clean = np.zeros((len(lens), max(lens)), np.uint8)
+        for i, (off, ln) in enumerate(spans):
+            assert raw[i * 20:(i + 1) * 20] == hashlib.sha1(
+                data[off:off + ln]).digest(), i
+            clean[i, :ln] = np.frombuffer(data, np.uint8, ln, off)
+        np.testing.assert_array_equal(sigs, np.asarray(M.minhash_batch(
+            clean, np.array(lens, np.int32), cfg.num_perms, cfg.shingle)))
+        if lens is first:
+            keys = gear_cdc.staging_buffer_stats()["keys"]
+    # the second request packed into the first's buffers, not new ones
+    assert gear_cdc.staging_buffer_stats()["keys"] == keys
+    assert eng.launched["pack_rows"] == len(first) + len(second)

@@ -170,8 +170,14 @@ def test_every_span_of_the_table_lies_inside_its_request(traced, cmd):
                and a["width_blocks"] > a["blen"] // 64 for a in launches)
     if cmd == FP_CUTS:      # every one of the 600 chunks on one row
         assert sum(a["rows"] for a in launches) == 600
+    # a pack carries the chunks it packed and the bytes it zeroed
+    packs = [e[4] for e in inside if e[0] == "fdfs.engine.pack"]
+    assert all(set(a) == {"rows", "zeroed"} for a in packs)
+    assert [a["rows"] for a in packs] == [a["rows"] for a in launches]
+    if cmd == FP_CUTS:      # three tiles of 256 x 2 KiB hold 600 chunks
+        assert sum(a["zeroed"] for a in packs) == (3 * 256 - 600) * 2048
     assert all(set(e[4]) <= {"cmd", "bytes"} for e in inside
-               if e[0] != "fdfs.engine.dispatch")
+               if e[0] not in ("fdfs.engine.dispatch", "fdfs.engine.pack"))
     # recv before the root and send after it, on the same thread
     wire = {e[0]: e for e in traced["events"] if e[1] == thread
             and e[0] in ("fdfs.sidecar.recv", "fdfs.sidecar.send")
@@ -235,6 +241,19 @@ def test_stats_fold_the_spans_and_keep_the_old_keys(traced):
     assert after["span_n"]["fdfs.engine.fingerprint"] == 2
     assert after["span_n"]["fdfs.sidecar.verify"] == 1
     assert after["span_n"]["fdfs.engine.slot_wait"] == 1
+    # the pack's counters: every chunk's row once, its bytes copied, and
+    # zeroed what its pack spans say (tails and empty rows: rows x width
+    # of the tiles less the bytes copied); no tile is wide enough to be
+    # packed by calls that let the interpreter go
+    p = {k: after[k] - before[k] for k in (
+        "pack_rows", "pack_rows_released", "pack_copied_bytes",
+        "pack_zeroed_bytes", "rows_placed")}
+    assert p["pack_rows"] == p["rows_placed"] == d["chunks"]
+    assert p["pack_copied_bytes"] == d["fingerprint_bytes"]
+    assert p["pack_zeroed_bytes"] == sum(
+        e[4]["zeroed"] for e in traced["events"]
+        if e[0] == "fdfs.engine.pack") > 0
+    assert p["pack_rows_released"] == 0
     # wall >= CPU by construction of the helper, up to the clocks' grain
     assert d["host_stall_us"] >= -50 * 16
     assert after["memory_peak_bytes"] >= 0 and after["backend"] == "cpu"
@@ -380,3 +399,11 @@ def test_cli_sidecar_trace_prints_the_deltas(traced):
                if ln.startswith("sha1 launches:")]
     walked, width = int(sha1.split()[3]), int(sha1.split()[6])
     assert 0 < walked <= width and "of the widths walked)" in sha1
+    # ... and the pack of those rows: no tile wide enough to let the
+    # interpreter go, every row's chunk copied, tails zeroed
+    launch, pack = sha1.split("; ")
+    placed, pack = int(launch.split()[-5]), pack.split()
+    assert pack[:4] == ["pack_rows", str(placed), "(pack_rows_released",
+                        "0),"]
+    assert pack[4] == "pack_copied_bytes" and float(pack[5]) > 0
+    assert pack[7] == "pack_zeroed_bytes" and float(pack[8]) > 0
