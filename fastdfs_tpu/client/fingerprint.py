@@ -21,7 +21,7 @@ own, as the daemon does (a segment end is a cut):
   where the batched kernel amortizes the device round-trip.
 
 A sidecar-mode node holds the recipe to its own cut of the stored bytes
-before it acknowledges (``server.cc:ReindexRecovered``) and re-verifies
+before it acknowledges (``native/storage/ingest.cc:AssembleCommit``) and re-verifies
 SHA1(payload) == digest of every shipped chunk, so a caller getting this
 wrong cannot corrupt the store: it is refused and uploads plain.
 """

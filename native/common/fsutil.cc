@@ -2,6 +2,7 @@
 
 #include <errno.h>
 #include <sys/stat.h>
+#include <unistd.h>
 
 namespace fdfs {
 
@@ -38,6 +39,18 @@ bool ReadWholeFile(const std::string& path, std::string* out) {
   bool ok = !ferror(f);
   fclose(f);
   return ok;
+}
+
+bool PreadFull(int fd, char* dst, int64_t len, int64_t off) {
+  int64_t got = 0;
+  while (got < len) {
+    ssize_t r = pread(fd, dst + got, static_cast<size_t>(len - got),
+                      off + got);
+    if (r < 0 && errno == EINTR) continue;
+    if (r <= 0) return false;
+    got += r;
+  }
+  return true;
 }
 
 }  // namespace fdfs

@@ -140,7 +140,7 @@ struct StageTrace {
 const char* const* StageArgNames(Stage s);
 
 // The recorder of the request this thread is working on, for code that
-// has no Conn at hand (dedup.cc; ChunkedStoreWith).  Null outside a
+// has no Conn at hand (dedup.cc; ingest.cc).  Null outside a
 // request's dio work (recovery, replication senders): StageScope then
 // does nothing.
 StageTrace* CurrentStageTrace();

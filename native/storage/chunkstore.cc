@@ -586,18 +586,9 @@ bool ChunkStore::ReadChunkSlice(const std::string& digest_hex,
     return slab_->ReadSlice(kSlabKindChunk, digest_hex, offset, len, dst);
   int fd = open(ChunkPath(digest_hex).c_str(), O_RDONLY);
   if (fd >= 0) {
-    int64_t got = 0;
-    while (got < len) {
-      ssize_t r = pread(fd, dst + got, static_cast<size_t>(len - got),
-                        offset + got);
-      if (r <= 0) {
-        close(fd);
-        return false;
-      }
-      got += r;
-    }
+    const bool ok = PreadFull(fd, dst, len, offset);
     close(fd);
-    return true;
+    return ok;
   }
   // EC cold tier: positional reads are offset math over 1-2 data
   // shards (no decode on the healthy path).

@@ -140,7 +140,7 @@ struct StorageConfig {
   // ec_k = 0 (default) disables demotion entirely (existing stripes
   // still serve, repair, and drain).  ec_demote_age_s: chunk payload
   // mtime age before scrub stage 5 may demote it; 0 = cold at once,
-  // erasure coding on write (StorageServer::EcOnWrite).  ec_bandwidth_mb_s:
+  // erasure coding on write (StorageServer::IngestFor).  ec_bandwidth_mb_s:
   // demote/repair IO pace, a SEPARATE token bucket from
   // scrub_bandwidth_mb_s (0 = unlimited).
   int ec_k = 0;

@@ -260,19 +260,19 @@ DedupPlugin::Verdict SidecarDedup::Judge(const std::string& sha1_hex, int64_t) {
   return v;
 }
 
-void SidecarDedup::Commit(const std::string& sha1_hex,
-                          const std::string& file_id) {
+void SidecarDedup::Notify(const std::string& body) {
   std::string resp;
   uint8_t status = 0;
-  Rpc(static_cast<uint8_t>(StorageCmd::kDedupCommit),
-      "commitfile " + sha1_hex + " " + file_id, &resp, &status);
+  Rpc(static_cast<uint8_t>(StorageCmd::kDedupCommit), body, &resp, &status);
+}
+
+void SidecarDedup::Commit(const std::string& sha1_hex,
+                          const std::string& file_id) {
+  Notify("commitfile " + sha1_hex + " " + file_id);
 }
 
 void SidecarDedup::Forget(const std::string& file_id) {
-  std::string resp;
-  uint8_t status = 0;
-  Rpc(static_cast<uint8_t>(StorageCmd::kDedupCommit),
-      std::string("forget ") + file_id, &resp, &status);
+  Notify("forget " + file_id);
 }
 
 // Sessions scope the sidecar's pending per-upload state.  The id embeds
@@ -347,25 +347,15 @@ bool SidecarDedup::FingerprintChunks(int64_t session, const char* data,
 }
 
 void SidecarDedup::CommitChunked(int64_t session, const std::string& file_id) {
-  std::string resp;
-  uint8_t status = 0;
-  Rpc(static_cast<uint8_t>(StorageCmd::kDedupCommit),
-      "commitchunks " + std::to_string(session) + " " + file_id, &resp,
-      &status);
+  Notify("commitchunks " + std::to_string(session) + " " + file_id);
 }
 
 void SidecarDedup::AbortChunked(int64_t session) {
-  std::string resp;
-  uint8_t status = 0;
-  Rpc(static_cast<uint8_t>(StorageCmd::kDedupCommit),
-      "abort " + std::to_string(session), &resp, &status);
+  Notify("abort " + std::to_string(session));
 }
 
 void SidecarDedup::ForgetChunked(const std::string& file_id) {
-  std::string resp;
-  uint8_t status = 0;
-  Rpc(static_cast<uint8_t>(StorageCmd::kDedupCommit),
-      std::string("forget ") + file_id, &resp, &status);
+  Notify("forget " + file_id);
 }
 
 bool SidecarDedup::NearDups(const std::string& file_id, std::string* out,

@@ -62,6 +62,17 @@ class DedupPlugin {
   virtual bool Save() { return true; }   // snapshot (checkpoint/resume)
   virtual const char* Name() const = 0;
 
+  // What the plugin is: an instance of its own for another thread
+  // (recovery, scrub), or null to share this one; whether it keeps an
+  // index beside the chunk store (near-dup signatures, attributions) that
+  // bytes stored without its fingerprint (a negotiated commit, a recovered
+  // recipe) must be re-indexed into; whether EcEncode computes parity.
+  virtual std::unique_ptr<DedupPlugin> ForAnotherThread() const {
+    return nullptr;
+  }
+  virtual bool IndexesBesideChunkStore() const { return false; }
+  virtual bool ComputesParity() const { return false; }
+
   // -- chunk granularity -------------------------------------------------
   // One chunked upload = one SESSION: BeginChunked() mints an id that
   // scopes all pending fingerprint state (file signature, digest
@@ -174,6 +185,11 @@ class SidecarDedup : public DedupPlugin {
   void Commit(const std::string& sha1_hex, const std::string& file_id) override;
   void Forget(const std::string& file_id) override;
   const char* Name() const override { return "sidecar"; }
+  std::unique_ptr<DedupPlugin> ForAnotherThread() const override {
+    return std::make_unique<SidecarDedup>(socket_path_, 0, widths_);
+  }
+  bool IndexesBesideChunkStore() const override { return true; }
+  bool ComputesParity() const override { return true; }
   int64_t BeginChunked() override;
   bool FingerprintChunks(int64_t session, const char* data, size_t len,
                          int64_t base_offset,
@@ -212,6 +228,8 @@ class SidecarDedup : public DedupPlugin {
            const int64_t* fp_rpc_args = nullptr);
   // The first exchange on a fresh connection; false = refused or dead.
   bool Handshake(int fd);
+  // A DEDUP_COMMIT whose answer nothing waits on (commit, abort, forget).
+  void Notify(const std::string& body);
   std::string socket_path_;
   const int max_idle_fds_;
   const CdcWidths widths_;

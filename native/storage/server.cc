@@ -3,7 +3,6 @@
 #include <errno.h>
 #include <fcntl.h>
 #include <string.h>
-#include <sys/mman.h>
 #include <sys/sendfile.h>
 #include <sys/socket.h>
 #include <sys/stat.h>
@@ -16,11 +15,13 @@
 #include <cstdio>
 
 #include "common/fileid.h"
+#include "common/fsutil.h"
 #include "common/healthmon.h"
 #include "common/log.h"
 #include "common/profiler.h"
 #include "common/protocol_gen.h"
 #include "common/threadreg.h"
+#include "storage/ingest.h"
 
 namespace fdfs {
 
@@ -32,90 +33,22 @@ namespace {
 constexpr int64_t kBinlogRotateSize = 64LL << 20;
 constexpr size_t kIoBufSize = 256 * 1024;
 
-// SHA-1 of the first `size` bytes of `path`; nullopt when they cannot be
-// read or the file is shorter.
-std::optional<Sha1Digest> Sha1OfFile(const std::string& path, int64_t size) {
+// SHA-1 of the first `size` bytes of `path`, a segment at a time in the
+// kept buffer; nullopt when they cannot be read or the file is shorter.
+std::optional<Sha1Digest> Sha1OfFile(const std::string& path, int64_t size,
+                                     int64_t segment_bytes) {
   int fd = open(path.c_str(), O_RDONLY);
   if (fd < 0) return std::nullopt;
   Sha1Stream sha1;
-  char buf[kIoBufSize];
-  while (size > 0) {
-    ssize_t r = read(fd, buf, std::min<int64_t>(size, sizeof(buf)));
-    if (r < 0 && errno == EINTR) continue;
-    if (r <= 0) break;
-    sha1.Update(buf, static_cast<size_t>(r));
-    size -= r;
-  }
+  const bool ok = WalkSegments(size, segment_bytes,
+                               [&](int64_t base, char* seg, int64_t len) {
+    if (!PreadFull(fd, seg, len, base)) return false;
+    sha1.Update(seg, static_cast<size_t>(len));
+    return true;
+  });
   close(fd);
-  if (size > 0) return std::nullopt;
+  if (!ok) return std::nullopt;
   return sha1.Final();
-}
-
-// The segment buffer a thread keeps from one upload to the next.
-struct SegmentBuf {
-  std::unique_ptr<char[]> buf;
-  int64_t cap = 0;
-  int64_t written = 0;  // from the start, since the last ReleaseTmpSegment
-};
-thread_local SegmentBuf t_seg;
-
-// Room for one segment of `len` bytes, for the chunker, the fingerprint
-// call and the chunk store to read: the buffer the calling thread keeps
-// from one segment to the next.  It grows to the largest segment the
-// thread has seen (at most dedup_segment_bytes) and is never zero-filled,
-// so its pages are faulted in once a thread and not once an upload: a
-// fresh 64 MB std::string a segment cost 2 ms per MB of upload.  Mapping
-// the tmp file instead read lower here and 26 ms per MB dearer in the
-// send to the sidecar (PERF.md section 6, PR 34).
-char* KeptSegment(int64_t len) {
-  SegmentBuf& s = t_seg;
-  if (s.cap < len) {
-    s.cap = 0;  // a throwing new leaves no capacity behind a null buffer
-    s.written = 0;
-    s.buf.reset();  // the old one first: never both resident
-    s.buf.reset(new char[static_cast<size_t>(len)]);
-    s.cap = len;
-  }
-  s.written = std::max(s.written, len);
-  return s.buf.get();
-}
-
-// Bytes [off, off + len) of a file, all of them or false.
-bool PreadFull(int fd, char* dst, int64_t len, int64_t off) {
-  int64_t got = 0;
-  while (got < len) {
-    ssize_t r = pread(fd, dst + got, static_cast<size_t>(len - got),
-                      off + got);
-    if (r < 0 && errno == EINTR) continue;
-    if (r <= 0) return false;
-    got += r;
-  }
-  return true;
-}
-
-// Bytes [off, off + len) of an upload's tmp file in the kept buffer, or
-// nullptr when they cannot be read.
-const char* ReadTmpSegment(int fd, int64_t off, int64_t len) {
-  char* seg = KeptSegment(len);
-  return PreadFull(fd, seg, len, off) ? seg : nullptr;
-}
-
-// After an upload's last segment: the buffer's bytes are dead, and the
-// kernel may take their pages when it needs memory, without swapping
-// them.  Until it does they stay mapped and the next upload writes over
-// them without a fault; after, that upload faults them in again, as
-// every upload did before there was a kept buffer.  So what idle workers
-// hold is a loan and not a reservation (OPERATIONS.md, "Host memory").
-void ReleaseTmpSegment() {
-#ifdef MADV_FREE
-  SegmentBuf& s = t_seg;
-  const uintptr_t page = static_cast<uintptr_t>(sysconf(_SC_PAGESIZE));
-  uintptr_t lo = reinterpret_cast<uintptr_t>(s.buf.get());
-  uintptr_t hi = (lo + static_cast<uintptr_t>(s.written)) & ~(page - 1);
-  lo = (lo + page - 1) & ~(page - 1);
-  if (hi > lo) madvise(reinterpret_cast<void*>(lo), hi - lo, MADV_FREE);
-  s.written = 0;
-#endif
 }
 
 // Per-chunk payload cap, shared by FETCH_CHUNK serving and the
@@ -509,16 +442,10 @@ bool StorageServer::Init(std::string* error) {
     // Recovered files dedup exactly like synced/uploaded ones: a rebuilt
     // node must not silently lose chunk-level dedup (its chunk store
     // would stay empty while peers dedup).  The hook runs on the
-    // recovery thread, so it gets its OWN plugin instance (ChunkStore is
-    // internally locked; the plugins are not).
+    // recovery thread, so it gets its own plugin instance where the
+    // plugin wants one per thread (ChunkStore is internally locked).
     if (dedup_ != nullptr && !chunk_stores_.empty()) {
-      // Only the sidecar plugin needs a per-thread instance (it owns a
-      // socket fd); CpuDedup's FingerprintChunks is stateless, and a
-      // second CpuDedup would pointlessly re-load the digest snapshot.
-      if (cfg_.dedup_mode == "sidecar")
-        recovery_dedup_ = MakeDedupPlugin(cfg_.dedup_mode, cfg_.base_path,
-                                          cfg_.dedup_sidecar, 0,
-                                          cfg_.cdc_widths);
+      recovery_dedup_ = dedup_->ForAnotherThread();
       DedupPlugin* rec_plugin =
           recovery_dedup_ != nullptr ? recovery_dedup_.get() : dedup_.get();
       recovery_->SetChunkedStore(
@@ -527,10 +454,9 @@ bool StorageServer::Init(std::string* error) {
             auto local = LocalPath(store_.store_path(spi), remote);
             if (!local.has_value()) return false;
             int64_t saved = 0, hits = 0;
-            return ChunkedStoreWith(rec_plugin, tmp, spi, size,
-                                    *local + ".rcp",
-                                    cfg_.group_name + "/" + remote, &saved,
-                                    &hits);
+            return StoreChunked(IngestFor(spi, rec_plugin, nullptr), tmp,
+                                size, *local + ".rcp",
+                                cfg_.group_name + "/" + remote, &saved, &hits);
           },
           cfg_.dedup_chunk_threshold);
       // Chunk-aware rebuild: pull the peer's recipe and only the chunk
@@ -574,7 +500,7 @@ bool StorageServer::Init(std::string* error) {
             *chunks_local = static_cast<int64_t>(done.chunks.size());
             *chunks_fetched = static_cast<int64_t>(missing.size());
             // Pass 2: fetch the misses in bounded batches.
-            std::string payloads;
+            std::string payloads, err;
             size_t i = 0;
             while (i < missing.size()) {
               std::vector<RecipeEntry> want;
@@ -587,37 +513,22 @@ bool StorageServer::Init(std::string* error) {
               if (!fetch_chunks(want, &payloads)) return fail();
               size_t off = 0;
               for (const RecipeEntry& e : want) {
-                // Content-addressed store: verify the payload IS its
-                // digest before admitting it, or a bit-rotted peer
-                // chunk would poison every future dedup hit.
-                if (Sha1(payloads.data() + off,
-                         static_cast<size_t>(e.length))
-                        .Hex() != e.digest_hex) {
+                const Admission a =
+                    AdmitChunk(cs, e, payloads.data() + off, &done, &err);
+                if (a == Admission::kNotItsDigest)
                   FDFS_LOG_WARN("recovery: chunk %s failed digest check",
                                 e.digest_hex.c_str());
-                  return fail();
-                }
-                bool existed = false;
-                std::string err;
-                if (!cs->PutAndRef(e.digest_hex, payloads.data() + off,
-                                   static_cast<size_t>(e.length), &existed,
-                                   &err))
-                  return fail();
-                done.chunks.push_back(e);
+                if (a != Admission::kAdmitted) return fail();
                 off += static_cast<size_t>(e.length);
               }
             }
-            std::string err;
             if (!cs->StoreRecipe(*local + ".rcp", r, &err)) return fail();
-            // Sidecar mode: re-register the file with the dedup engine
-            // (near-dup signature + attributions) exactly as an upload
-            // would — zero extra wire, the bytes are local now.  The
-            // cpu plugin keeps its index in the chunk store itself, so
-            // re-fingerprinting there would be pure waste.
-            if (rec_plugin != nullptr &&
-                std::string(rec_plugin->Name()) == "sidecar")
-              ReindexRecovered(rec_plugin, *local,
-                               cfg_.group_name + "/" + remote);
+            // Re-register the file with a plugin that indexes beside the
+            // chunk store (near-dup signature + attributions) exactly as
+            // an upload would — zero extra wire, the bytes are local now.
+            if (rec_plugin->IndexesBesideChunkStore())
+              ReindexFromRecipe(IngestFor(spi, rec_plugin, nullptr), r,
+                                cfg_.group_name + "/" + remote);
             return true;
           });
     }
@@ -634,9 +545,7 @@ bool StorageServer::Init(std::string* error) {
   // runs when SCRUB_KICK forces a pass, so operators and tests can
   // drive deterministic passes on an otherwise-idle daemon.
   if (!chunk_stores_.empty()) {
-    if (cfg_.dedup_mode == "sidecar")
-      scrub_dedup_ = MakeDedupPlugin(cfg_.dedup_mode, cfg_.base_path,
-                                     cfg_.dedup_sidecar, 0, cfg_.cdc_widths);
+    scrub_dedup_ = dedup_->ForAnotherThread();
     ScrubOptions sopts;
     sopts.interval_s = cfg_.scrub_interval_s;
     sopts.bandwidth_bytes_s =
@@ -1126,11 +1035,11 @@ void StorageServer::InitStatsRegistry() {
   ctr_fallback_rehash_ = registry_.Counter("upload.fallback_rehash");
   registry_.GaugeFn("crc32.impl",
                     [] { return static_cast<int64_t>(Crc32Chosen()); });
-  ctr_dedup_chunk_hits_ = registry_.Counter("dedup.chunk_hits");
-  ctr_dedup_chunk_misses_ = registry_.Counter("dedup.chunk_misses");
-  ctr_ec_write_stripes_ = registry_.Counter("ec.write_stripes");
-  ctr_ec_write_bytes_ = registry_.Counter("ec.write_bytes");
-  ctr_ec_encode_failures_ = registry_.Counter("ec.encode_failures");
+  ingest_ctrs_.chunk_hits = registry_.Counter("dedup.chunk_hits");
+  ingest_ctrs_.chunk_misses = registry_.Counter("dedup.chunk_misses");
+  ingest_ctrs_.ec_write_stripes = registry_.Counter("ec.write_stripes");
+  ingest_ctrs_.ec_write_bytes = registry_.Counter("ec.write_bytes");
+  ingest_ctrs_.ec_encode_failures = registry_.Counter("ec.encode_failures");
   // Negotiated uploads on the ingest edge (UPLOAD_RECIPE/UPLOAD_CHUNKS):
   // bytes_saved_wire counts chunk bytes the client never shipped because
   // the bitmap reported them present — the client-facing twin of
@@ -1147,9 +1056,9 @@ void StorageServer::InitStatsRegistry() {
   // ReadChunkSlices made for commits and the chunks those served (chunks
   // a batch is the reading; chunks that are not slab-resident take its
   // per-chunk fall-through and count in neither).
-  ctr_ingest_commit_read_batches_ =
+  ingest_ctrs_.commit_read_batches =
       registry_.Counter("ingest.commit_read_batches");
-  ctr_ingest_commit_read_chunks_ =
+  ingest_ctrs_.commit_read_chunks =
       registry_.Counter("ingest.commit_read_chunks");
   hist_ingest_negotiate_ = registry_.Histogram(
       "ingest.negotiate_us", StatsRegistry::LatencyBucketsUs());
@@ -1621,8 +1530,8 @@ void StorageServer::FillBeatStats(int64_t* out) {
   out[25] = recovery_ != nullptr ? recovery_->files_recovered() : 0;
   out[26] = ctr_chunkfetch_batches_ != nullptr
                 ? ctr_chunkfetch_batches_->load() : 0;
-  out[27] = ctr_dedup_chunk_misses_ != nullptr
-                ? ctr_dedup_chunk_misses_->load() : 0;
+  out[27] = ingest_ctrs_.chunk_misses != nullptr
+                ? ingest_ctrs_.chunk_misses->load() : 0;
   // Rebalance migrator progress (ISSUE 11): the tracker leader's
   // auto-retire decision reads slots 30 (pending) and 32 (done) from
   // every ACTIVE member of a draining group.
@@ -1749,7 +1658,7 @@ void StorageServer::OffloadToDio(Conn* c, int spi, std::function<void()> work) {
     c->stages.Add(Stage::kDioWait, c->work_start_us,
                   std::max(MonoUs(), c->work_start_us + 1));
     {
-      // dedup.cc and ChunkedStoreWith reach the recorder through this.
+      // dedup.cc and StoreChunked reach the recorder through this.
       StageTraceBinding stages(&c->stages);
       work();
     }
@@ -1852,8 +1761,6 @@ void StorageServer::ResetForNextRequest(Conn* c) {
   c->send_remaining = 0;
   c->rstream.reset();
   c->work_start_us = 0;
-  c->commit_read_batches = 0;
-  c->commit_read_chunks = 0;
   c->ingest_session = 0;
   c->ingest_chunks_total = 0;
   c->ingest_chunks_missing = 0;
@@ -2053,7 +1960,7 @@ void StorageServer::LogAccess(Conn* c, uint8_t status, int64_t bytes) {
     // (the assembled segment cut and fingerprinted for the file's
     // signature, the answer held against the client's recipe, the
     // fingerprint session committed; cdc and fp_lock are then shares of
-    // it); and ec = erasure coding on write (EcOnWrite): each segment's
+    // it); and ec = erasure coding on write (IngestFor): each segment's
     // new chunks found, laid into a stripe, its parity asked of the
     // sidecar, its shards and manifest written, the references taken (a
     // share of cswrite).  Columns are 0 when a stage did not occur;
@@ -2111,8 +2018,6 @@ void StorageServer::LogAccess(Conn* c, uint8_t status, int64_t bytes) {
   // response was built.
   c->req_start_us = 0;
   c->work_start_us = 0;
-  c->commit_read_batches = 0;
-  c->commit_read_chunks = 0;
   c->heat_key.clear();
   c->heat_op = 0;
 }
@@ -3368,9 +3273,10 @@ void StorageServer::SyncCreateComplete(Conn* c) {
       int spi = 0;
       sscanf(c->sync_remote.c_str(), "M%02X/", &spi);
       int64_t saved = 0, hits = 0;
-      if (StoreChunkedFromTmp(c->tmp_path, spi, st.st_size, local + ".rcp",
-                              cfg_.group_name + "/" + c->sync_remote,
-                              &saved, &hits)) {
+      if (StoreChunked(IngestFor(spi, dedup_.get(), &c->stages), c->tmp_path,
+                       st.st_size, local + ".rcp",
+                       cfg_.group_name + "/" + c->sync_remote, &saved,
+                       &hits)) {
         unlink(c->tmp_path.c_str());
         stats_.dedup_hits += hits;
         stats_.dedup_bytes_saved += saved;
@@ -3389,44 +3295,6 @@ void StorageServer::SyncCreateComplete(Conn* c) {
     Respond(c, 0);
     return;
   }
-}
-
-// Feed a recovered file's (locally assembled) bytes through the dedup
-// plugin in upload-sized segments so its near-dup signature and chunk
-// attributions re-enter the engine's indexes — a sidecar-mode rebuild
-// would otherwise leave every recovered file invisible to NEAR_DUPS
-// and un-forgettable on delete.  Best-effort: failures only cost index
-// coverage, never the recovered data.
-void StorageServer::ReindexRecovered(DedupPlugin* plugin,
-                                     const std::string& local,
-                                     const std::string& file_ref) {
-  int64_t size = 0;
-  int fd = OpenLogical(local, &size);
-  if (fd < 0) return;
-  const int64_t session = plugin->BeginChunked() | kDedupReindexSessionBit;
-  std::string seg;
-  int64_t base = 0;
-  bool ok = true;
-  while (ok && base < size) {
-    int64_t want = std::min<int64_t>(cfg_.dedup_segment_bytes, size - base);
-    seg.resize(static_cast<size_t>(want));
-    int64_t got = 0;
-    while (got < want) {
-      ssize_t r = read(fd, seg.data() + got, want - got);
-      if (r <= 0) break;
-      got += r;
-    }
-    std::vector<ChunkFp> fps;
-    ok = got == want &&
-         plugin->FingerprintChunks(session, seg.data(), seg.size(), base,
-                                   &fps);
-    base += want;
-  }
-  close(fd);
-  if (ok)
-    plugin->CommitChunked(session, file_ref);
-  else
-    plugin->AbortChunked(session);
 }
 
 // FETCH_RECIPE (128): serve a recipe-stored file's chunk list to a
@@ -3881,40 +3749,11 @@ bool StorageServer::BeginUploadChunks(Conn* c) {
   return true;
 }
 
-// Whether the node's own cut of one segment (its chunker's ends, the
-// plugin's SHA-1s: `fps`) is entries [first, first + n) of the client's
-// recipe, entry for entry.  If not, the client cut under other parameters
-// than this node's (or the plugin's digests are wrong) and the caller
-// rolls the commit back; the WARN names the first entry that differs.
-static bool NodeCutsEqualRecipe(const std::vector<ChunkFp>& fps,
-                                const Recipe& recipe, size_t first, size_t n,
-                                int64_t session) {
-  for (size_t k = 0; k < fps.size(); ++k) {
-    if (k < n && recipe.chunks[first + k].length == fps[k].length &&
-        recipe.chunks[first + k].digest_hex == fps[k].digest_hex)
-      continue;
-    FDFS_LOG_WARN("negotiated upload session %lld: at offset %lld this node "
-                  "cuts %lld bytes %s, the client's recipe entry %zu differs",
-                  static_cast<long long>(session),
-                  static_cast<long long>(fps[k].offset),
-                  static_cast<long long>(fps[k].length),
-                  fps[k].digest_hex.c_str(), first + k);
-    return false;
-  }
-  return fps.size() == n;
-}
-
 // UPLOAD_CHUNKS completion (dio worker): assemble the logical stream
 // once, segment by segment as this node would have cut it, in the buffer
-// the thread keeps, and let everything that needs the bytes read them
-// there.  Of each dedup_segment_bytes segment of the recipe: the shipped
-// chunks are read from the upload's tmp file into their place, each
-// verified to BE its claimed digest and written via PutAndRef; the
-// present ones are referenced and then read from the store into theirs
-// by ONE ReadChunkSlices call (slab-resident chunks many to a preadv);
-// the CRC is folded over the whole segment; and in sidecar mode the same
-// buffer goes through the fingerprint RPC, whose answer has to be the
-// client's recipe entries of this segment.  Then the file ID is minted
+// the thread keeps (AssembleCommit, ingest.h: shipped chunks admitted,
+// present ones read by one ReadChunkSlices a segment, the CRC folded, the
+// node's own fingerprint held to the recipe).  Then the file ID is minted
 // from the content's CRC exactly like UPLOAD_FILE, the recipe stored and
 // the fingerprint session committed.  All-or-nothing with ref rollback;
 // any failure makes the client fall back to a plain upload.
@@ -3952,175 +3791,35 @@ void StorageServer::UploadChunksComplete(Conn* c) {
     fail(5);
     return;
   }
-  // Sidecar mode keeps its near-dup/attribution index OUTSIDE the chunk
-  // store, and the client-side fingerprint pipeline never talked to it:
-  // each assembled segment goes through the plugin as an upload's would
-  // (the cpu plugin indexes in the chunk store itself, so fingerprinting
-  // there would be pure waste).  The same answer holds the client's
-  // recipe to this node's own cut of the content: a recipe cut under
-  // other parameters is rolled back before anything names the file, and
-  // the client uploads plain.  A sidecar that cannot be reached fails
-  // open as everywhere (no signature, the plugin's WARN line).
-  DedupPlugin* const fp_plugin =
-      dedup_ != nullptr && std::string(dedup_->Name()) == "sidecar"
-          ? dedup_.get()
-          : nullptr;
-  const int64_t fp_session =
-      fp_plugin != nullptr ? fp_plugin->BeginChunked() | kDedupReindexSessionBit
-                           : 0;
-  bool fp_ok = fp_plugin != nullptr;
-  Recipe done;  // refs taken so far (the rollback set, in no order)
-  int64_t saved = 0, hits = 0, missing = 0;
+  // A plugin that indexes beside the chunk store never saw the client's
+  // fingerprints: each assembled segment goes through it as an upload's
+  // would, and its answer holds the client's recipe to this node's own
+  // cut (a foreign cut is rolled back, the client uploads plain).  A
+  // sidecar that cannot be reached fails open as everywhere.
+  std::optional<FingerprintSession> fp;
+  if (dedup_->IndexesBesideChunkStore()) fp.emplace(dedup_.get(), true);
   // The file ID's crc32 is identity metadata every consumer may check
   // (trunk slots already do): computed server-side over the logical
   // stream — shipped chunks from the wire payload, present chunks read
   // back from the store (local-disk cost, still far below re-shipping)
   // — never the client's claim.
-  uint32_t crc = 0;
-  uint8_t status = 0;  // the commit's answer so far: 0, EIO or EINVAL
-  std::vector<ChunkStore::SliceReq> reads;
-  size_t first = 0;      // the segment's first recipe entry
-  int64_t base = 0;      // and its offset in the logical stream
-  int64_t tmp_off = 0;   // next shipped chunk's offset in the tmp file
-  while (status == 0 && base < recipe.logical_size) {
-    const int64_t seg_len = std::min<int64_t>(cfg_.dedup_segment_bytes,
-                                              recipe.logical_size - base);
-    // This node cuts every segment on its own, so its recipe has an
-    // entry ending at every segment end.  One that crosses it (or is
-    // longer than a segment) is a foreign cut, and has no place in the
-    // buffer either.
-    size_t end = first;
-    int64_t fill = 0;
-    while (end < recipe.chunks.size() &&
-           fill + recipe.chunks[end].length <= seg_len)
-      fill += recipe.chunks[end++].length;
-    if (fill != seg_len) {
-      FDFS_LOG_WARN("negotiated upload session %lld: the client's recipe "
-                    "entry %zu crosses offset %lld, where this node's cut "
-                    "of a %lld-byte segment ends",
-                    static_cast<long long>(s->id), end,
-                    static_cast<long long>(base + seg_len),
-                    static_cast<long long>(cfg_.dedup_segment_bytes));
-      status = 22;
-      break;
-    }
-    char* const seg = KeptSegment(seg_len);
-    // This segment's chunk-store share: verify, then present, inside it.
-    StageScope cs_write(&c->stages, Stage::kCsWrite);
-    StageScope verify(&c->stages, Stage::kVerify);
-    // Shipped entries first, so that a digest this commit ships is in
-    // the store before a later occurrence of it is read from there.
-    int64_t at = 0;  // the entry's place in the segment
-    for (size_t i = first; i < end; at += recipe.chunks[i].length, ++i) {
-      if (s->needed[i] == 0) continue;
-      const RecipeEntry& e = recipe.chunks[i];
-      ++missing;
-      // Content-addressed store: the payload must BE its claimed digest
-      // before PutAndRef (same check the replication receiver runs) —
-      // the client computed these digests, and a buggy or hostile one
-      // must not poison future dedup hits under this digest.
-      if (!PreadFull(tmp_fd, seg + at, e.length, tmp_off) ||
-          Sha1(seg + at, static_cast<size_t>(e.length)).Hex() !=
-              e.digest_hex) {
-        FDFS_LOG_WARN("negotiated upload: chunk %s failed digest check",
-                      e.digest_hex.c_str());
-        status = 5;
-        break;
-      }
-      tmp_off += e.length;
-      bool existed = false;
-      std::string err;
-      if (!s->cs->PutAndRef(e.digest_hex, seg + at,
-                            static_cast<size_t>(e.length), &existed, &err)) {
-        FDFS_LOG_ERROR("negotiated upload chunk store: %s", err.c_str());
-        status = 5;
-        break;
-      }
-      done.chunks.push_back(e);  // ref taken: in the rollback set
-    }
-    verify.End();
-    StageScope present(&c->stages, Stage::kPresent);
-    const int64_t batches0 = c->commit_read_batches;
-    const int64_t chunks0 = c->commit_read_chunks;
-    reads.clear();
-    at = 0;
-    for (size_t i = first; status == 0 && i < end;
-         at += recipe.chunks[i].length, ++i) {
-      if (s->needed[i] != 0) continue;
-      const RecipeEntry& e = recipe.chunks[i];
-      int64_t stored_len = -1;
-      if (!s->cs->RefOne(e.digest_hex, &stored_len)) {
-        // Deleted between the bitmap and this commit (the pin only
-        // defers the unlink, it does not preserve the reference):
-        // report failure and let the client re-send the whole payload.
-        FDFS_LOG_WARN("negotiated upload: chunk %s vanished before commit",
-                      e.digest_hex.c_str());
-        status = 5;
-        break;
-      }
-      done.chunks.push_back(e);
-      // The batched read checks bounds only: a stored chunk of another
-      // length than the recipe's is not the recipe's chunk.
-      if (stored_len != e.length) {
-        FDFS_LOG_WARN("negotiated upload: chunk %s is %lld bytes in the "
-                      "store, %lld in the recipe", e.digest_hex.c_str(),
-                      static_cast<long long>(stored_len),
-                      static_cast<long long>(e.length));
-        status = 5;
-        break;
-      }
-      reads.push_back({&e.digest_hex, 0, e.length, seg + at});
-      saved += e.length;
-      ++hits;
-    }
-    std::string unreadable;
-    if (status == 0 && !reads.empty() &&
-        !s->cs->ReadChunkSlices(reads.data(), reads.size(),
-                                &c->commit_read_batches,
-                                &c->commit_read_chunks, &unreadable)) {
-      FDFS_LOG_WARN("negotiated upload: chunk %s unreadable at commit",
-                    unreadable.c_str());
-      status = 5;
-    }
-    if (status != 0) break;
-    crc = Crc32(seg, static_cast<size_t>(seg_len), crc);
-    present.SetArgs(c->commit_read_chunks - chunks0,
-                    c->commit_read_batches - batches0);
-    present.End();
-    cs_write.End();
-    if (fp_ok) {
-      // The chunker, the lock wait and the RPC nest under it (dedup.cc).
-      StageScope reindex(&c->stages, Stage::kReindex);
-      std::vector<ChunkFp> fps;
-      fp_ok = fp_plugin->FingerprintChunks(
-          fp_session, seg, static_cast<size_t>(seg_len), base, &fps);
-      if (fp_ok &&
-          !NodeCutsEqualRecipe(fps, recipe, first, end - first, s->id))
-        status = 22;
-    }
-    first = end;
-    base += seg_len;
-  }
+  CommitAssembly a;
+  uint8_t status = AssembleCommit(IngestFor(s->spi, dedup_.get(), &c->stages),
+                                  recipe, s->needed, tmp_fd, s->id,
+                                  fp ? &*fp : nullptr, &a);
   close(tmp_fd);
   unlink(c->tmp_path.c_str());
   c->tmp_path.clear();
-  ReleaseTmpSegment();
-  c->ingest_chunks_missing = missing;
-  if (ctr_ingest_commit_read_batches_ != nullptr) {
-    ctr_ingest_commit_read_batches_->fetch_add(c->commit_read_batches,
-                                               std::memory_order_relaxed);
-    ctr_ingest_commit_read_chunks_->fetch_add(c->commit_read_chunks,
-                                              std::memory_order_relaxed);
-  }
+  c->ingest_chunks_missing = a.missing;
   // The recipe's write is the chunk store's too (the access log's cswrite
   // column is present + verify + recipe).
   StageScope cs_write(&c->stages, Stage::kCsWrite);
   StageScope recipe_write(&c->stages, Stage::kRecipe);
-  if (status == 0 && crc != s->crc32)
+  if (status == 0 && a.crc != s->crc32)
     FDFS_LOG_WARN("negotiated upload: client declared crc %u, content is %u "
-                  "(ID minted from content)", s->crc32, crc);
-  std::string id = status == 0 ? MintFileId(s->spi, recipe.logical_size, crc,
-                                            s->ext, false)
+                  "(ID minted from content)", s->crc32, a.crc);
+  std::string id = status == 0 ? MintFileId(s->spi, recipe.logical_size,
+                                            a.crc, s->ext, false)
                                : "";
   auto parts = id.empty() ? std::nullopt : DecodeFileId(id);
   std::optional<std::string> local =
@@ -4134,46 +3833,36 @@ void StorageServer::UploadChunksComplete(Conn* c) {
     status = 5;
   }
   if (status != 0) {
-    if (fp_plugin != nullptr) fp_plugin->AbortChunked(fp_session);
-    s->cs->UnrefAll(done);
+    s->cs->UnrefAll(a.taken);
     fail(status);
     return;
   }
   recipe_write.End();
   cs_write.End();
-  if (fp_plugin != nullptr) {
+  if (fp) {
     StageScope reindex(&c->stages, Stage::kReindex);
-    if (fp_ok)
-      fp_plugin->CommitChunked(
-          fp_session, cfg_.group_name + "/" + parts->RemoteFilename());
+    if (a.fingerprinted)
+      fp->Commit(cfg_.group_name + "/" + parts->RemoteFilename());
     else
-      fp_plugin->AbortChunked(fp_session);
+      fp.reset();  // the abort, inside this interval
   }
-  stats_.dedup_hits += hits;
-  stats_.dedup_bytes_saved += saved;
-  if (ctr_dedup_chunk_hits_ != nullptr && hits > 0)
-    ctr_dedup_chunk_hits_->fetch_add(hits, std::memory_order_relaxed);
-  if (ctr_dedup_chunk_misses_ != nullptr && missing > 0)
-    ctr_dedup_chunk_misses_->fetch_add(missing, std::memory_order_relaxed);
+  stats_.dedup_hits += a.hits;
+  stats_.dedup_bytes_saved += a.saved;
+  if (ingest_ctrs_.chunk_hits != nullptr) {
+    ingest_ctrs_.chunk_hits->fetch_add(a.hits, std::memory_order_relaxed);
+    ingest_ctrs_.chunk_misses->fetch_add(a.missing, std::memory_order_relaxed);
+  }
   // Wire accounting: `saved` bytes never left the client — the whole
   // point of the negotiated path.
   if (ctr_ingest_recipe_uploads_ != nullptr) {
     ctr_ingest_recipe_uploads_->fetch_add(1, std::memory_order_relaxed);
-    ctr_ingest_bytes_saved_wire_->fetch_add(saved,
+    ctr_ingest_bytes_saved_wire_->fetch_add(a.saved,
                                             std::memory_order_relaxed);
-    ctr_ingest_chunks_present_->fetch_add(hits, std::memory_order_relaxed);
-    ctr_ingest_chunks_shipped_->fetch_add(missing,
+    ctr_ingest_chunks_present_->fetch_add(a.hits, std::memory_order_relaxed);
+    ctr_ingest_chunks_shipped_->fetch_add(a.missing,
                                           std::memory_order_relaxed);
   }
-  {
-    StageScope binlog(&c->stages, Stage::kBinlog);
-    binlog_.Append(kBinlogOpCreate, parts->RemoteFilename());
-  }
-  NoteTracedMutation(c, parts->RemoteFilename());
-  stats_.success_upload++;
-  stats_.last_source_update = time(nullptr);
-  NoteHeat(c, HeatOp::kUpload, cfg_.group_name + "/" + parts->RemoteFilename());
-  Respond(c, 0, PackGroupField(cfg_.group_name) + parts->RemoteFilename());
+  AckCreated(c, parts->RemoteFilename());
 }
 
 // SYNC_CREATE_RECIPE (127): phase 2 of chunk-aware replication — take a
@@ -4229,55 +3918,40 @@ void StorageServer::SyncRecipeComplete(Conn* c) {
   }
   Recipe recipe;
   recipe.logical_size = logical;
-  int64_t saved = 0, hits = 0, covered = 0;
+  int64_t saved = 0, hits = 0, covered = 0, tmp_off = 0;
   bool ok = true;
-  uint8_t fail_status = 5;
-  std::string payload;
   for (int64_t i = 0; ok && i < n_chunks; ++i) {
     const uint8_t* e = entries + i * 29;
-    std::string hex = BytesToHex(e, 20);
-    int64_t len = GetInt64BE(e + 20);  // validated above: (0, cap]
-    bool needed = e[28] != 0;
-    if (needed) {
-      payload.resize(static_cast<size_t>(len));
-      int64_t got = 0;
-      while (got < len) {
-        ssize_t r = read(tmp_fd, payload.data() + got, len - got);
-        if (r <= 0) break;
-        got += r;
-      }
-      // Content-addressed store: the payload must BE its claimed digest
-      // before PutAndRef, or a bit-rotted peer chunk would poison every
-      // future dedup hit under that digest.  Failing the replay makes
-      // the sender fall back to the full-copy SYNC_CREATE_FILE.
-      if (got == len &&
-          Sha1(payload.data(), static_cast<size_t>(len)).Hex() != hex) {
+    // the length is validated above: (0, cap]
+    const RecipeEntry entry{BytesToHex(e, 20), GetInt64BE(e + 20)};
+    covered += entry.length;
+    if (e[28] != 0) {  // shipped
+      // Failing the replay makes the sender fall back to the full-copy
+      // SYNC_CREATE_FILE.
+      char* payload = KeptSegment(entry.length);
+      ok = PreadFull(tmp_fd, payload, entry.length, tmp_off);
+      tmp_off += entry.length;
+      std::string err;
+      const Admission a = ok ? AdmitChunk(cs, entry, payload, &recipe, &err)
+                             : Admission::kStoreError;
+      if (a == Admission::kNotItsDigest) {
         FDFS_LOG_WARN("sync recipe %s: chunk %s failed digest check",
-                      c->sync_remote.c_str(), hex.c_str());
+                      c->sync_remote.c_str(), entry.digest_hex.c_str());
         if (ctr_sync_digest_mismatch_ != nullptr)
           ctr_sync_digest_mismatch_->fetch_add(1, std::memory_order_relaxed);
-        ok = false;
-        break;
       }
-      bool existed = false;
-      std::string err;
-      if (got != len ||
-          !cs->PutAndRef(hex, payload.data(), len, &existed, &err)) {
-        ok = false;
-        break;
-      }
-    } else if (!cs->RefOne(hex)) {
+      ok = a == Admission::kAdmitted;
+    } else if (cs->RefOne(entry.digest_hex)) {
+      saved += entry.length;
+      ++hits;
+      recipe.chunks.push_back(entry);
+    } else {
       // The chunk vanished between query and create (concurrent
       // delete): report it and let the sender fall back to full copy.
       ok = false;
-      break;
-    } else {
-      saved += len;
-      ++hits;
     }
-    recipe.chunks.push_back({hex, len});
-    covered += len;
   }
+  ReleaseKeptSegment();
   close(tmp_fd);
   unlink(c->tmp_path.c_str());
   c->tmp_path.clear();
@@ -4285,7 +3959,7 @@ void StorageServer::SyncRecipeComplete(Conn* c) {
   if (!ok || covered != logical ||
       !cs->StoreRecipe(local + ".rcp", recipe, &err)) {
     cs->UnrefAll(recipe);  // roll back what this replay referenced
-    Respond(c, ok ? (covered != logical ? 22 : 5) : fail_status);
+    Respond(c, ok && covered != logical ? 22 : 5);
     return;
   }
   stats_.dedup_hits += hits;
@@ -4773,25 +4447,16 @@ void StorageServer::FinishUpload(Conn* c) {
       // fan-out directory (StoreRecipe creates the chain only for the
       // flat sidecar; the flat-store fallback below makes its own).
       int64_t saved = 0, hits = 0;
-      if (StoreChunkedFromTmp(c->tmp_path, c->store_path_index, c->file_size,
-                              local + ".rcp",
-                              cfg_.group_name + "/" + parts->RemoteFilename(),
-                              &saved, &hits)) {
+      if (StoreChunked(IngestFor(c->store_path_index, dedup_.get(),
+                                 &c->stages),
+                       c->tmp_path, c->file_size, local + ".rcp",
+                       cfg_.group_name + "/" + parts->RemoteFilename(), &saved,
+                       &hits)) {
         unlink(c->tmp_path.c_str());
         c->tmp_path.clear();
         stats_.dedup_hits += hits;
         stats_.dedup_bytes_saved += saved;
-        {
-          StageScope binlog(&c->stages, Stage::kBinlog);
-          binlog_.Append(kBinlogOpCreate, parts->RemoteFilename());
-        }
-        NoteTracedMutation(c, parts->RemoteFilename());
-        stats_.success_upload++;
-        stats_.last_source_update = time(nullptr);
-        NoteHeat(c, HeatOp::kUpload,
-                 cfg_.group_name + "/" + parts->RemoteFilename());
-        Respond(c, 0,
-                PackGroupField(cfg_.group_name) + parts->RemoteFilename());
+        AckCreated(c, parts->RemoteFilename());
         return;
       }
     }
@@ -4802,7 +4467,8 @@ void StorageServer::FinishUpload(Conn* c) {
     if (!c->hashing) {
       // A chunk-eligible upload whose chunked store failed (a dead
       // sidecar, refused widths): the digest the receive stage left out.
-      auto late = Sha1OfFile(c->tmp_path, c->file_size);
+      auto late = Sha1OfFile(c->tmp_path, c->file_size,
+                             cfg_.dedup_segment_bytes);
       if (!late.has_value()) {
         FDFS_LOG_ERROR("re-read of %s failed", c->tmp_path.c_str());
         unlink(c->tmp_path.c_str());
@@ -4889,15 +4555,19 @@ void StorageServer::FinishUpload(Conn* c) {
   }
   c->tmp_path.clear();
   if (dedup_ != nullptr && !appender) dedup_->Commit(digest, id);
+  AckCreated(c, parts->RemoteFilename());
+}
+
+void StorageServer::AckCreated(Conn* c, const std::string& remote) {
   {
     StageScope binlog(&c->stages, Stage::kBinlog);
-    binlog_.Append(kBinlogOpCreate, parts->RemoteFilename());
+    binlog_.Append(kBinlogOpCreate, remote);
   }
-  NoteTracedMutation(c, parts->RemoteFilename());
+  NoteTracedMutation(c, remote);
   stats_.success_upload++;
   stats_.last_source_update = time(nullptr);
-  NoteHeat(c, HeatOp::kUpload, cfg_.group_name + "/" + parts->RemoteFilename());
-  Respond(c, 0, PackGroupField(cfg_.group_name) + parts->RemoteFilename());
+  NoteHeat(c, HeatOp::kUpload, cfg_.group_name + "/" + remote);
+  Respond(c, 0, PackGroupField(cfg_.group_name) + remote);
 }
 
 std::string StorageServer::ResolveLocal(const std::string& group,
@@ -4916,10 +4586,6 @@ std::string StorageServer::ResolveLocal(const std::string& group,
 bool StorageServer::ChunkEligible(int64_t size) const {
   return dedup_ != nullptr && cfg_.dedup_chunk_threshold > 0 &&
          size >= cfg_.dedup_chunk_threshold && !chunk_stores_.empty();
-}
-
-bool StorageServer::EcOnWrite() const {
-  return cfg_.ec_k > 0 && cfg_.ec_demote_age_s == 0;
 }
 
 ChunkStore* StorageServer::StoreForLocal(const std::string& local) const {
@@ -4945,139 +4611,11 @@ bool StorageServer::RecipeExistsFor(const std::string& local) const {
   return stat((local + ".rcp").c_str(), &st) == 0;
 }
 
-bool StorageServer::StoreChunkedFromTmp(const std::string& tmp_path, int spi,
-                                        int64_t size,
-                                        const std::string& rcp_path,
-                                        const std::string& file_ref,
-                                        int64_t* saved_bytes,
-                                        int64_t* chunk_hits) {
-  return ChunkedStoreWith(dedup_.get(), tmp_path, spi, size, rcp_path,
-                          file_ref, saved_bytes, chunk_hits);
-}
-
-bool StorageServer::ChunkedStoreWith(DedupPlugin* plugin,
-                                     const std::string& tmp_path, int spi,
-                                     int64_t size, const std::string& rcp_path,
-                                     const std::string& file_ref,
-                                     int64_t* saved_bytes,
-                                     int64_t* chunk_hits) {
-  if (spi >= static_cast<int>(chunk_stores_.size())) return false;
-  // The request this thread works on (null on the recovery thread): each
-  // segment's read-back, fingerprint and chunk-store writes are intervals
-  // of it, one per segment and stage.
-  StageTrace* const stages = CurrentStageTrace();
-  ChunkStore* cs = chunk_stores_[spi].get();
-  int fd = open(tmp_path.c_str(), O_RDONLY);
-  if (fd < 0) return false;
-
-  // One upload = one fingerprint session; committed to `file_ref` on
-  // success, aborted on any failure so the plugin never leaks pending
-  // state into the next upload (flat-fallback included).
-  const int64_t session = plugin->BeginChunked();
-  Recipe recipe;
-  recipe.logical_size = size;
-  int64_t seg_base = 0;
-  bool ok = true;
-  while (ok && seg_base < size) {
-    int64_t want = std::min<int64_t>(cfg_.dedup_segment_bytes,
-                                     size - seg_base);
-    StageScope readback(stages, Stage::kReadback);
-    const char* seg = ReadTmpSegment(fd, seg_base, want);
-    readback.End();
-    if (seg == nullptr) {
-      ok = false;
-      break;
-    }
-    // Fingerprint this segment (accelerated in sidecar mode: CDC +
-    // batched SHA1 run on the TPU); then write only unseen chunks.
-    std::vector<ChunkFp> fps;
-    // The chunker, the lock wait and the RPC nest under it (dedup.cc).
-    StageScope fingerprint(stages, Stage::kFingerprint);
-    bool fp_ok = plugin->FingerprintChunks(
-        session, seg, static_cast<size_t>(want), seg_base, &fps);
-    fingerprint.End();
-    if (!fp_ok) {
-      ok = false;  // fingerprinting unavailable: caller stores flat
-      break;
-    }
-    StageScope cs_write(stages, Stage::kCsWrite);
-    if (EcOnWrite() && cs->ec_enabled()) {
-      // The segment's new chunks into one stripe whose parity the
-      // sidecar computes (the host's codec in cpu mode).  A stripe the
-      // sidecar does not encode fails the chunked store, and the upload
-      // takes the flat fallback an unavailable fingerprint takes.
-      StageScope ec_write(stages, Stage::kEcWrite);
-      std::vector<ChunkStore::SegmentChunk> seg_chunks;
-      seg_chunks.reserve(fps.size());
-      for (const ChunkFp& fp : fps)
-        seg_chunks.push_back(
-            {&fp.digest_hex, seg + (fp.offset - seg_base), fp.length});
-      ChunkStore::ParityFn encode;
-      if (std::string(plugin->Name()) == "sidecar")
-        encode = [plugin](int k, int m, int64_t shard_len, const char* data,
-                          std::string* parity) {
-          return plugin->EcEncode(k, m, shard_len, data, parity);
-        };
-      ChunkStore::StripeWrite w;
-      std::string err;
-      ok = cs->PutSegmentStriped(seg_chunks, encode, &recipe, &w, &err);
-      if (!ok) FDFS_LOG_ERROR("chunk store: %s", err.c_str());
-      if (w.encode_failed && ctr_ec_encode_failures_ != nullptr)
-        ctr_ec_encode_failures_->fetch_add(1, std::memory_order_relaxed);
-      *saved_bytes += w.saved_bytes;
-      *chunk_hits += w.chunk_hits;
-      if (ctr_dedup_chunk_hits_ != nullptr) {
-        ctr_dedup_chunk_hits_->fetch_add(w.chunk_hits,
-                                         std::memory_order_relaxed);
-        ctr_dedup_chunk_misses_->fetch_add(w.chunk_misses,
-                                           std::memory_order_relaxed);
-      }
-      if (w.stripe_bytes > 0 && ctr_ec_write_stripes_ != nullptr) {
-        ctr_ec_write_stripes_->fetch_add(1, std::memory_order_relaxed);
-        ctr_ec_write_bytes_->fetch_add(w.stripe_bytes,
-                                       std::memory_order_relaxed);
-      }
-      seg_base += want;
-      continue;
-    }
-    for (const ChunkFp& fp : fps) {
-      bool existed = false;
-      std::string err;
-      if (!cs->PutAndRef(fp.digest_hex, seg + (fp.offset - seg_base),
-                         fp.length, &existed, &err)) {
-        FDFS_LOG_ERROR("chunk store: %s", err.c_str());
-        ok = false;
-        break;
-      }
-      if (existed) {
-        *saved_bytes += fp.length;
-        ++*chunk_hits;
-        if (ctr_dedup_chunk_hits_ != nullptr)
-          ctr_dedup_chunk_hits_->fetch_add(1, std::memory_order_relaxed);
-      } else if (ctr_dedup_chunk_misses_ != nullptr) {
-        ctr_dedup_chunk_misses_->fetch_add(1, std::memory_order_relaxed);
-      }
-      recipe.chunks.push_back({fp.digest_hex, fp.length});
-    }
-    seg_base += want;
-  }
-  close(fd);
-  ReleaseTmpSegment();
-  std::string err;
-  // The recipe's write is a chunk-store write like the chunks' (an
-  // upload's recipe_us column stays the negotiated commit's alone).
-  StageScope recipe_write(ok ? stages : nullptr, Stage::kCsWrite);
-  if (!ok || !cs->StoreRecipe(rcp_path, recipe, &err)) {
-    if (ok) FDFS_LOG_ERROR("recipe write: %s", err.c_str());
-    // Roll back references taken so far; untouched chunks stay for
-    // other recipes, newly-written orphans fall to the startup GC.
-    cs->UnrefAll(recipe);
-    plugin->AbortChunked(session);
-    return false;
-  }
-  recipe_write.End();
-  plugin->CommitChunked(session, file_ref);
-  return true;
+IngestTarget StorageServer::IngestFor(int spi, DedupPlugin* plugin,
+                                      StageTrace* stages) const {
+  // ec_k > 0 and ec_demote_age_s = 0: erasure coding on write.
+  return {chunk_stores_[spi].get(), plugin, cfg_.dedup_segment_bytes,
+          cfg_.ec_k > 0 && cfg_.ec_demote_age_s == 0, stages, ingest_ctrs_};
 }
 
 int64_t StorageServer::LogicalSize(const std::string& local) const {
@@ -5117,23 +4655,19 @@ int StorageServer::OpenLogical(const std::string& local, int64_t* size) {
   fd = open(tmp.c_str(), O_CREAT | O_RDWR | O_TRUNC, 0600);
   if (fd < 0) return -1;
   unlink(tmp.c_str());
-  std::string chunk;
-  for (const RecipeEntry& e : r->chunks) {
-    if (!cs->ReadChunk(e.digest_hex, e.length, &chunk)) {
-      FDFS_LOG_ERROR("missing chunk %s for %s", e.digest_hex.c_str(),
-                     local.c_str());
-      close(fd);
-      return -1;
-    }
-    size_t off = 0;
-    while (off < chunk.size()) {
-      ssize_t w = write(fd, chunk.data() + off, chunk.size() - off);
-      if (w <= 0) {
-        close(fd);
-        return -1;
-      }
-      off += static_cast<size_t>(w);
-    }
+  // kIoBufSize segments: the kept buffer of a replication thread stays
+  // small.
+  if (!WalkRecipe(cs, *r, kIoBufSize, [fd](int64_t, char* seg, int64_t len) {
+        for (int64_t off = 0; off < len;) {
+          const ssize_t w = write(fd, seg + off, static_cast<size_t>(len - off));
+          if (w <= 0) return false;
+          off += w;
+        }
+        return true;
+      })) {
+    FDFS_LOG_ERROR("cannot assemble %s from its chunks", local.c_str());
+    close(fd);
+    return -1;
   }
   *size = r->logical_size;
   lseek(fd, 0, SEEK_SET);

@@ -43,6 +43,7 @@
 #include "storage/config.h"
 #include "storage/dedup.h"
 #include "storage/hotrepl.h"
+#include "storage/ingest.h"
 #include "storage/recovery.h"
 #include "storage/rebalance.h"
 #include "storage/scrub.h"
@@ -230,9 +231,6 @@ class StorageServer {
     // ingest histograms are its sums, the span ring and the access log's
     // "stages" line its intervals (common/trace.h).
     StageTrace stages;
-    // the preadv calls the commit's batched reads took, and their chunks
-    int64_t commit_read_batches = 0;
-    int64_t commit_read_chunks = 0;
     std::string peer_ip;
     // Negotiated upload (UPLOAD_CHUNKS): the session this request
     // commits, plus the missing/total split RecordRequestSpans turns
@@ -422,15 +420,14 @@ class StorageServer {
   void UploadChunksComplete(Conn* c);  // dio worker
   std::unique_ptr<UploadSession> TakeIngestSession(int64_t id);
   void SweepIngestSessions();          // timer: expire vanished clients
-  // Re-register a recovered file's signature/attributions with the
-  // dedup plugin (sidecar-mode rebuilds; bytes are local, wire cost 0).
-  void ReindexRecovered(DedupPlugin* plugin, const std::string& local,
-                        const std::string& file_ref);
   void DeleteWork(Conn* c);          // delete body (dio worker)
 
   // -- handlers (storage_service.c analogues) ----------------------------
   bool BeginUpload(Conn* c);        // parse fixed, open tmp file
   void FinishUpload(Conn* c);       // mint id, dedup, commit, binlog
+  // A new file's bytes or recipe are down: its binlog record (the
+  // request's storage.binlog interval), then the reply naming it.
+  void AckCreated(Conn* c, const std::string& remote);
   void HandleDownload(Conn* c);
   void HandleDelete(Conn* c);
   void HandleQueryFileInfo(Conn* c);
@@ -475,32 +472,16 @@ class StorageServer {
   // Whether this upload takes the chunked path (plugin active, chunking
   // enabled, size over threshold).
   bool ChunkEligible(int64_t size) const;
-  // ec_k > 0 and ec_demote_age_s = 0: a chunked upload's new chunks go
-  // into RS(k, m) stripes as it commits, not into slab or flat files.
-  bool EcOnWrite() const;
   ChunkStore* StoreForLocal(const std::string& local) const;
   // Slab-aware recipe access for call sites that may lack a chunk store
   // (dedup off): route through the store's recipe codec (slab record or
   // flat sidecar) when one exists, else the flat .rcp file directly.
   std::optional<Recipe> LoadRecipeFor(const std::string& local) const;
   bool RecipeExistsFor(const std::string& local) const;
-  // Chunk the tmp file via the dedup plugin, write unique chunks into the
-  // store-path's chunk store, and write the recipe at `rcp_path`.
-  // *saved_bytes accumulates duplicate-chunk bytes.  False => caller
-  // stores the file flat (fingerprinting unavailable or IO error).
-  // Each segment's read-back, fingerprint and chunk-store writes are
-  // intervals of the request the calling thread works on
-  // (CurrentStageTrace(); none on the recovery thread).
-  bool StoreChunkedFromTmp(const std::string& tmp_path, int spi,
-                           int64_t size, const std::string& rcp_path,
-                           const std::string& file_ref,
-                           int64_t* saved_bytes, int64_t* chunk_hits);
-  // Same, against an explicit plugin (the recovery thread uses its own
-  // instance — the plugins are not thread-safe, the ChunkStore is).
-  bool ChunkedStoreWith(DedupPlugin* plugin, const std::string& tmp_path,
-                        int spi, int64_t size, const std::string& rcp_path,
-                        const std::string& file_ref, int64_t* saved_bytes,
-                        int64_t* chunk_hits);
+  // What an ingest (ingest.h) into store path `spi` runs against: the
+  // plugin of the calling thread, the stage recorder of its request.
+  IngestTarget IngestFor(int spi, DedupPlugin* plugin,
+                         StageTrace* stages) const;
   // Open the logical content at `local`: a plain fd, or a recipe
   // materialized into an unlinked temp file.  -1 when missing.
   int OpenLogical(const std::string& local, int64_t* size);
@@ -645,15 +626,12 @@ class StorageServer {
   std::atomic<int64_t>* ctr_chunkfetch_bytes_ = nullptr;
   std::atomic<int64_t>* ctr_recv_hashed_bytes_ = nullptr;
   std::atomic<int64_t>* ctr_fallback_rehash_ = nullptr;
-  std::atomic<int64_t>* ctr_dedup_chunk_hits_ = nullptr;
-  std::atomic<int64_t>* ctr_dedup_chunk_misses_ = nullptr;
-  // Erasure coding on write (EcOnWrite()): stripes committed by uploads,
-  // their data shards' bytes (k x shard_len, what the sidecar's
-  // ec_encode_bytes counts when it computed the parity), and stripes
-  // the sidecar did not encode (each upload then stored flat).
-  std::atomic<int64_t>* ctr_ec_write_stripes_ = nullptr;
-  std::atomic<int64_t>* ctr_ec_write_bytes_ = nullptr;
-  std::atomic<int64_t>* ctr_ec_encode_failures_ = nullptr;
+  // dedup.chunk_hits / chunk_misses, and erasure coding on write
+  // (IngestFor): stripes committed by uploads, their data shards' bytes
+  // (k x shard_len, what the sidecar's ec_encode_bytes counts when it
+  // computed the parity), and stripes the sidecar did not encode (each
+  // upload then stored flat).
+  IngestCounters ingest_ctrs_;
   // Negotiated-upload (ingest edge) accounting: completed recipe
   // uploads, chunk bytes the client did NOT ship because the store
   // already held them, and server-observable fallbacks (no chunk
@@ -664,8 +642,6 @@ class StorageServer {
   std::atomic<int64_t>* ctr_ingest_fallbacks_ = nullptr;
   std::atomic<int64_t>* ctr_ingest_chunks_present_ = nullptr;
   std::atomic<int64_t>* ctr_ingest_chunks_shipped_ = nullptr;
-  std::atomic<int64_t>* ctr_ingest_commit_read_batches_ = nullptr;
-  std::atomic<int64_t>* ctr_ingest_commit_read_chunks_ = nullptr;
   // Negotiated-upload stage clocks (the access log's last five columns).
   StatHistogram* hist_ingest_negotiate_ = nullptr;
   StatHistogram* hist_ingest_present_ = nullptr;

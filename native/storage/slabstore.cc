@@ -14,6 +14,7 @@
 #include <set>
 
 #include "common/bytes.h"
+#include "common/fsutil.h"
 #include "common/log.h"
 
 namespace fdfs {
@@ -29,21 +30,8 @@ int64_t RecordExtent(size_t key_len, int64_t alloc_len) {
   return static_cast<int64_t>(kSlabRecordHeaderSize + key_len) + alloc_len;
 }
 
-// Read exactly [offset, offset+len) of fd into dst; false on any short
-// read or error.
-bool PreadAll(int fd, char* dst, int64_t len, int64_t offset) {
-  int64_t got = 0;
-  while (got < len) {
-    ssize_t r = pread(fd, dst + got, static_cast<size_t>(len - got),
-                      offset + got);
-    if (r <= 0) return false;
-    got += r;
-  }
-  return true;
-}
-
 // Vectored read of the full iov chain at offset; false on any short
-// read or error.  Advances through partial reads like PreadAll.
+// read or error.  Advances through partial reads like PreadFull.
 bool PreadvAll(int fd, struct iovec* iov, int iovcnt, int64_t offset) {
   while (iovcnt > 0) {
     ssize_t r = preadv(fd, iov, iovcnt, offset);
@@ -346,7 +334,7 @@ bool SlabStore::Read(uint8_t kind, const std::string& key,
     int fd = open(SlabPath(s.slab_id).c_str(), O_RDONLY);
     if (fd < 0) continue;
     out->resize(static_cast<size_t>(s.payload_len));
-    bool ok = PreadAll(fd, out->data(), s.payload_len, s.payload_off);
+    bool ok = PreadFull(fd, out->data(), s.payload_len, s.payload_off);
     close(fd);
     if (ok) return true;
   }
@@ -361,7 +349,7 @@ bool SlabStore::ReadSlice(uint8_t kind, const std::string& key,
     if (offset < 0 || len < 0 || offset + len > s.payload_len) return false;
     int fd = open(SlabPath(s.slab_id).c_str(), O_RDONLY);
     if (fd < 0) continue;
-    bool ok = PreadAll(fd, dst, len, s.payload_off + offset);
+    bool ok = PreadFull(fd, dst, len, s.payload_off + offset);
     close(fd);
     if (ok) return true;
   }
@@ -506,7 +494,7 @@ void SlabStore::ForEachLive(
       bool ok = false;
       if (fd >= 0) {
         payload.resize(static_cast<size_t>(it.slot.payload_len));
-        ok = PreadAll(fd, payload.data(), it.slot.payload_len,
+        ok = PreadFull(fd, payload.data(), it.slot.payload_len,
                       it.slot.payload_off);
       }
       // Slab vanished/moved under us (a concurrent compaction):
@@ -537,7 +525,7 @@ void SlabStore::ScanOneSlab(
     hdr.resize(kSlabRecordHeaderSize + kSlabKeyMaxLen);
     int64_t want = std::min<int64_t>(static_cast<int64_t>(hdr.size()),
                                      size - off);
-    if (!PreadAll(fd, hdr.data(), want, off)) break;
+    if (!PreadFull(fd, hdr.data(), want, off)) break;
     SlabRecordView v;
     if (!SlabDecodeRecord(hdr.data(), static_cast<size_t>(want), &v) ||
         off + v.record_len > size) {
@@ -751,7 +739,7 @@ SlabStore::CompactResult SlabStore::Compact(
         int64_t want = std::min<int64_t>(
             static_cast<int64_t>(buf.size()), size - off);
         SlabRecordView v;
-        if (!PreadAll(fd, buf.data(), want, off) ||
+        if (!PreadFull(fd, buf.data(), want, off) ||
             !SlabDecodeRecord(buf.data(), static_cast<size_t>(want), &v) ||
             off + v.record_len > size) {
           FDFS_LOG_WARN("slab compact %s: unreadable record at %lld, "
@@ -766,7 +754,7 @@ SlabStore::CompactResult SlabStore::Compact(
         if (live) {
           std::string payload;
           payload.resize(static_cast<size_t>(v.payload_len));
-          if (!PreadAll(fd, payload.data(), v.payload_len,
+          if (!PreadFull(fd, payload.data(), v.payload_len,
                         here.payload_off)) {
             scan_ok = false;
             break;

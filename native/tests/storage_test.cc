@@ -18,6 +18,7 @@
 #include "storage/chunkstore.h"
 #include "storage/ecstore.h"
 #include "storage/dedup.h"
+#include "storage/ingest.h"
 #include "storage/store.h"
 #include "storage/trunk.h"
 
@@ -1328,6 +1329,154 @@ static void TestChunkStoreEcDemoteReleaseRemoteRead() {
   CHECK(owner2.ec_parity_bytes() == 0);
 }
 
+// A plugin that counts what the ingest asks of it; fingerprints fail on
+// demand (one chunk a segment otherwise).
+class CountingPlugin : public DedupPlugin {
+ public:
+  int begins = 0, segments = 0, commits = 0, aborts = 0;
+  int64_t session = 0;
+  std::string committed_to;
+  bool fail_fingerprint = false;
+  Verdict Judge(const std::string&, int64_t) override { return {}; }
+  void Commit(const std::string&, const std::string&) override {}
+  void Forget(const std::string&) override {}
+  const char* Name() const override { return "counting"; }
+  int64_t BeginChunked() override { return ++begins; }
+  bool FingerprintChunks(int64_t s, const char* data, size_t len,
+                         int64_t base, std::vector<ChunkFp>* out) override {
+    ++segments;
+    session = s;
+    if (fail_fingerprint) return false;
+    out->push_back({base, static_cast<int64_t>(len),
+                    Sha1(data, len).Hex()});
+    return true;
+  }
+  void CommitChunked(int64_t s, const std::string& ref) override {
+    ++commits;
+    session = s;
+    committed_to = ref;
+  }
+  void AbortChunked(int64_t s) override {
+    ++aborts;
+    session = s;
+  }
+};
+
+// Every exit of a caller ends the session: an early return aborts it, a
+// commit commits it once and aborts nothing.
+static void TestFingerprintSessionCannotLeak() {
+  CountingPlugin p;
+  auto ingest = [&p](bool bail) {
+    FingerprintSession s(&p, /*reindex=*/true);
+    std::vector<ChunkFp> fps;
+    if (!s.Segment("abc", 3, 0, &fps) || bail) return false;
+    s.Commit("group1/M00/00/00/f");
+    return true;
+  };
+  CHECK(!ingest(true));
+  CHECK(p.begins == 1 && p.segments == 1 && p.aborts == 1 && p.commits == 0);
+  CHECK((p.session & kDedupReindexSessionBit) != 0);
+  CHECK(ingest(false));
+  CHECK(p.begins == 2 && p.aborts == 1 && p.commits == 1);
+  CHECK(p.committed_to == "group1/M00/00/00/f");
+  // The upload's own session: a fingerprint that fails mid-stream ends
+  // in the flat fallback with the session aborted, not left pending.
+  std::string dir = TempDir();
+  ChunkStore cs(dir, 0);
+  std::string tmp = dir + "/upload.tmp";
+  std::string body(5000, 'u');
+  FILE* f = std::fopen(tmp.c_str(), "wb");
+  std::fwrite(body.data(), 1, body.size(), f);
+  std::fclose(f);
+  IngestTarget t;
+  t.cs = &cs;
+  t.plugin = &p;
+  t.segment_bytes = 2048;
+  int64_t saved = 0, hits = 0;
+  p.fail_fingerprint = true;
+  CHECK(!StoreChunked(t, tmp, 5000, dir + "/a.rcp", "g/a", &saved, &hits));
+  CHECK(p.begins == 3 && p.aborts == 2 && p.commits == 1);
+  CHECK((p.session & kDedupReindexSessionBit) == 0);
+  p.fail_fingerprint = false;
+  CHECK(StoreChunked(t, tmp, 5000, dir + "/b.rcp", "g/b", &saved, &hits));
+  CHECK(p.begins == 4 && p.segments == 6 && p.aborts == 2 && p.commits == 2);
+  CHECK(p.committed_to == "g/b" && cs.HasRecipe(dir + "/b.rcp"));
+  CHECK(!cs.HasRecipe(dir + "/a.rcp"));
+}
+
+// A chunk that is not its digest is refused before the store sees it:
+// no reference, nothing in the rollback set.
+static void TestAdmitChunkRefusesWhatIsNotItsDigest() {
+  std::string dir = TempDir();
+  ChunkStore cs(dir, 0);
+  std::string good(3000, 'g'), bad(3000, 'b');
+  const RecipeEntry e{Sha1(good.data(), good.size()).Hex(), 3000};
+  Recipe taken;
+  taken.chunks.push_back({"0000000000000000000000000000000000000000", 1});
+  std::string err;
+  CHECK(AdmitChunk(&cs, e, bad.data(), &taken, &err) ==
+        Admission::kNotItsDigest);
+  CHECK(taken.chunks.size() == 1);
+  CHECK(!cs.Has(e.digest_hex));
+  CHECK(AdmitChunk(&cs, e, good.data(), &taken, &err) ==
+        Admission::kAdmitted);
+  CHECK(taken.chunks.size() == 2 && taken.chunks[1].digest_hex == e.digest_hex);
+  CHECK(cs.Has(e.digest_hex));
+  std::string back;
+  CHECK(cs.ReadChunk(e.digest_hex, 3000, &back) && back == good);
+}
+
+// The recipe walk hands over each segment's bytes exactly, entries that
+// cross a segment end and a digest repeated within one segment included,
+// from flat and slab-resident chunks alike.
+static void TestWalkRecipeAssemblesSegments() {
+  for (int slab = 0; slab < 2; ++slab) {
+    std::string dir = TempDir();
+    SlabOptions so;
+    if (slab) {
+      so.chunk_threshold = 64 * 1024;
+      so.recipe_threshold = 4096;
+      so.slab_bytes = 1 << 20;
+    }
+    ChunkStore cs(dir, 0, 0, so);
+    cs.RebuildFromRecipes();
+    std::string a(3000, '\0'), b(5000, '\0');
+    for (size_t i = 0; i < a.size(); ++i) a[i] = static_cast<char>(i * 7);
+    for (size_t i = 0; i < b.size(); ++i) b[i] = static_cast<char>(i * 13 + 1);
+    const std::string da = Sha1(a.data(), a.size()).Hex();
+    const std::string db = Sha1(b.data(), b.size()).Hex();
+    bool existed = false;
+    std::string err;
+    CHECK(cs.PutAndRef(da, a.data(), a.size(), &existed, &err));
+    CHECK(cs.PutAndRef(db, b.data(), b.size(), &existed, &err));
+    Recipe r;
+    std::string want;
+    for (int k : {0, 1, 0, 0, 1}) {
+      r.chunks.push_back({k ? db : da, k ? 5000 : 3000});
+      want += k ? b : a;
+    }
+    r.logical_size = static_cast<int64_t>(want.size());
+    for (int64_t seg : {int64_t{7000}, int64_t{1} << 20}) {
+      std::string got;
+      std::vector<int64_t> bases;
+      CHECK(WalkRecipe(&cs, r, seg, [&](int64_t base, char* p, int64_t len) {
+        bases.push_back(base);
+        CHECK(len == std::min(seg, r.logical_size - base));
+        got.append(p, static_cast<size_t>(len));
+        return true;
+      }));
+      CHECK(got == want);
+      CHECK(bases.size() ==
+            static_cast<size_t>((r.logical_size + seg - 1) / seg));
+    }
+    // A chunk the store does not hold fails the walk.
+    Recipe gone = r;
+    gone.chunks[3].digest_hex = std::string(40, 'f');
+    CHECK(!WalkRecipe(&cs, gone, 7000,
+                      [](int64_t, char*, int64_t) { return true; }));
+  }
+}
+
 int main() {
   TestBinlogRecordCodec();
   TestBinlogWriteReadResume();
@@ -1353,6 +1502,9 @@ int main() {
   TestEcStoreStripeLifecycle();
   TestEcStoreDuplicateDigestKeepsOneSlot();
   TestChunkStoreEcDemoteReleaseRemoteRead();
+  TestFingerprintSessionCannotLeak();
+  TestAdmitChunkRefusesWhatIsNotItsDigest();
+  TestWalkRecipeAssemblesSegments();
   if (g_failures == 0) {
     std::printf("storage_test: ALL PASS\n");
     return 0;

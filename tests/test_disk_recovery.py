@@ -289,13 +289,14 @@ def test_sidecar_mode_recovery_reindexes_near_dups(tmp_path_factory):
     """A sidecar-mode rebuild must re-register recovered files with its
     (fresh) dedup engine: after wiping BOTH the data path and the
     sidecar state, NEAR_DUPS on the rebuilt node still reports the
-    recovered neighbours (ReindexRecovered feeds the assembled bytes
-    back through the plugin)."""
+    recovered neighbours (ReindexFromRecipe feeds the bytes, read from
+    the recipe just stored, back through the plugin)."""
     import random
     import sys
 
     sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
     from test_chunked_storage import _start_sidecar
+    from fastdfs_tpu.sidecar import read_stats
 
     tracker = start_tracker(tmp_path_factory.mktemp("nrtr"))
     taddr = f"127.0.0.1:{tracker.port}"
@@ -322,8 +323,10 @@ def test_sidecar_mode_recovery_reindexes_near_dups(tmp_path_factory):
         fdfs = FdfsClient(taddr)
         rng = random.Random(47)
         shared = rng.randbytes(1 << 20)
-        fa = fdfs.upload_buffer(shared + rng.randbytes(64 << 10), ext="bin")
-        fb = fdfs.upload_buffer(shared + rng.randbytes(64 << 10), ext="bin")
+        da = shared + rng.randbytes(64 << 10)
+        db = shared + rng.randbytes(64 << 10)
+        fa = fdfs.upload_buffer(da, ext="bin")
+        fb = fdfs.upload_buffer(db, ext="bin")
         assert _wait(lambda: all(
             len(t.query_fetch_all(f)) == 2 for f in (fa, fb)), timeout=60)
 
@@ -343,6 +346,7 @@ def test_sidecar_mode_recovery_reindexes_near_dups(tmp_path_factory):
         os.makedirs(os.path.join(str(sc2_dir), "state"))
         sc2, _ = _start_sidecar(sc2_dir, state_dir=os.path.join(
             str(sc2_dir), "state"))
+        before = read_stats(sock2)["reindex_bytes"]
 
         conf = os.path.join(str(s2dir), "storage.conf")
         s2 = Daemon(STORAGED, conf, s2_port, ip=ips[1])
@@ -355,6 +359,9 @@ def test_sidecar_mode_recovery_reindexes_near_dups(tmp_path_factory):
             got = _wait(lambda: any(
                 r == fb for r, _ in sc.near_dups(fa)) or None, timeout=30)
             assert got, "recovered files missing from the near-dup index"
+        # Both files are over the chunk threshold, so both came back from
+        # their recipes, and the re-index read every byte of each.
+        assert read_stats(sock2)["reindex_bytes"] - before == len(da) + len(db)
     finally:
         s2.stop()
         s1.stop()

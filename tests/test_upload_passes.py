@@ -1,5 +1,6 @@
 """How often the daemon's upload path passes over an uploaded byte
-(``native/storage/server.cc``: the receive stage and ``ChunkedStoreWith``).
+(``native/storage/server.cc``: the receive stage; ``ingest.cc``:
+``StoreChunked``).
 
 * The receive stage's whole-file SHA-1 runs only where ``Judge`` /
   ``Commit`` can read it: an upload that is neither an appender nor
@@ -7,7 +8,7 @@
   stored flat as before, and takes the digest from its tmp file then
   (``upload.fallback_rehash``); ``upload.recv_hashed_bytes`` counts the
   bytes the receive stage hashed.
-* ``ChunkedStoreWith`` reads each ``dedup_segment_bytes`` segment of the
+* ``StoreChunked`` reads each ``dedup_segment_bytes`` segment of the
   tmp file into a buffer its thread keeps: the recipe is the reference's
   (``benchmark/reference.py``) at every segment boundary, and an upload
   never sees the bytes of the one before it on the same worker.
